@@ -70,6 +70,9 @@ def _run_json_subprocess(src: str, devices: int) -> dict:
     # the subprocess arms time themselves with the same primitive as the
     # in-process benches.
     env["PYTHONPATH"] = os.pathsep.join([str(REPO / "src"), str(REPO)])
+    # The parent has already run JAX and may hold the accelerator; these
+    # arms are fake-host-device CPU runs by construction.
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(src)],
                        capture_output=True, text=True, timeout=900, env=env)
     if r.returncode != 0:

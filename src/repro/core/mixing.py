@@ -67,15 +67,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-try:
-    _shard_map = jax.shard_map
-except AttributeError:  # jax < 0.5 keeps shard_map under experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from .gossip_plan import GossipPlan
 from .quantize import QuantConfig, dequantize_int, quantize_int
 from .topology import MixingSpec, TopologySchedule
-from .wire_layout import WireLayout
+from .wire_layout import WireLayout, rows_to_planar
 
 Pytree = Any
 
@@ -167,6 +162,33 @@ class MixerConfig:
         return "dense"
 
 
+def _take_lanes(rows: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``rows[idx]`` over the lane axis for a short traced index vector of
+    in-bounds lanes, as one dynamic slice per lane. XLA's TPU backend
+    splits a gather of wide rows into 32768-element pieces, so a gather
+    over a whole model's wire row grows the program with the model until
+    compilation stalls."""
+    return jnp.concatenate(
+        [jax.lax.dynamic_slice_in_dim(rows, idx[j], 1, axis=0)
+         for j in range(idx.shape[0])], axis=0)
+
+
+def _put_lanes(out: jnp.ndarray, idx: jnp.ndarray,
+               vals: jnp.ndarray) -> jnp.ndarray:
+    """``out.at[idx].set(vals, mode="drop")`` over the lane axis as one
+    dynamic update slice per lane (an index ``>= out.shape[0]`` writes
+    the last lane's own row back) — the scatter counterpart of
+    :func:`_take_lanes`. Each write touches one lane's row, not all
+    ``m_local`` of them."""
+    last = out.shape[0] - 1
+    for j in range(idx.shape[0]):
+        at = jnp.minimum(idx[j], last)
+        row = jnp.where(idx[j] <= last, vals[j:j + 1],
+                        jax.lax.dynamic_slice_in_dim(out, at, 1, axis=0))
+        out = jax.lax.dynamic_update_slice_in_dim(out, row, at, axis=0)
+    return out
+
+
 def _pallas_wire(wire: str) -> bool:
     """Whether the flat wire codec runs the Pallas buffer kernels (True)
     or their pure-XLA oracle (False; the CPU default)."""
@@ -177,31 +199,23 @@ def _pallas_wire(wire: str) -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _shard_map_no_repcheck(body, mesh, in_specs, out_specs):
-    """shard_map with replication checking off — pallas_call has no
-    replication rule, so the planar-wire body needs it disabled. The
-    kwarg was renamed check_rep -> check_vma across jax releases."""
-    try:
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-
-
 # ---------------------------------------------------------------------------
 # Dense backend: x' = W @ Z (einsum over client axis). Reference semantics.
 # ---------------------------------------------------------------------------
 
+_F32_EXACT = jax.lax.Precision.HIGHEST
+
+
 def mix_dense(W: np.ndarray, stacked: Pytree) -> Pytree:
     """Eq. 5 reference: x' = W @ z per leaf, f32 tensordot over the
     client axis (the bitwise target every other backend is tested
-    against)."""
+    against). HIGHEST precision: a TPU's default f32 matmul is one bf16
+    pass, which would round every mixed parameter to ~3 digits."""
     Wj = jnp.asarray(W)
 
     def mx(z):
         out = jnp.tensordot(Wj.astype(jnp.float32), z.astype(jnp.float32),
-                            axes=([1], [0]))
+                            axes=([1], [0]), precision=_F32_EXACT)
         return out.astype(z.dtype)
 
     return jax.tree.map(mx, stacked)
@@ -252,11 +266,12 @@ def _mix_dense_quantized(W: np.ndarray, x: Pytree, z: Pytree,
         if quant.delta_mode == "lemma5":
             # x' = W (x + q): the recursion the paper's proofs analyze.
             mixed = jnp.tensordot(Wj, xl.astype(jnp.float32) + q,
-                                  axes=([1], [0]))
+                                  axes=([1], [0]), precision=_F32_EXACT)
             out.append(mixed.astype(xl.dtype))
         else:
             # x' = x + W q: Algorithm 2 verbatim (needs PSD W, see docs).
-            mixed = jnp.tensordot(Wj, q, axes=([1], [0]))
+            mixed = jnp.tensordot(Wj, q, axes=([1], [0]),
+                                  precision=_F32_EXACT)
             out.append((xl.astype(jnp.float32) + mixed).astype(xl.dtype))
     return jax.tree.unflatten(treedef, out)
 
@@ -271,6 +286,27 @@ def _weighted_replica_base(xs, weights):
     for j in range(1, xs.shape[-3]):
         base = base + weights[..., j, None, None] * xs[..., j, :, :]
     return base
+
+
+def _split_streams(streams, n_words: int, n_leaves: int, per: int,
+                   lemma5: bool):
+    """Received u32 streams (each [m_local, L]) -> their parts, each
+    stacked over streams: packed words [m_local, K, W], per-leaf f32
+    scales [m_local, K, nl] and, for ``lemma5``, the f32 replica buffers
+    [m_local, K, per, W] (else None). Each stream is sliced before the
+    stacking: parts sliced out of one stacked [m_local, K, L] buffer
+    share its layout, and the TPU compiler lays that buffer out for the
+    small scale block, padding the client and stream dims 64-fold."""
+    words = jnp.stack([st[:, :n_words] for st in streams], axis=1)
+    scales = jax.lax.bitcast_convert_type(
+        jnp.stack([st[:, n_words:n_words + n_leaves] for st in streams],
+                  axis=1), jnp.float32)
+    xs = None
+    if lemma5:
+        xs = jnp.stack([rows_to_planar(jax.lax.bitcast_convert_type(
+            st[:, n_words + n_leaves:], jnp.float32), per, n_words)
+            for st in streams], axis=1)
+    return words, scales, xs
 
 
 def execute_plan_reference(plan: GossipPlan, W, stacked: Pytree,
@@ -499,16 +535,17 @@ def _make_sparse_exec(plan: GossipPlan, mesh, client_axes: Sequence[str],
         antichain), so the collectives overlap each other and whatever
         compute runs between issue and combine (collective-matmul
         idiom). Returns {step: [received buffers per sub-step]}."""
-        return {k: [jax.lax.ppermute(rows[send[s]], axis, sub.pairs)
+        return {k: [jax.lax.ppermute(_take_lanes(rows, send[s]), axis,
+                                     sub.pairs)
                     for sub, send, _ in sub_t[k]] for k in live}
 
     def combine_recv(rows, got_k, k, s):
         """Step k's receive for this shard: intra lanes gather locally;
         boundary lanes scatter the already-issued sub-step transfers
         over the identity gather (padded rows drop)."""
-        out = rows[intra_t[k][s]]
+        out = _take_lanes(rows, intra_t[k][s])
         for (sub, send, recv), got in zip(sub_t[k], got_k):
-            out = out.at[recv[s]].set(got, mode="drop")
+            out = _put_lanes(out, recv[s], got)
         return out
 
     if quant is None or not quant.enabled:
@@ -532,11 +569,9 @@ def _make_sparse_exec(plan: GossipPlan, mesh, client_axes: Sequence[str],
             # every model column (client-axis-only collectives), but the
             # static replication checker can't see through the ppermutes
             # — turn it off rather than weaken the specs.
-            smap = _shard_map_no_repcheck if sharded2d else (
-                lambda b, mesh, in_specs, out_specs: _shard_map(
-                    b, mesh=mesh, in_specs=in_specs, out_specs=out_specs))
-            fn = smap(body, mesh=mesh,
-                      in_specs=(specs,) + w_specs, out_specs=specs)
+            fn = jax.shard_map(body, mesh=mesh,
+                               in_specs=(specs,) + w_specs, out_specs=specs,
+                               check_vma=not sharded2d)
             return fn(z, jnp.asarray(wself, jnp.float32),
                       jnp.asarray(wsteps, jnp.float32))
 
@@ -580,18 +615,10 @@ def _make_sparse_exec(plan: GossipPlan, mesh, client_axes: Sequence[str],
         streams = [stream] + [combine_recv(stream, got[k], k, s)
                               for k in live]
         wlist = [wself] + [wsteps[k] for k in live]
-        S = jnp.stack(streams, axis=1)                # [m_local, K, L] u32
         weights = jnp.stack(wlist, axis=1)            # [m_local, K]
-        words_all = S[..., :W]
-        scales_all = jax.lax.bitcast_convert_type(
-            S[..., W:W + nl], jnp.float32)            # [m_local, K, nl]
-        if lemma5:
-            xs = jax.lax.bitcast_convert_type(
-                S[..., W + nl:], jnp.float32).reshape(
-                    m_local, -1, layout.per, W)
-            base = _weighted_replica_base(xs, weights)
-        else:
-            base = x2d
+        words_all, scales_all, xs = _split_streams(streams, W, nl,
+                                                   layout.per, lemma5)
+        base = _weighted_replica_base(xs, weights) if lemma5 else x2d
         out = layout.decode_apply(base, words_all, scales_all, weights,
                                   quant, pallas=pallas)
         return layout.from_planar_stacked(out)
@@ -617,13 +644,13 @@ def _make_sparse_exec(plan: GossipPlan, mesh, client_axes: Sequence[str],
             extra_specs = (specs,)
         else:
             extra, extra_specs = (), ()
-        smap = _shard_map_no_repcheck if (pallas or sharded2d) else (
-            lambda b, mesh, in_specs, out_specs: _shard_map(
-                b, mesh=mesh, in_specs=in_specs, out_specs=out_specs))
-        fn = smap(q_body, mesh=mesh,
-                  in_specs=(specs, specs, P(ca, None, None)) + w_specs
-                  + extra_specs,
-                  out_specs=specs)
+        # pallas_call has no replication rule, so the planar-wire body runs
+        # with the checker off.
+        fn = jax.shard_map(q_body, mesh=mesh,
+                           in_specs=(specs, specs, P(ca, None, None))
+                           + w_specs + extra_specs,
+                           out_specs=specs,
+                           check_vma=not (pallas or sharded2d))
         return fn(x, z, keys, jnp.asarray(wself, jnp.float32),
                   jnp.asarray(wsteps, jnp.float32), *extra)
 
@@ -842,13 +869,14 @@ def make_fused_tail(loss_fn, m: int, *, eta: float, theta: float,
         # All sends read `stream` — a dataflow antichain; the
         # boundary collectives overlap each other and the gradient
         # computed between issue and combine.
-        return {k: [jax.lax.ppermute(stream[send[s]], axis, sub.pairs)
+        return {k: [jax.lax.ppermute(_take_lanes(stream, send[s]), axis,
+                                     sub.pairs)
                     for sub, send, _ in sub_t[k]] for k in live}
 
     def combine_step(stream, got_k, k, s):
-        out = stream[intra_t[k][s]]
+        out = _take_lanes(stream, intra_t[k][s])
         for (sub, send, recv), got in zip(sub_t[k], got_k):
-            out = out.at[recv[s]].set(got, mode="drop")
+            out = _put_lanes(out, recv[s], got)
         return out
 
     if not quant_on:
@@ -898,11 +926,11 @@ def make_fused_tail(loss_fn, m: int, *, eta: float, theta: float,
             w_self, w_steps = plan.gather_weights(W)
             specs = _full_specs(x, ca, param_specs)
             bspecs = _full_specs(batch_last, ca, None)
-            fn = _shard_map(body, mesh=mesh,
-                            in_specs=(specs, specs, specs, specs, bspecs,
-                                      P(ca, None), P(ca), P(None, ca),
-                                      P(ca)),
-                            out_specs=(specs, specs, P(ca)))
+            fn = jax.shard_map(body, mesh=mesh,
+                               in_specs=(specs, specs, specs, specs, bspecs,
+                                         P(ca, None), P(ca), P(None, ca),
+                                         P(ca)),
+                               out_specs=(specs, specs, P(ca)))
             return fn(x, y, v, g, batch_last, keys_last,
                       jnp.asarray(w_self, jnp.float32),
                       jnp.asarray(w_steps, jnp.float32),
@@ -985,18 +1013,10 @@ def make_fused_tail(loss_fn, m: int, *, eta: float, theta: float,
         streams = [stream] + [combine_step(stream, got[k], k, s)
                               for k in live]
         wlist = [wself] + [wsteps[k] for k in live]
-        S = jnp.stack(streams, axis=1)              # [m_local, K, L] u32
         weights = jnp.stack(wlist, axis=1)          # [m_local, K]
-        words_all = S[..., :Wd]
-        scales_all = jax.lax.bitcast_convert_type(
-            S[..., Wd:Wd + nl], jnp.float32)        # [m_local, K, nl]
-        if lemma5:
-            xs = jax.lax.bitcast_convert_type(
-                S[..., Wd + nl:], jnp.float32).reshape(
-                    m_loc, -1, layout.per, Wd)
-            base = _weighted_replica_base(xs, weights)
-        else:
-            base = x2d
+        words_all, scales_all, xs = _split_streams(streams, Wd, nl,
+                                                   layout.per, lemma5)
+        base = _weighted_replica_base(xs, weights) if lemma5 else x2d
         # RECEIVE: mix + deferred last update in one fused pass (kernel
         # path) / one XLA-fused chain (oracle path).
         if pallas:
@@ -1027,14 +1047,12 @@ def make_fused_tail(loss_fn, m: int, *, eta: float, theta: float,
                 keys = keys[jnp.asarray(plan.lane_to_client)]
         else:
             keys = jnp.zeros((m, 1, 2), jnp.uint32)
-        smap = _shard_map_no_repcheck if pallas else (
-            lambda b, mesh, in_specs, out_specs: _shard_map(
-                b, mesh=mesh, in_specs=in_specs, out_specs=out_specs))
-        fn = smap(q_body, mesh=mesh,
-                  in_specs=(specs, specs, specs, specs, bspecs,
-                            P(ca, None), P(ca, None, None), P(ca),
-                            P(None, ca), P(ca)),
-                  out_specs=(specs, specs, P(ca)))
+        fn = jax.shard_map(q_body, mesh=mesh,
+                           in_specs=(specs, specs, specs, specs, bspecs,
+                                     P(ca, None), P(ca, None, None), P(ca),
+                                     P(None, ca), P(ca)),
+                           out_specs=(specs, specs, P(ca)),
+                           check_vma=not pallas)
         return fn(x, y, v, g, batch_last, keys_last, keys,
                   jnp.asarray(w_self, jnp.float32),
                   jnp.asarray(w_steps, jnp.float32),

@@ -247,22 +247,27 @@ def spectral_gap(W: np.ndarray) -> float:
 
 def check_mixing_matrix(W: np.ndarray, graph: Graph | None = None,
                         atol: float = 1e-10) -> None:
-    """Raise if W violates Definition 1. Used by tests and constructors."""
+    """Raise if W violates Definition 1. Used by tests and constructors.
+
+    ``atol`` is the tolerance of every equality test (symmetry, row sums,
+    the eigenvalue-1 tests); row sums and eigenvalues are never held
+    tighter than 1e-8. Pass ~1e-6 for a W built in float32."""
     W = np.asarray(W, dtype=np.float64)
+    tol = max(atol, 1e-8)
     m = W.shape[0]
     if W.shape != (m, m):
         raise ValueError("W must be square")
     if not np.allclose(W, W.T, atol=atol):
         raise ValueError("W must be symmetric")
-    if not np.allclose(W.sum(axis=1), 1.0, atol=1e-8):
+    if not np.allclose(W.sum(axis=1), 1.0, atol=tol):
         raise ValueError("rows of W must sum to 1")
     ev = np.linalg.eigvalsh(W)
     if ev.min() <= -1.0 + 1e-12:
         raise ValueError("need W > -I (smallest eigenvalue > -1)")
-    if ev.max() > 1.0 + 1e-8:
+    if ev.max() > 1.0 + tol:
         raise ValueError("need I >= W")
     # null(I - W) = span(1)  <=>  eigenvalue 1 is simple (for connected G).
-    if np.sum(np.abs(ev - 1.0) < 1e-8) != 1:
+    if np.sum(np.abs(ev - 1.0) < tol) != 1:
         raise ValueError("eigenvalue 1 of W must be simple "
                          "(is the graph connected?)")
     if graph is not None:

@@ -67,7 +67,42 @@ from ..kernels.ref import LANE_BLOCK
 
 Pytree = Any
 
-__all__ = ["WireLayout", "LANE_BLOCK"]
+__all__ = ["WireLayout", "LANE_BLOCK", "rows_to_planar"]
+
+
+def rows_to_planar(flat: jnp.ndarray, per: int, lw: int) -> jnp.ndarray:
+    """[..., n] -> [..., per, lw], zero-padded: row r holds
+    ``flat[..., r*lw:(r+1)*lw]`` — the planar view of a flat vector. It
+    is built from row slices because the TPU compiler takes minutes per
+    leaf on the equivalent pad + reshape at model width (splitting the
+    minor dim), and about a second this way."""
+    n = flat.shape[-1]
+    pad = [(0, 0)] * (flat.ndim - 1)
+    rows = []
+    for r in range(per):
+        row = flat[..., r * lw:min((r + 1) * lw, n)]
+        rows.append(jnp.pad(row, pad + [(0, lw - row.shape[-1])]))
+    return jnp.stack(rows, axis=-2)
+
+
+def _planar_to_leaf(buf: jnp.ndarray, shape: tuple) -> jnp.ndarray:
+    """Inverse of :func:`rows_to_planar` into a leaf's shape: [..., per,
+    lw] -> [..., *shape], by concatenating rows (see there for why not a
+    reshape). Where a row holds whole rows of the leaf's 2-D view, the
+    rows are joined in that view: joining them flat and reshaping the
+    result takes the TPU compiler about a minute per layer weight."""
+    lead, lw = buf.shape[:-2], buf.shape[-1]
+    n = int(np.prod(shape)) if shape else 1
+    last = shape[-1] if shape else 1
+    n_rows = -(-n // lw)
+    if lw % last == 0:
+        rows = [buf[..., r, :].reshape(lead + (lw // last, last))
+                for r in range(n_rows)]
+        flat2d = jnp.concatenate(rows, axis=-2)[..., :n // last, :]
+        return flat2d.reshape(lead + tuple(shape))
+    rows = [buf[..., r, :] for r in range(n_rows)]
+    return jnp.concatenate(rows, axis=-1)[..., :n].reshape(
+        lead + tuple(shape))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -162,45 +197,35 @@ class WireLayout:
 
     def to_planar(self, tree: Pytree) -> jnp.ndarray:
         """Client-local tree -> [per, total_words] f32, zero-padded."""
-        segs = []
-        for leaf, n, lw in zip(self._leaves(tree), self.sizes,
-                               self.leaf_words):
-            flat = leaf.reshape(-1).astype(jnp.float32)
-            segs.append(jnp.pad(flat, (0, self.per * lw - n))
-                        .reshape(self.per, lw))
+        segs = [rows_to_planar(leaf.reshape(-1).astype(jnp.float32),
+                               self.per, lw)
+                for leaf, lw in zip(self._leaves(tree), self.leaf_words)]
         return jnp.concatenate(segs, axis=1)
 
     def from_planar(self, buf2d: jnp.ndarray) -> Pytree:
         outs = []
-        for shape, dtype, n, lw, off in zip(self.shapes, self.dtypes,
-                                            self.sizes, self.leaf_words,
-                                            self.word_offsets):
-            seg = buf2d[:, off:off + lw]
-            outs.append(seg.reshape(-1)[:n].reshape(shape).astype(dtype))
+        for shape, dtype, lw, off in zip(self.shapes, self.dtypes,
+                                         self.leaf_words, self.word_offsets):
+            outs.append(_planar_to_leaf(buf2d[:, off:off + lw], shape)
+                        .astype(dtype))
         return jax.tree.unflatten(self.treedef, outs)
 
     def to_planar_stacked(self, tree: Pytree) -> jnp.ndarray:
         """Stacked tree (leaves [m, ...]) -> [m, per, total_words] f32.
         Row c equals ``to_planar`` of client c's local tree — the batched
         form the mesh-free reference executor uses."""
-        segs = []
-        for leaf, n, lw in zip(self._leaves(tree), self.sizes,
-                               self.leaf_words):
-            m = leaf.shape[0]
-            flat = leaf.reshape(m, -1).astype(jnp.float32)
-            segs.append(jnp.pad(flat, ((0, 0), (0, self.per * lw - n)))
-                        .reshape(m, self.per, lw))
+        segs = [rows_to_planar(
+                    leaf.reshape(leaf.shape[0], -1).astype(jnp.float32),
+                    self.per, lw)
+                for leaf, lw in zip(self._leaves(tree), self.leaf_words)]
         return jnp.concatenate(segs, axis=2)
 
     def from_planar_stacked(self, buf: jnp.ndarray) -> Pytree:
         outs = []
-        m = buf.shape[0]
-        for shape, dtype, n, lw, off in zip(self.shapes, self.dtypes,
-                                            self.sizes, self.leaf_words,
-                                            self.word_offsets):
-            seg = buf[:, :, off:off + lw]
-            outs.append(seg.reshape(m, -1)[:, :n]
-                        .reshape((m,) + shape).astype(dtype))
+        for shape, dtype, lw, off in zip(self.shapes, self.dtypes,
+                                         self.leaf_words, self.word_offsets):
+            outs.append(_planar_to_leaf(buf[:, :, off:off + lw], shape)
+                        .astype(dtype))
         return jax.tree.unflatten(self.treedef, outs)
 
     # -- per-leaf scales and stochastic-rounding noise ----------------------
@@ -242,23 +267,21 @@ class WireLayout:
         ``_quant_leaf_keys`` derivation, so the dense reference draws the
         IDENTICAL bits). Padding is zero: ``noise < (a - floor(a))`` never
         rounds a padded zero up. Returns [per, total_words]."""
-        segs = []
-        for li, (n, lw) in enumerate(zip(self.sizes, self.leaf_words)):
-            u = jax.random.uniform(leaf_keys[li], (n,), jnp.float32)
-            segs.append(jnp.pad(u, (0, self.per * lw - n))
-                        .reshape(self.per, lw))
+        segs = [rows_to_planar(
+                    jax.random.uniform(leaf_keys[li], (n,), jnp.float32),
+                    self.per, lw)
+                for li, (n, lw) in enumerate(zip(self.sizes,
+                                                 self.leaf_words))]
         return jnp.concatenate(segs, axis=1)
 
     def noise_stacked(self, keys: jnp.ndarray) -> jnp.ndarray:
         """Batched :meth:`noise`: ``keys`` [n_leaves, m, 2] (the raw
         ``_quant_leaf_keys`` output) -> [m, per, total_words]."""
-        m = keys.shape[1]
-        segs = []
-        for li, (n, lw) in enumerate(zip(self.sizes, self.leaf_words)):
-            u = jax.vmap(lambda k, n=n: jax.random.uniform(
-                k, (n,), jnp.float32))(keys[li])
-            segs.append(jnp.pad(u, ((0, 0), (0, self.per * lw - n)))
-                        .reshape(m, self.per, lw))
+        segs = [rows_to_planar(
+                    jax.vmap(lambda k, n=n: jax.random.uniform(
+                        k, (n,), jnp.float32))(keys[li]), self.per, lw)
+                for li, (n, lw) in enumerate(zip(self.sizes,
+                                                 self.leaf_words))]
         return jnp.concatenate(segs, axis=2)
 
     def block_scales(self, scales: jnp.ndarray) -> jnp.ndarray:

@@ -21,49 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .quantize_pack import block_scale, scale_groups, scale_spec
 from .ref import LANE_BLOCK
-
-
-def _dequant_mix_kernel(x_ref, qo_ref, ql_ref, qr_ref, s_ref, out_ref, *,
-                        bits: int, w_self: float, w_nb: float):
-    per = 32 // bits
-    mask = jnp.uint32((1 << bits) - 1)
-    offset = jnp.int32(1 << (bits - 1))
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (per, 1), 0) * bits
-
-    def deq(words, s):  # words: [LANE_BLOCK] u32 -> [per, LANE_BLOCK] f32
-        fields = (words[None, :] >> shifts) & mask
-        return (fields.astype(jnp.int32) - offset).astype(jnp.float32) * s
-
-    acc = x_ref[...].astype(jnp.float32)
-    acc += w_self * deq(qo_ref[...], s_ref[0, 0])
-    acc += w_nb * deq(ql_ref[...], s_ref[0, 1])
-    acc += w_nb * deq(qr_ref[...], s_ref[0, 2])
-    out_ref[...] = acc.astype(out_ref.dtype)
-
-
-def _dequant_mix_plan_kernel(x_ref, q_ref, sw_ref, out_ref, *, bits: int,
-                             n_streams: int):
-    """Plan-generic fused apply (eq. 7 over a GossipPlan):
-
-        out = x + sum_k weight[k] * deq(stream[k], scale[k])
-
-    Streams are the client's OWN packed words plus one received stream per
-    plan step; scales AND weights are runtime values (per-round gathered
-    weights of a time-varying W_t), packed as sw_ref = [[scales],[weights]].
-    """
-    per = 32 // bits
-    mask = jnp.uint32((1 << bits) - 1)
-    offset = jnp.int32(1 << (bits - 1))
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (per, 1), 0) * bits
-
-    acc = x_ref[...].astype(jnp.float32)
-    for k in range(n_streams):
-        fields = (q_ref[k][None, :] >> shifts) & mask
-        deq = (fields.astype(jnp.int32) - offset).astype(jnp.float32) \
-            * sw_ref[0, k]
-        acc += sw_ref[1, k] * deq
-    out_ref[...] = acc.astype(out_ref.dtype)
 
 
 def _dequant_mix_buffer_kernel(x_ref, q_ref, s_ref, w_ref, out_ref, *,
@@ -91,7 +50,7 @@ def _dequant_mix_buffer_kernel(x_ref, q_ref, s_ref, w_ref, out_ref, *,
     for k in range(n_streams):
         fields = (q_ref[k][None, :] >> shifts) & mask
         deq = (fields.astype(jnp.int32) - offset).astype(jnp.float32) \
-            * s_ref[k, 0]
+            * block_scale(s_ref, k)
         acc += w_ref[0, k] * deq
     out_ref[...] = acc.astype(out_ref.dtype)
 
@@ -118,13 +77,14 @@ def dequant_mix_buffer_pallas(x2d: jnp.ndarray, streams: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((per, LANE_BLOCK), lambda i: (0, i)),
             pl.BlockSpec((k, LANE_BLOCK), lambda i: (0, i)),
-            pl.BlockSpec((k, 1), lambda i: (0, i)),
+            scale_spec(k),
             pl.BlockSpec((1, k), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((per, LANE_BLOCK), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
         interpret=interpret,
-    )(x2d, streams, block_scales.astype(jnp.float32),
+        name="dequant_mix_buffer",
+    )(x2d, streams, scale_groups(block_scales),
       weights.reshape(1, k).astype(jnp.float32))
 
 
@@ -152,7 +112,7 @@ def _dequant_mix_momentum_buffer_kernel(x_ref, q_ref, s_ref, w_ref, v_ref,
     for k in range(n_streams):
         fields = (q_ref[k][None, :] >> shifts) & mask
         deq = (fields.astype(jnp.int32) - offset).astype(jnp.float32) \
-            * s_ref[k, 0]
+            * block_scale(s_ref, k)
         acc += w_ref[0, k] * deq
     v_next = (et_ref[0, 1] * v_ref[...].astype(jnp.float32)
               - et_ref[0, 0] * g_ref[...].astype(jnp.float32))
@@ -186,7 +146,7 @@ def dequant_mix_momentum_buffer_pallas(x2d: jnp.ndarray, streams: jnp.ndarray,
         in_specs=[
             buf,
             pl.BlockSpec((k, LANE_BLOCK), lambda i: (0, i)),
-            pl.BlockSpec((k, 1), lambda i: (0, i)),
+            scale_spec(k),
             pl.BlockSpec((1, k), lambda i: (0, 0)),
             buf, buf,
             pl.BlockSpec((1, 2), lambda i: (0, 0)),
@@ -194,61 +154,36 @@ def dequant_mix_momentum_buffer_pallas(x2d: jnp.ndarray, streams: jnp.ndarray,
         out_specs=buf,
         out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
         interpret=interpret,
-    )(x2d, streams, block_scales.astype(jnp.float32),
+        name="dequant_mix_momentum_buffer",
+    )(x2d, streams, scale_groups(block_scales),
       weights.reshape(1, k).astype(jnp.float32), v2d, g2d,
       et.reshape(1, 2).astype(jnp.float32))
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "interpret"))
 def dequant_mix_plan_pallas(x2d: jnp.ndarray, streams: jnp.ndarray,
                             scales: jnp.ndarray, weights: jnp.ndarray, *,
                             bits: int, interpret: bool = False
                             ) -> jnp.ndarray:
     """x2d: [per, W] (f32/bf16); streams: uint32 [k, W]; scales/weights:
-    f32 [k] (traced OK — the per-round mask). Returns [per, W]."""
-    per, w = x2d.shape
-    k = streams.shape[0]
-    assert per == 32 // bits and w % LANE_BLOCK == 0, (per, w)
-    grid = (w // LANE_BLOCK,)
-    kernel = functools.partial(_dequant_mix_plan_kernel, bits=bits,
-                               n_streams=k)
-    sw = jnp.stack([scales, weights]).astype(jnp.float32)  # [2, k]
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((per, LANE_BLOCK), lambda i: (0, i)),
-            pl.BlockSpec((k, LANE_BLOCK), lambda i: (0, i)),
-            pl.BlockSpec((2, k), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((per, LANE_BLOCK), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
-        interpret=interpret,
-    )(x2d, streams, sw)
+    f32 [k] (traced OK — the per-round mask). Returns [per, W]:
+    :func:`dequant_mix_buffer_pallas` with stream k's one scale on every
+    lane block."""
+    k, w = streams.shape
+    block_scales = jnp.broadcast_to(
+        jnp.asarray(scales, jnp.float32).reshape(k, 1),
+        (k, w // LANE_BLOCK))
+    return dequant_mix_buffer_pallas(x2d, streams, block_scales, weights,
+                                     bits=bits, interpret=interpret)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("bits", "w_self", "w_nb", "interpret"))
 def dequant_mix_pallas(x2d: jnp.ndarray, q_own: jnp.ndarray,
                        q_left: jnp.ndarray, q_right: jnp.ndarray,
                        scales: jnp.ndarray, *, bits: int, w_self: float,
                        w_nb: float, interpret: bool = False) -> jnp.ndarray:
-    """x2d: [per, W] (f32/bf16); q_*: uint32 [W]; scales: f32 [3]."""
-    per, w = x2d.shape
-    assert per == 32 // bits and w % LANE_BLOCK == 0, (per, w)
-    grid = (w // LANE_BLOCK,)
-    kernel = functools.partial(_dequant_mix_kernel, bits=bits,
-                               w_self=w_self, w_nb=w_nb)
-    word_spec = pl.BlockSpec((LANE_BLOCK,), lambda i: (i,))
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((per, LANE_BLOCK), lambda i: (0, i)),
-            word_spec, word_spec, word_spec,
-            pl.BlockSpec((1, 3), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((per, LANE_BLOCK), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
-        interpret=interpret,
-    )(x2d, q_own, q_left, q_right, scales.reshape(1, 3).astype(jnp.float32))
+    """x2d: [per, W] (f32/bf16); q_*: uint32 [W]; scales: f32 [3] (own,
+    left, right). Returns [per, W]: :func:`dequant_mix_plan_pallas` over
+    the three ring streams."""
+    return dequant_mix_plan_pallas(
+        x2d, jnp.stack([q_own, q_left, q_right]), scales,
+        jnp.asarray([w_self, w_nb, w_nb], jnp.float32), bits=bits,
+        interpret=interpret)

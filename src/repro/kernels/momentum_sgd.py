@@ -68,6 +68,7 @@ def momentum_sgd_pallas(y2d: jnp.ndarray, v2d: jnp.ndarray, g2d: jnp.ndarray,
         out_shape=(jax.ShapeDtypeStruct(y2d.shape, y2d.dtype),
                    jax.ShapeDtypeStruct(v2d.shape, v2d.dtype)),
         interpret=interpret,
+        name="momentum_sgd",
     )(y2d, v2d, g2d, et)
     if padded:
         y_o, v_o = y_o[:r, :c], v_o[:r, :c]

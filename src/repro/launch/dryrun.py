@@ -17,10 +17,12 @@ from repro.launch.cost_model import (analytic_hbm_bytes,  # noqa: E402
                                      structural_costs)
 from repro.launch.hlo_stats import (collect_collectives,  # noqa: E402
                                     collect_collectives_looped)
-from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,  # noqa: E402
+from repro.launch.mesh import (device_peaks,  # noqa: E402
                                make_production_mesh)
 
 OUT_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
+# The chip the production meshes model (fake host devices stand in for it).
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 def validate_sharding(archs=None, model_parallels=(2, 4, 8),
@@ -169,9 +171,10 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
     #                struct.bytes is an unfused upper bound, reported too]
     #   collective = wire_bytes_per_device / link_bw
     hbm_bytes = analytic_hbm_bytes(cfg, built.meta, n_chips)
-    compute_t = struct.flops / (n_chips * PEAK_FLOPS_BF16)
-    memory_t = hbm_bytes / (n_chips * HBM_BW)
-    coll_t = coll.wire_bytes / ICI_BW
+    peaks = device_peaks(TARGET_DEVICE_KIND)
+    compute_t = struct.flops / (n_chips * peaks["flops_bf16"])
+    memory_t = hbm_bytes / (n_chips * peaks["hbm_bw"])
+    coll_t = coll.wire_bytes / peaks["ici_bw_per_link"]
     terms = {"compute_s": compute_t, "memory_s": memory_t,
              "collective_s": coll_t}
     dom = max(terms, key=terms.get)

@@ -146,6 +146,25 @@ def collect_collectives(hlo_text: str) -> CollectiveStats:
     return stats
 
 
+def jaxpr_eqns(jaxpr):
+    """Every equation of a (closed) jaxpr, sub-jaxprs (scan, cond,
+    shard_map, pjit bodies) included, in program order."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
+                    yield from jaxpr_eqns(sub)
+
+
+def pallas_kernel_names(jaxpr) -> list[str]:
+    """The ``name`` of every ``pallas_call`` in ``jaxpr`` (see
+    :func:`jaxpr_eqns`), in program order — the kernels a program runs."""
+    return [e.params["name"] for e in jaxpr_eqns(jaxpr)
+            if e.primitive.name == "pallas_call"]
+
+
 def traced_flops(fn, *args) -> float:
     """Scan-aware FLOP count of ``fn(*args)`` (args may be arrays or
     ShapeDtypeStructs). Thin forwarding of ``cost_model.structural_costs``

@@ -7,19 +7,15 @@ must set XLA_FLAGS before that happens.
 from __future__ import annotations
 
 import jax
+from jax.sharding import Mesh
 
 
-def auto_axis_types_kw(n_axes: int) -> dict:
-    """``axis_types=(AxisType.Auto,) * n`` where supported, else {}.
-
-    jax.sharding.AxisType only exists on newer jax; older releases (e.g.
-    0.4.x) treat every mesh axis as Auto already, so omitting the kwarg is
-    equivalent there.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto_mesh(shape, axes) -> Mesh:
+    """Mesh over the first ``prod(shape)`` devices with every axis Auto
+    (GSPMD-partitioned), which ``jax.sharding.Mesh`` gives by default and
+    ``jax.make_mesh`` does not."""
+    from jax.experimental import mesh_utils
+    return Mesh(mesh_utils.create_device_mesh(shape), axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -27,12 +23,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2 pods = 512 chips (2, 16, 16) ("pod", "data", "model")."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **auto_axis_types_kw(len(axes)))
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(4, 2), axes=("data", "model")):
     """Small host-device mesh for CPU multi-device tests."""
-    return jax.make_mesh(shape, axes, **auto_axis_types_kw(len(axes)))
+    return _auto_mesh(shape, axes)
 
 
 # (m, clients_per_shard) combinations already warned about — the dense
@@ -55,12 +51,10 @@ def make_client_mesh(m: int, axis: str = "clients",
     (per-device wire drops ~linearly with ``model_parallel``). Returns
     ``None`` when the host has too few devices — with a ONE-TIME warning
     naming the dense fallback and the flags that control it (this used to
-    happen silently). Uses ``jax.sharding.Mesh`` directly so it works on
-    jax releases without ``jax.make_mesh``."""
+    happen silently)."""
     import warnings
 
     import numpy as np
-    from jax.sharding import Mesh
 
     if clients_per_shard < 1 or m % clients_per_shard:
         raise ValueError(
@@ -114,16 +108,32 @@ def resident_lane_capacity(bytes_per_client: int,
     if model_parallel < 1:
         raise ValueError(f"model_parallel={model_parallel} must be >= 1")
     if budget_bytes is None:
-        try:
-            stats = jax.devices()[0].memory_stats() or {}
-            budget_bytes = stats.get("bytes_limit", 0) or 2 << 30
-        except Exception:
+        dev = jax.devices()[0]
+        budget_bytes = (dev.memory_stats() or {}).get("bytes_limit")
+        if not budget_bytes:
+            if dev.platform != "cpu":
+                raise RuntimeError(
+                    f"{dev.device_kind} reports no bytes_limit; pass "
+                    f"--resident-lanes to size the pool explicitly")
             budget_bytes = 2 << 30
     per_device = -(-bytes_per_client // model_parallel)
     return max(1, int(budget_bytes / (overhead * per_device)))
 
 
-# v5e hardware constants for the roofline analysis (per chip / per link)
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # B/s
-ICI_BW = 50e9                   # B/s per link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s ICI per chip over 4 links (50 GB/s per link). JAX names
+# the v5e "TPU v5 lite".
+DEVICE_PEAKS = {"TPU v5 lite": {"flops_bf16": 197e12, "hbm_bw": 819e9,
+                                "ici_bw_per_link": 50e9}}
+
+
+def device_peaks(device_kind: str) -> dict:
+    """Peak FLOP/s and bytes/s of one chip of ``device_kind``; a kind not
+    in :data:`DEVICE_PEAKS` is an error, never a default."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: "
+                         f"{sorted(DEVICE_PEAKS)}") from None

@@ -2,16 +2,20 @@
 this; also usable standalone):
 
   PYTHONPATH=src python -m repro.launch.train --arch smollm-135m \
-      --reduced --rounds 50 --clients 8 --bits 8
+      --rounds 50 --clients 8 --bits 8
 
-On CPU this trains a reduced config on synthetic LM data; on a real slice
-the same code path runs the production mesh (pass --mesh prod).
+By default it trains the config's reduced CPU smoke-test variant on
+synthetic LM data; ``--no-reduced`` keeps the published widths and
+``--layers N`` cuts only the depth (``chip_smoke.py`` runs OLMo-1B that
+way on one TPU).
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import time
 import warnings
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +30,20 @@ from ..core.topology import erdos_renyi_graph, ring_graph, torus_graph
 from ..data.synthetic import lm_client_batches, lm_round_batches
 from ..models import model as M
 from ..telemetry import RunLog, Tracer, telemetry_host
+
+
+class RunResult(NamedTuple):
+    """What a resident run returns. ``step`` is the jitted round step and
+    ``batches`` one round's input, so ``step.lower(state, batches)``
+    shows the round program that ran; ``rounds`` are the round records
+    the run logged (``t``, ``loss``, ``round_s``, ...)."""
+
+    state: Any
+    metrics: dict
+    step: Callable
+    batches: Any
+    rounds: list
+
 
 # RunLog round-record fields pulled straight out of the step's metrics
 # dict when present (the telemetry pytree, if any, is merged first and
@@ -56,6 +74,23 @@ def _round_fields(metrics, comm_bits=None):
     if comm_bits is not None:
         out["comm_bits"] = float(comm_bits)
     return out
+
+
+def _placement(mesh, spec: jax.sharding.PartitionSpec
+               ) -> jax.sharding.NamedSharding:
+    """``spec`` on ``mesh`` in the form the jitted step gives its outputs:
+    no mesh axes of size 1, no trailing ``None``. A spec that differs
+    from the outputs' only in form is the same layout, but it misses
+    jit's fast dispatch cache, and round 2 would take the slow path."""
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    parts = []
+    for e in spec:
+        names = tuple(a for a in ((e,) if isinstance(e, str) else e or ())
+                      if sizes[a] > 1)
+        parts.append(names[0] if len(names) == 1 else (names or None))
+    while parts and parts[-1] is None:
+        parts.pop()
+    return jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec(*parts))
 
 
 def build_topology(args, m: int):
@@ -189,7 +224,14 @@ def run_pooled(args, cfg, log, tracer):
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="train the config's CPU smoke-test variant "
+                         "(configs.reduced: d_model<=256, vocab<=512, "
+                         "float32); --no-reduced keeps the published "
+                         "widths and dtype")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to N layers; no width changes")
     ap.add_argument("--rounds", type=int, default=20)
     ap.add_argument("--clients", type=int, default=8)
     ap.add_argument("--local-steps", type=int, default=4)
@@ -318,7 +360,13 @@ def main(argv=None):
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = make_reduced(cfg)
+    if args.layers is not None:
+        if args.layers < 1:
+            raise SystemExit(f"--layers {args.layers} must be >= 1")
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     cfg = dataclasses.replace(cfg, remat=False)
+    from .compile_cache import use_compile_cache
+    use_compile_cache()
     log = RunLog(jsonl=args.log_jsonl)
     tracer = Tracer(enabled=args.trace is not None)
     log.start(config={k: v for k, v in vars(args).items()})
@@ -368,9 +416,9 @@ def _run_resident(args, cfg, log, tracer):
             "shard_map body, which would only see a 1/model_parallel "
             "slice of the params; run the unfused round (its local SGD "
             "auto-partitions over the model axis under GSPMD)")
+    from .mesh import make_client_mesh
     mesh = client_axes = None
     if args.mixer_impl in ("auto", "sparse"):
-        from .mesh import make_client_mesh
         if args.clients_per_shard < 1 or m % args.clients_per_shard:
             raise SystemExit(f"--clients-per-shard {args.clients_per_shard} "
                              f"must be >= 1 and divide --clients {m}")
@@ -388,6 +436,17 @@ def _run_resident(args, cfg, log, tracer):
                 f"lower --model-parallel to fit")
     impl = "sparse" if mesh is not None else "dense"
     client_axes = ("clients",) if mesh is not None else ()
+    # Where the host has a device per client shard, the dense reference's
+    # clients live on the client mesh a sparse run would use: its einsum
+    # then gathers over that mesh, and no device holds every client's
+    # local SGD.
+    place_mesh = mesh
+    cps = args.clients_per_shard
+    if (args.mixer_impl == "dense" and not args.async_gossip
+            and cps >= 1 and m % cps == 0
+            and 1 < m // cps <= jax.device_count()):
+        place_mesh = make_client_mesh(
+            m, clients_per_shard=args.clients_per_shard)
     dfed = DFedAvgMConfig(eta=args.eta, theta=args.theta,
                           local_steps=args.local_steps, quant=quant,
                           mixer_impl=impl, wire=args.wire,
@@ -458,8 +517,7 @@ def _run_resident(args, cfg, log, tracer):
                                      leading_client=("clients",))
         stacked = jax.device_put(
             stacked,
-            jax.tree.map(lambda s: jax.sharding.NamedSharding(mesh, s),
-                         param_specs,
+            jax.tree.map(lambda s: _placement(mesh, s), param_specs,
                          is_leaf=lambda s: isinstance(
                              s, jax.sharding.PartitionSpec)))
         n_sharded = sum(
@@ -473,6 +531,12 @@ def _run_resident(args, cfg, log, tracer):
         log.info(f"2D mesh: model_parallel={args.model_parallel}, "
                  f"{n_sharded}/{n_leaves} param leaves model-sharded "
                  f"(the rest replicate per column)")
+    elif place_mesh is not None:
+        # Client blocks go to their shards up front, so no device holds
+        # every client's copy on the way into the first round.
+        clients_on_mesh = _placement(place_mesh,
+                                     jax.sharding.PartitionSpec("clients"))
+        stacked = jax.device_put(stacked, clients_on_mesh)
 
     loss = lambda p, b, r: M.loss_fn(p, cfg, b, r)
     acfg = None
@@ -491,19 +555,35 @@ def _run_resident(args, cfg, log, tracer):
     # warning on CPU, a real saving on device).
     warnings.filterwarnings("ignore",
                             message="Some donated buffers were not usable")
-    step = jax.jit(make_round_step(loss, dfed, spec, mesh=mesh,
-                                   client_axes=client_axes or (),
-                                   param_specs=param_specs,
-                                   async_cfg=acfg,
-                                   with_telemetry=args.telemetry,
-                                   placement=placement),
-                   donate_argnums=(0,))
+    round_step = make_round_step(loss, dfed, spec, mesh=mesh,
+                                 client_axes=client_axes or (),
+                                 param_specs=param_specs,
+                                 async_cfg=acfg,
+                                 with_telemetry=args.telemetry,
+                                 placement=placement)
+    if mesh is None and place_mesh is not None:
+        # GSPMD would hand the dense mix back replicated on every device;
+        # the clients stay on their shards for the next round.
+        def round_step(state, batches, _step=round_step):
+            state, metrics = _step(state, batches)
+            return state._replace(params=jax.lax.with_sharding_constraint(
+                state.params, clients_on_mesh)), metrics
+    step = jax.jit(round_step, donate_argnums=(0,))
     if acfg is not None:
         state = init_async_state(stacked, k_state, acfg.speed)
     else:
         token = (spec.init_token()
                  if scheduled and spec.is_stateful else None)
         state = init_round_state(stacked, k_state, token=token)
+    if place_mesh is not None:
+        # The step returns its scalars (round counter, PRNG key) on the
+        # mesh; committing them there up front keeps round 2 from
+        # compiling the step a second time.
+        rep = jax.sharding.NamedSharding(place_mesh,
+                                         jax.sharding.PartitionSpec())
+        state = jax.tree.map(
+            lambda a: a if isinstance(a.sharding, jax.sharding.NamedSharding)
+            else jax.device_put(a, rep), state)
 
     d = cfg.n_params()
     if plan is not None and args.model_parallel > 1:
@@ -523,7 +603,9 @@ def _run_resident(args, cfg, log, tracer):
     # event below (the set varies with readiness and staleness).
     ledger = CommLedger(0.0 if acfg is not None
                         else round_comm_bits(spec, d, quant))
+    records = []
     for t in range(args.rounds):
+        t_round = time.perf_counter()
         with tracer.span("round/data", t=t):
             if acfg is not None:
                 # Async events are unordered across clients, so data must
@@ -562,15 +644,17 @@ def _run_resident(args, cfg, log, tracer):
                                        comm_bits=ledger.total_bits)
                 if acfg is not None:
                     fields.setdefault("clock", float(state.clock))
-                log.round(t, float(metrics["loss"]), console=cadence,
-                          **fields)
+                loss_t = float(metrics["loss"])
+                records.append(log.round(
+                    t, loss_t, console=cadence,
+                    round_s=time.perf_counter() - t_round, **fields))
     avg = average_params(state.params)
     log.info(f"done; consensus model leaves: {len(jax.tree.leaves(avg))}")
     log.end(args.rounds, comm_bits=float(ledger.total_bits),
             final_loss=float(metrics["loss"]),
             final_consensus_dist=(float(metrics["consensus_dist"])
                                   if "consensus_dist" in metrics else None))
-    return state, metrics
+    return RunResult(state, metrics, step, batches, records)
 
 
 if __name__ == "__main__":

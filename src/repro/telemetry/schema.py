@@ -27,7 +27,7 @@ from typing import Any
 __all__ = ["SCHEMA_VERSION", "RECORD_FIELDS", "validate_record",
            "require_valid"]
 
-SCHEMA_VERSION = 1
+SCHEMA_VERSION = 2
 
 _NUM = (int, float)
 _INT = (int,)
@@ -49,6 +49,9 @@ RECORD_FIELDS: dict[str, dict[str, tuple[tuple, bool]]] = {
         "t": (_INT, True),           # round (sync) / event (async) index
         "loss": (_NUM, True),        # participation-weighted mean loss
         "wall_s": (_NUM, True),      # host seconds since run_start
+        # host seconds from this round's data to its loss on the host
+        # (the first round's includes compilation)
+        "round_s": (_NUM, False),
         # -- shared optional metrics --------------------------------------
         "consensus_dist": (_NUM, False),   # Lemma 4 LHS over x^{t+1}
         "local_drift": (_NUM, False),      # same functional over z^t

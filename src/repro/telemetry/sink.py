@@ -80,6 +80,8 @@ class ConsoleRenderer:
             parts.append(f"({rec['pool_mbytes']:.1f}MB host)")
         if "comm_bits" in rec:
             parts.append(f"comm={rec['comm_bits'] / 8 / 2**20:.1f}MB")
+        if "round_s" in rec:
+            parts.append(f"round={rec['round_s']:.3f}s")
         parts.append(f"({rec['wall_s']:.1f}s)")
         return " ".join(parts)
 
@@ -121,11 +123,13 @@ class RunLog:
         self._emit({"kind": "info", "msg": msg})
 
     def round(self, t: int, loss: float, console: bool = True,
-              **fields: Any) -> None:
+              **fields: Any) -> dict:
+        """Emit one round record and return it."""
         rec = {"kind": "round", "t": int(t), "loss": float(loss),
                "wall_s": time.time() - self._t0}
         rec.update({k: v for k, v in fields.items() if v is not None})
         self._emit(rec, console=console)
+        return rec
 
     def end(self, rounds: int, **fields: Any) -> None:
         rec = {"kind": "run_end", "rounds": int(rounds),

@@ -473,10 +473,13 @@ def test_capped_partial_skip_reduces_flops():
 def test_async_ready_capacity_same_numerics():
     """The async analogue of the padded gather: with ``ready_capacity``
     set, each event trains only (up to) cap gathered ready lanes instead
-    of vmapping local SGD over all m — and the trajectory is BITWISE
-    identical to the full-width engine, because overflow lanes keep
-    their elapsed clocks and fire in immediately-following zero-duration
-    events (graceful event splitting, not dropped work)."""
+    of vmapping local SGD over all m — and the trajectory is the
+    full-width engine's, because overflow lanes keep their elapsed clocks
+    and fire in immediately-following zero-duration events (graceful
+    event splitting, not dropped work). The event schedule (ready
+    fraction, clock) is exact; the two engines are separately compiled
+    programs, so their floats agree to a few ulp (XLA picks FMA
+    contraction per module), as in the other parity tests."""
     from repro.core import make_async_round_step
     params, loss_fn, batches = dot_problem()
     cfg = DFedAvgMConfig(eta=0.05, theta=0.9, local_steps=4)
@@ -488,14 +491,18 @@ def test_async_ready_capacity_same_numerics():
         loss_fn, cfg, spec, AsyncConfig(speed=speed, ready_capacity=1)))
     s1 = init_async_state(params, jax.random.PRNGKey(0), speed)
     s2 = init_async_state(params, jax.random.PRNGKey(0), speed)
+    eps = float(np.finfo(np.float32).eps)
     for _ in range(12):
         s1, m1 = full(s1, batches)
         s2, m2 = skip(s2, batches)
-        assert float(m1["loss"]) == float(m2["loss"])
+        l1 = float(m1["loss"])
+        assert abs(l1 - float(m2["loss"])) <= 8 * eps * (abs(l1) + 1.0)
         assert float(m1["ready_frac"]) == float(m2["ready_frac"])
         for a, b in zip(jax.tree.leaves(s1.params),
                         jax.tree.leaves(s2.params)):
-            assert np.array_equal(np.asarray(a), np.asarray(b))
+            a, b = np.asarray(a), np.asarray(b)
+            tol = 8 * eps * (float(np.abs(a).max()) + 1.0)
+            np.testing.assert_allclose(b, a, rtol=0, atol=tol)
     assert float(s1.clock) == float(s2.clock)
 
 

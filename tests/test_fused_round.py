@@ -48,6 +48,8 @@ def run_sub(code: str, devices: int = 8, timeout: int = 900) -> str:
         env.get("XLA_FLAGS", "") +
         f" --xla_force_host_platform_device_count={devices}").strip()
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    # A CPU run with fake host devices: never the parent's accelerator.
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, timeout=timeout,
                        env=env)
@@ -249,25 +251,13 @@ def test_fused_round_kernel_structure():
     mesh = Mesh(np.array(jax.devices()[:M]), ("clients",))
     spec = MixingSpec.ring(M, self_weight=0.5)
 
+    from repro.launch.hlo_stats import jaxpr_eqns, pallas_kernel_names
+
     def kernel_names_and_scans(step, st, batches):
         jx = jax.make_jaxpr(step)(st, batches)
-        names, scans = [], []
-
-        def walk(j):
-            for e in j.eqns:
-                if e.primitive.name == "pallas_call":
-                    nsi = str(e.params.get("name_and_src_info"))
-                    names.append(nsi.split(" at ")[0])
-                if e.primitive.name == "scan":
-                    scans.append(int(e.params["length"]))
-                for v in e.params.values():
-                    if hasattr(v, "eqns"):
-                        walk(v)
-                    elif hasattr(v, "jaxpr"):
-                        walk(v.jaxpr)
-
-        walk(jx.jaxpr)
-        return names, scans
+        scans = [int(e.params["length"]) for e in jaxpr_eqns(jx)
+                 if e.primitive.name == "scan"]
+        return pallas_kernel_names(jx), scans
 
     def build(q, fuse):
         cfg = DFedAvgMConfig(eta=0.05, theta=0.9, local_steps=K, quant=q,
@@ -283,8 +273,8 @@ def test_fused_round_kernel_structure():
 
     # fused q8: one fused encode + one fused decode, nothing else
     names, scans = build(q8, fuse=True)
-    enc = [n for n in names if "momentum_quantize_pack" in n]
-    dec = [n for n in names if "dequant_mix_momentum" in n]
+    enc = [n for n in names if n == "momentum_quantize_pack_buffer"]
+    dec = [n for n in names if n == "dequant_mix_momentum_buffer"]
     assert len(enc) == 1, names
     assert len(dec) == 1, names
     assert len(names) == 2, names           # no standalone/plain kernels
@@ -299,8 +289,8 @@ def test_fused_round_kernel_structure():
 
     # unfused q8 contrast: plain codec kernels, full-length scan
     names, scans = build(q8, fuse=False)
-    assert any(n == "_quantize_pack_kernel" for n in names), names
-    assert any(n == "_dequant_mix_buffer_kernel" for n in names), names
+    assert "quantize_pack_buffer" in names, names
+    assert "dequant_mix_buffer" in names, names
     assert not any("momentum_quantize_pack" in n for n in names), names
     assert K in scans, scans
     print("OK")

@@ -7,10 +7,12 @@ hypothesis sweep over random graphs rides along at the bottom. The
 shard_map realization of the same plans is exercised on a real CPU mesh
 in test_sparse_backend_mesh.py (subprocess, 8 host devices).
 """
+import hypothesis
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (MixerConfig, MixingSpec, QuantConfig,
                         TopologySchedule, execute_plan_reference, make_mixer,
@@ -345,33 +347,24 @@ def test_plan_round_bits_is_a_wire_diagnostic_not_the_bill():
 
 
 # ---------------------------------------------------------------------------
-# Hypothesis sweep (guarded: bare environments skip, CI runs it)
+# Hypothesis sweep
 # ---------------------------------------------------------------------------
 
-try:
-    import hypothesis
-    from hypothesis import given, settings, strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:                                # pragma: no cover
-    HAVE_HYPOTHESIS = False
-
-if HAVE_HYPOTHESIS:
-
-    @settings(deadline=None, max_examples=25)
-    @given(m=st.integers(4, 12), p=st.floats(0.2, 0.9),
-           seed=st.integers(0, 1000))
-    def test_property_random_graph_plan_equivalence(m, p, seed):
-        """Any connected random graph: the plan rebuilds Metropolis W
-        exactly and the plan executor matches the dense einsum."""
-        try:
-            g = erdos_renyi_graph(m, p, seed=seed)
-        except RuntimeError:
-            hypothesis.assume(False)
-        spec = MixingSpec.dense(g)
-        plan = spec.gossip_plan()
-        np.testing.assert_array_equal(plan.as_matrix(), spec.W)
-        z = {"w": jax.random.normal(jax.random.PRNGKey(seed), (m, 5))}
-        out = execute_plan_reference(plan, spec.W, z)["w"]
-        ref = mix_dense(spec.W, z)["w"]
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-6)
+@settings(deadline=None, max_examples=25)
+@given(m=st.integers(4, 12), p=st.floats(0.2, 0.9),
+       seed=st.integers(0, 1000))
+def test_property_random_graph_plan_equivalence(m, p, seed):
+    """Any connected random graph: the plan rebuilds Metropolis W
+    exactly and the plan executor matches the dense einsum."""
+    try:
+        g = erdos_renyi_graph(m, p, seed=seed)
+    except RuntimeError:
+        hypothesis.assume(False)
+    spec = MixingSpec.dense(g)
+    plan = spec.gossip_plan()
+    np.testing.assert_array_equal(plan.as_matrix(), spec.W)
+    z = {"w": jax.random.normal(jax.random.PRNGKey(seed), (m, 5))}
+    out = execute_plan_reference(plan, spec.W, z)["w"]
+    ref = mix_dense(spec.W, z)["w"]
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-6)

@@ -1,7 +1,6 @@
 """HLO collective parser + jaxpr structural cost model."""
 import jax
 import jax.numpy as jnp
-import pytest
 import numpy as np
 
 from repro.launch.cost_model import structural_costs
@@ -89,10 +88,8 @@ def test_structural_costs_counts_grad_and_remat():
 
 
 def test_structural_costs_collectives():
-    if not hasattr(jax, "shard_map"):
-        pytest.skip("jax.shard_map requires a newer jax release")
-    from repro.launch.mesh import auto_axis_types_kw
-    mesh = jax.make_mesh((1,), ("x",), **auto_axis_types_kw(1))
+    from repro.launch.mesh import make_test_mesh
+    mesh = make_test_mesh((1,), ("x",))
 
     def f(a):
         return jax.shard_map(

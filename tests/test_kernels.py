@@ -5,11 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:  # dev dep: a bare env runs a fixed-grid fallback, not zero tests
-    from hypothesis import given, settings, strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:
-    HAVE_HYPOTHESIS = False
+from hypothesis import given, settings, strategies as st
 
 from repro.core import local_train
 from repro.kernels import (decode_apply_plan, decode_apply_ring,
@@ -106,18 +102,11 @@ def _check_momentum_flat(n, theta, eta):
     np.testing.assert_allclose(np.asarray(vo), np.asarray(vr), atol=1e-6)
 
 
-if HAVE_HYPOTHESIS:
-    @given(st.integers(1, 40000), st.sampled_from([0.0, 0.5, 0.9, 0.99]),
-           st.sampled_from([1e-3, 1e-2, 0.1]))
-    @settings(max_examples=25, deadline=None)
-    def test_momentum_matches_ref(n, theta, eta):
-        _check_momentum_flat(n, theta, eta)
-else:
-    @pytest.mark.parametrize("n", (1, 513, 40000))
-    @pytest.mark.parametrize("theta", (0.0, 0.9))
-    @pytest.mark.parametrize("eta", (1e-3, 0.1))
-    def test_momentum_matches_ref(n, theta, eta):
-        _check_momentum_flat(n, theta, eta)
+@given(st.integers(1, 40000), st.sampled_from([0.0, 0.5, 0.9, 0.99]),
+       st.sampled_from([1e-3, 1e-2, 0.1]))
+@settings(max_examples=25, deadline=None)
+def test_momentum_matches_ref(n, theta, eta):
+    _check_momentum_flat(n, theta, eta)
 
 
 def test_fused_update_in_local_train_bitexact():

@@ -37,6 +37,8 @@ def run_sub(code: str, devices: int = 8, timeout: int = 900) -> str:
         env.get("XLA_FLAGS", "") +
         f" --xla_force_host_platform_device_count={devices}").strip()
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    # A CPU run with fake host devices: never the parent's accelerator.
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, timeout=timeout,
                        env=env)
@@ -278,13 +280,18 @@ def test_2d_train_driver_production_config():
     """The sliced production config end to end through the real CLI
     driver: gemma-7b (reduced) on the (2, 4) mesh, strategy-A rules
     sharding 8/11 leaves, quantized gossip — trains, logs the 2D mesh
-    line and the per-device wire reduction, and the loss moves."""
+    line and the per-device wire reduction, the loss moves, and every
+    round hits the one dispatch-cache entry."""
     out = run_sub("""
     from repro.launch.train import main
-    main(["--arch", "gemma-7b", "--reduced", "--clients", "2",
-          "--model-parallel", "4", "--rounds", "3", "--bits", "8",
-          "--local-steps", "2", "--batch", "2", "--seq", "16"])
+    res = main(["--arch", "gemma-7b", "--reduced", "--clients", "2",
+                "--model-parallel", "4", "--rounds", "3", "--bits", "8",
+                "--local-steps", "2", "--batch", "2", "--seq", "16"])
+    print("STEP_SIGNATURES", res.step._cache_size())
     """, timeout=900)
+    # The model-sharded params go in as they come out: every round hits
+    # the one entry of jit's dispatch cache.
+    assert "STEP_SIGNATURES 1" in out, out
     assert "2D mesh: model_parallel=4" in out
     assert "param leaves model-sharded" in out
     assert "4.0x reduction" in out

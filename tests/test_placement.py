@@ -28,10 +28,12 @@ import subprocess
 import sys
 import warnings
 
+import hypothesis
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (DFedAvgMConfig, MixingSpec, QuantConfig,
                         average_params, compute_placement,
@@ -184,32 +186,23 @@ def test_placed_reference_bitwise_all_quant_modes():
 
 
 # ---------------------------------------------------------------------------
-# Hypothesis sweep (guarded: bare environments skip, CI runs it)
+# Hypothesis sweep
 # ---------------------------------------------------------------------------
 
-try:
-    import hypothesis
-    from hypothesis import given, settings, strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:                                # pragma: no cover
-    HAVE_HYPOTHESIS = False
-
-if HAVE_HYPOTHESIS:
-
-    @settings(deadline=None, max_examples=15)
-    @given(p=st.floats(0.25, 0.8), gseed=st.integers(0, 500),
-           pseed=st.integers(0, 500), dseed=st.integers(0, 500))
-    def test_property_placed_bitwise_random_graph_and_perm(
-            p, gseed, pseed, dseed):
-        """Any connected random graph x any random permutation: the
-        placed reference replays each client's exact arithmetic on its
-        new lane (fp32 and both quantized modes, stochastic draws
-        included)."""
-        try:
-            g = erdos_renyi_graph(8, p, seed=gseed)
-        except RuntimeError:
-            hypothesis.assume(False)
-        _check_placed_bitwise(g, perm_seed=pseed, data_seed=dseed)
+@settings(deadline=None, max_examples=15)
+@given(p=st.floats(0.25, 0.8), gseed=st.integers(0, 500),
+       pseed=st.integers(0, 500), dseed=st.integers(0, 500))
+def test_property_placed_bitwise_random_graph_and_perm(
+        p, gseed, pseed, dseed):
+    """Any connected random graph x any random permutation: the
+    placed reference replays each client's exact arithmetic on its
+    new lane (fp32 and both quantized modes, stochastic draws
+    included)."""
+    try:
+        g = erdos_renyi_graph(8, p, seed=gseed)
+    except RuntimeError:
+        hypothesis.assume(False)
+    _check_placed_bitwise(g, perm_seed=pseed, data_seed=dseed)
 
 
 # ---------------------------------------------------------------------------
@@ -264,6 +257,7 @@ def test_single_sparse_executor_lint_passes():
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools",
                                       "check_single_executor.py")],
-        capture_output=True, text=True, timeout=60)
+        capture_output=True, text=True, timeout=60,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr}"
     assert "_make_sparse_exec" in r.stdout

@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-pytest.importorskip("hypothesis")  # dev dep: bare env skips, not errors
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (DFedAvgMConfig, MixingSpec, QuantConfig,

@@ -5,15 +5,6 @@ import subprocess
 import sys
 import textwrap
 
-import jax
-import pytest
-
-# The sharded mixers drive jax.set_mesh / jax.shard_map in subprocesses;
-# both APIs need newer jax than some containers ship. CI (latest CPU jax)
-# always runs these.
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax, "set_mesh"),
-    reason="jax.set_mesh requires a newer jax release")
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -22,6 +13,8 @@ def run_sub(code: str, devices: int = 8, timeout: int = 900) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    # A CPU run with fake host devices: never the parent's accelerator.
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, timeout=timeout,
                        env=env)
@@ -35,8 +28,8 @@ def test_ring_mixer_matches_dense_on_mesh():
         from repro.core import MixingSpec, QuantConfig
         from repro.core.mixing import (make_ring_mixer, mix_dense,
                                        _mix_dense_quantized)
-        from repro.launch.mesh import auto_axis_types_kw
-        mesh = jax.make_mesh((8,), ("clients",), **auto_axis_types_kw(1))
+        from repro.launch.mesh import make_test_mesh
+        mesh = make_test_mesh((8,), ("clients",))
         m, d = 8, 65
         spec = MixingSpec.ring(m)
         x = jax.random.normal(jax.random.PRNGKey(0), (m, d))
@@ -67,8 +60,8 @@ def test_quantized_wire_is_u32_in_hlo():
         import jax, jax.numpy as jnp
         from repro.core import MixingSpec, QuantConfig
         from repro.core.mixing import make_ring_mixer
-        from repro.launch.mesh import auto_axis_types_kw
-        mesh = jax.make_mesh((8,), ("clients",), **auto_axis_types_kw(1))
+        from repro.launch.mesh import make_test_mesh
+        mesh = make_test_mesh((8,), ("clients",))
         spec = MixingSpec.ring(8)
         qc = QuantConfig(bits=8, stochastic=False, delta_mode="eq7")
         rq = make_ring_mixer(spec, mesh, ("clients",), quant=qc)
@@ -107,8 +100,8 @@ def test_sharded_train_round_matches_single_device():
         s_ref = init_round_state({"w": jnp.zeros((m, d))},
                                  jax.random.PRNGKey(7))
         # sharded: ring mixer via shard_map
-        from repro.launch.mesh import auto_axis_types_kw
-        mesh = jax.make_mesh((8,), ("clients",), **auto_axis_types_kw(1))
+        from repro.launch.mesh import make_test_mesh
+        mesh = make_test_mesh((8,), ("clients",))
         pspecs = {"w": P("clients", None)}
         cfg_r = DFedAvgMConfig(eta=0.05, theta=0.5, local_steps=4,
                                quant=QuantConfig(bits=8, stochastic=False),
@@ -170,13 +163,13 @@ def test_torus_mixer_matches_dense_both_layouts():
         z = jax.random.normal(jax.random.PRNGKey(1), (8, 33))
         spec = MixingSpec.torus(2, 4)
         ref = mix_dense(spec.W, {"w": z})["w"]
-        from repro.launch.mesh import auto_axis_types_kw
-        m1 = jax.make_mesh((8,), ("clients",), **auto_axis_types_kw(1))
+        from repro.launch.mesh import make_test_mesh
+        m1 = make_test_mesh((8,), ("clients",))
         mx = make_torus_mixer(spec, m1, ("clients",))
         with jax.set_mesh(m1):
             o1 = jax.jit(lambda zz: mx(None, zz))({"w": z})["w"]
         assert float(jnp.max(jnp.abs(o1 - ref))) < 1e-5
-        m2 = jax.make_mesh((2, 4), ("pod", "data"), **auto_axis_types_kw(2))
+        m2 = make_test_mesh((2, 4), ("pod", "data"))
         mx2 = make_torus_mixer(spec, m2, ("pod", "data"))
         with jax.set_mesh(m2):
             o2 = jax.jit(lambda zz: mx2(None, zz))({"w": z})["w"]

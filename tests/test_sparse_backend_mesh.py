@@ -35,6 +35,8 @@ def run_sub(code: str, devices: int = 8, timeout: int = 900) -> str:
         env.get("XLA_FLAGS", "") +
         f" --xla_force_host_platform_device_count={devices}").strip()
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    # A CPU run with fake host devices: never the parent's accelerator.
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, timeout=timeout,
                        env=env)
@@ -225,10 +227,7 @@ def test_flat_wire_words_bitwise_mesh_vs_reference():
     out = run_sub(_PRELUDE + """
     from repro.core.wire_layout import WireLayout
     from repro.core.mixing import _quant_leaf_keys
-    try:
-        sm = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm
+    sm = jax.shard_map
     xt = {"w": x, "b": jax.random.normal(jax.random.PRNGKey(4), (M, 3, 2))}
     zt = {"w": z, "b": jax.random.normal(jax.random.PRNGKey(5), (M, 3, 2))}
     for q in (QuantConfig(bits=8, stochastic=False, delta_mode="eq7"),
@@ -630,23 +629,19 @@ def test_unified_executor_one_permute_per_step_m_local_1():
     size is pinned tiny here). No all-gather, no f32 wire when
     quantized."""
     out = run_sub(_PRELUDE + """
-    import re
-    def wire_permutes(txt, min_elems):
-        wires, small = [], []
-        for l in txt.splitlines():
-            ls = l.strip()
-            if not ls.startswith("%collective-permute"):
-                continue
-            if "-done(" in ls or "collective-permute-start(" in ls:
-                continue
-            shape = re.match(r"%\\S+\\s*=\\s*(\\w+)\\[([\\d,]*)\\]", ls)
-            dtype, dims = shape.group(1), shape.group(2)
-            n = 1
-            for d in dims.split(","):
-                if d:
-                    n *= int(d)
-            (wires if n >= min_elems else small).append((dtype, n))
-        return wires, small
+    from repro.core.wire_layout import WireLayout
+    from repro.launch.hlo_stats import collect_collectives
+    def wire_permutes(txt, min_bytes):
+        sizes = [b for kind, b in collect_collectives(txt).per_op
+                 if kind == "collective-permute"]
+        return ([b for b in sizes if b >= min_bytes],
+                [b for b in sizes if b < min_bytes])
+    lay = WireLayout.for_tree({"w": x[0]}, bits=8)
+    # fp32: one f32 row; q8 lemma5: the u32 stream of packed words,
+    # per-leaf scales and the f32 replica buffer.
+    wire_bytes = {None: 4 * D,
+                  "q8": 4 * (lay.total_words + lay.n_leaves
+                             + lay.per * lay.total_words)}
     specs = [MixingSpec.ring(M, self_weight=0.5),
              MixingSpec.torus(2, M // 2),
              MixingSpec.dense(erdos_renyi_graph(M, 0.5, seed=3))]
@@ -660,14 +655,13 @@ def test_unified_executor_one_permute_per_step_m_local_1():
                                     jax.random.PRNGKey(0),
                                     0).compile().as_text()
             assert "all-gather" not in txt, spec.kind
-            wires, small = wire_permutes(txt, min_elems=D)
+            wires, small = wire_permutes(txt, min_bytes=4 * D)
             assert len(wires) == plan.n_steps, \\
                 (spec.kind, q and q.delta_mode, wires, small)
             # key-split artifacts stay word-sized, far below the payload
-            assert all(n < D for _, n in small), (spec.kind, small)
-            if q is not None:
-                assert all(t == "u32" for t, _ in wires), \\
-                    (spec.kind, wires)
+            assert all(b < 4 * D for b in small), (spec.kind, small)
+            want = wire_bytes["q8" if q is not None else None]
+            assert all(b == want for b in wires), (spec.kind, wires, want)
             print("UNIFIED_OK", spec.kind, plan.n_steps,
                   "q8" if q else "fp32")
     """)

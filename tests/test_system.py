@@ -2,6 +2,9 @@
 placeholder): full training runs reproducing the paper's qualitative
 claims at small scale, plus the serving path."""
 import dataclasses
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -100,7 +103,82 @@ def test_serve_pipeline_runs():
 
 def test_train_driver_cli():
     from repro.launch.train import main as train_main
-    state, metrics = train_main([
+    res = train_main([
         "--arch", "smollm-135m", "--rounds", "4", "--clients", "4",
         "--batch", "2", "--seq", "32", "--bits", "8"])
-    assert bool(jnp.isfinite(metrics["loss"]))
+    assert bool(jnp.isfinite(res.metrics["loss"]))
+    # rounds <= 10 log every round, each with its host time
+    assert [r["t"] for r in res.rounds] == [0, 1, 2, 3]
+    assert all(r["round_s"] > 0 for r in res.rounds)
+
+
+def test_train_driver_layers_cut_keeps_widths():
+    """``--layers`` cuts only the depth: the stacked layer axis shrinks,
+    every width stays the (reduced) config's."""
+    from repro.launch.train import main as train_main
+    res = train_main(["--arch", "olmo-1b", "--layers", "1", "--rounds", "1",
+                      "--clients", "2", "--local-steps", "1",
+                      "--batch", "1", "--seq", "8"])
+    cfg = reduced(get_config("olmo-1b"))
+    assert res.state.params["embed"]["table"].shape == (2, cfg.vocab_size,
+                                                        cfg.d_model)
+    for leaf in jax.tree.leaves(res.state.params["stages"]):
+        assert leaf.shape[:2] == (2, 1), leaf.shape
+
+
+def test_train_driver_traces_step_once_on_mesh():
+    """Client state placed on a one-device client mesh goes in as the step
+    gives it back (size-1 mesh axes dropped), so every round hits the one
+    entry of jit's dispatch cache."""
+    from repro.launch.train import main as train_main
+    res = train_main(["--arch", "olmo-1b", "--layers", "1", "--rounds", "3",
+                      "--clients", "2", "--clients-per-shard", "2",
+                      "--mixer-impl", "sparse", "--bits", "8",
+                      "--local-steps", "1", "--batch", "1", "--seq", "8"])
+    assert res.step._cache_size() == 1
+
+
+@pytest.mark.parametrize("from_env", (True, False))
+def test_compile_cache_dir(tmp_path, from_env):
+    """A set JAX_COMPILATION_CACHE_DIR receives the compiles and is left
+    as JAX's own setting; unset, the cache goes to <repo>/.jax_cache."""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(JAX_PLATFORMS="cpu",
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               PYTHONPATH=os.pathsep.join(sys.path))
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    code = """
+import jax, jax.numpy as jnp
+from repro.launch.compile_cache import REPO_CACHE_DIR, use_compile_cache
+where = use_compile_cache()
+assert jax.config.jax_compilation_cache_dir == where, where
+if "{tmp}" == "-":
+    assert where == str(REPO_CACHE_DIR), where
+else:
+    assert where == "{tmp}", where
+    jax.jit(lambda x: x * 3 + 1)(jnp.arange(8.0)).block_until_ready()
+print("CACHE_OK")
+""".format(tmp=tmp_path if from_env else "-")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=300, env=env)
+    assert "CACHE_OK" in r.stdout, r.stdout + r.stderr
+    if from_env:
+        assert any(tmp_path.iterdir()), "no compile landed in the cache"
+
+
+def test_chip_path_never_imports_dryrun():
+    """``launch/dryrun.py`` rewrites XLA_FLAGS when imported; the chip
+    path (the training entry point and ``chip_smoke.py``) must not load
+    it."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("import sys, chip_smoke, repro.launch.train; "
+            "assert 'repro.launch.dryrun' not in sys.modules; print('OK')")
+    r = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=120, cwd=repo,
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "PYTHONPATH": os.pathsep.join([os.path.join(repo, "src"),
+                                            repo])})
+    assert r.stdout.strip() == "OK", r.stdout + r.stderr

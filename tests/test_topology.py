@@ -2,7 +2,6 @@
 import numpy as np
 import pytest
 
-pytest.importorskip("hypothesis")  # dev dep: bare env skips, not errors
 from hypothesis import given, settings, strategies as st
 
 from repro.core.topology import (Graph, MixingSpec, check_mixing_matrix,
