@@ -255,14 +255,16 @@ def make_round_step(loss_fn: LossFn, cfg: DFedAvgMConfig,
                                                           client_axes)
 
     def round_step(state: RoundState, batches: Pytree):
-        key_round, key_mix, key_next = jax.random.split(state.rng, 3)
-        client_keys = jax.random.split(key_round, m)
-        if perm is not None:
-            # Lane order: lane p trains client perm[p] — its batches and
-            # its round key. Keys derive in CLIENT order first (single
-            # source of truth), so placed == unplaced bitwise per client.
-            batches = jax.tree.map(lambda b: b[perm], batches)
-            client_keys = client_keys[perm]
+        with jax.named_scope("round/keys"):
+            key_round, key_mix, key_next = jax.random.split(state.rng, 3)
+            client_keys = jax.random.split(key_round, m)
+            if perm is not None:
+                # Lane order: lane p trains client perm[p] — its batches
+                # and its round key. Keys derive in CLIENT order first
+                # (single source of truth), so placed == unplaced bitwise
+                # per client.
+                batches = jax.tree.map(lambda b: b[perm], batches)
+                client_keys = client_keys[perm]
 
         train_one = lambda p, b, k: local_train(
             loss_fn, p, b, k, eta=cfg.eta, theta=cfg.theta,
@@ -274,25 +276,29 @@ def make_round_step(loss_fn: LossFn, cfg: DFedAvgMConfig,
         # key_mix, so sampling here is not a second draw.
         token_next = state.token
         active = None
-        if stateful:
-            if state.token is None:
-                raise ValueError(
-                    "stateful schedule: seed the walk with "
-                    "init_round_state(..., token=spec.init_token())")
-            W_t, active, key_q, token_next = spec.token_event(key_mix,
-                                                              state.token)
-        elif skip:
-            # Telemetry keeps the round's W_t / key_q in hand — the SAME
-            # event from the same key, not a second draw.
-            if with_telemetry:
-                W_t, active, key_q = spec.round_event(key_mix, state.round)
-            else:
-                _, active, _ = spec.round_event(key_mix, state.round)
-        elif scheduled and with_telemetry:
-            W_t, _, key_q = spec.round_event(key_mix, state.round)
-        if perm is not None and active is not None:
-            # Schedule events are CLIENT-order; state is lane-order.
-            active = active[perm]
+        # The round's mixing event (W_t and the active mask) is the
+        # mixer's per-round exchange weights, wherever it is drawn.
+        with jax.named_scope("round/mix/wire/exchange"):
+            if stateful:
+                if state.token is None:
+                    raise ValueError(
+                        "stateful schedule: seed the walk with "
+                        "init_round_state(..., token=spec.init_token())")
+                W_t, active, key_q, token_next = spec.token_event(
+                    key_mix, state.token)
+            elif skip:
+                # Telemetry keeps the round's W_t / key_q in hand — the
+                # SAME event from the same key, not a second draw.
+                if with_telemetry:
+                    W_t, active, key_q = spec.round_event(key_mix,
+                                                          state.round)
+                else:
+                    _, active, _ = spec.round_event(key_mix, state.round)
+            elif scheduled and with_telemetry:
+                W_t, _, key_q = spec.round_event(key_mix, state.round)
+            if perm is not None and active is not None:
+                # Schedule events are CLIENT-order; state is lane-order.
+                active = active[perm]
 
         if skip:
             # Padded upper-bound gather: unused slots fill with the
@@ -301,18 +307,18 @@ def make_round_step(loss_fn: LossFn, cfg: DFedAvgMConfig,
             # so a round with fewer than k_active live clients stays
             # exact. Cohorts/walks fill every slot; capped i.i.d.
             # participation uses the slack.
-            idx = jnp.nonzero(active, size=k_active, fill_value=m)[0]
-            safe = jnp.minimum(idx, m - 1)
-            valid = (idx < m).astype(jnp.float32)
             with jax.named_scope("round/local_sgd"):
+                idx = jnp.nonzero(active, size=k_active, fill_value=m)[0]
+                safe = jnp.minimum(idx, m - 1)
+                valid = (idx < m).astype(jnp.float32)
                 z_sub, losses = jax.vmap(train_one)(
                     jax.tree.map(lambda p: p[safe], state.params),
                     jax.tree.map(lambda b: b[safe], batches),
                     client_keys[safe])
-            # Inactive lanes never trained: their z IS their held x.
-            z = jax.tree.map(
-                lambda xl, zl: xl.at[idx].set(zl, mode="drop"),
-                state.params, z_sub)
+                # Inactive lanes never trained: their z IS their held x.
+                z = jax.tree.map(
+                    lambda xl, zl: xl.at[idx].set(zl, mode="drop"),
+                    state.params, z_sub)
         else:
             with jax.named_scope("round/local_sgd"):
                 z, losses = jax.vmap(train_one)(state.params, batches,
@@ -329,25 +335,28 @@ def make_round_step(loss_fn: LossFn, cfg: DFedAvgMConfig,
                                        state.round)
             else:
                 x_next = mixer(state.params, z, key_mix, state.round)
-        if with_metrics and scheduled:
-            metrics["active_frac"] = jnp.mean(active)
-        # "loss" is the mean over clients that PARTICIPATED this round —
-        # inactive clients' lanes are either skipped (gathered path) or
-        # discarded, so averaging them in would mix in training that never
-        # entered the model. Identical whether compute-skip is on or off.
-        if skip:
-            # Mean over the VALID slots (== the active lanes; padded
-            # slots of a capped round trained a dummy and don't count).
-            metrics["loss"] = (jnp.sum(losses * valid)
-                               / jnp.maximum(valid.sum(), 1.0))
-        elif scheduled and spec.gates_participation:
-            metrics["loss"] = (jnp.sum(losses * active)
-                               / jnp.maximum(active.sum(), 1.0))
-        else:
-            metrics["loss"] = jnp.mean(losses)
-        if with_metrics or with_telemetry:
-            cdist = consensus_distance(x_next)
-            drift = consensus_distance(z)
+        with jax.named_scope("round/metrics"):
+            if with_metrics and scheduled:
+                metrics["active_frac"] = jnp.mean(active)
+            # "loss" is the mean over clients that PARTICIPATED this
+            # round — inactive clients' lanes are either skipped
+            # (gathered path) or discarded, so averaging them in would mix
+            # in training that never entered the model. Identical whether
+            # compute-skip is on or off.
+            if skip:
+                # Mean over the VALID slots (== the active lanes; padded
+                # slots of a capped round trained a dummy and don't
+                # count).
+                metrics["loss"] = (jnp.sum(losses * valid)
+                                   / jnp.maximum(valid.sum(), 1.0))
+            elif scheduled and spec.gates_participation:
+                metrics["loss"] = (jnp.sum(losses * active)
+                                   / jnp.maximum(active.sum(), 1.0))
+            else:
+                metrics["loss"] = jnp.mean(losses)
+            if with_metrics or with_telemetry:
+                cdist = consensus_distance(x_next)
+                drift = consensus_distance(z)
         if with_metrics:
             metrics["consensus_dist"] = cdist
             metrics["local_drift"] = drift
@@ -396,8 +405,10 @@ def make_round_step(loss_fn: LossFn, cfg: DFedAvgMConfig,
                     fields.update(quant_err_sq=qe, quant_bound=qb,
                                   quant_sat_frac=qs)
                 metrics["telemetry"] = Telemetry(**fields)
+        with jax.named_scope("round/keys"):
+            round_next = state.round + 1
         new_state = RoundState(params=x_next, rng=key_next,
-                               round=state.round + 1, token=token_next)
+                               round=round_next, token=token_next)
         return new_state, metrics
 
     return round_step
@@ -464,50 +475,55 @@ def _make_fused_round_step(loss_fn: LossFn, cfg: DFedAvgMConfig,
                                                        client_axes))
 
     def round_step(state: RoundState, batches: Pytree):
-        key_round, key_mix, key_next = jax.random.split(state.rng, 3)
-        client_keys = jax.random.split(key_round, m)
-        if perm is not None:
-            # Lane order: lane p trains client perm[p] (keys derive in
-            # client order first — see make_round_step).
-            batches = jax.tree.map(lambda b: b[perm], batches)
-            client_keys = client_keys[perm]
+        with jax.named_scope("round/keys"):
+            key_round, key_mix, key_next = jax.random.split(state.rng, 3)
+            client_keys = jax.random.split(key_round, m)
+            if perm is not None:
+                # Lane order: lane p trains client perm[p] (keys derive
+                # in client order first — see make_round_step).
+                batches = jax.tree.map(lambda b: b[perm], batches)
+                client_keys = client_keys[perm]
         K = jax.tree.leaves(batches)[0].shape[1]
 
         train_head = lambda p, b, k: local_train_deferred(
             loss_fn, p, b, k, eta=cfg.eta, theta=cfg.theta,
             fused_update=fused_update)
-        y, v, g, losses_head = jax.vmap(train_head)(
-            state.params, batches, client_keys)          # losses [m, K-1]
+        with jax.named_scope("round/local_sgd"):
+            y, v, g, losses_head = jax.vmap(train_head)(
+                state.params, batches, client_keys)      # losses [m, K-1]
 
-        if scheduled:
-            W_t, active, key_q = spec.round_event(key_mix, state.round)
-            if perm is not None:
-                active = active[perm]   # client-order event, lane state
-        else:
-            W_t = jnp.asarray(spec.W, jnp.float32)
-            active, key_q = ones, key_mix
-        batch_last = jax.tree.map(lambda b: b[:, K - 1], batches)
-        keys_last = jax.vmap(
-            lambda ck: jax.random.split(ck, K)[K - 1])(client_keys)
+        with jax.named_scope("round/mix/wire/exchange"):
+            if scheduled:
+                W_t, active, key_q = spec.round_event(key_mix, state.round)
+                if perm is not None:
+                    active = active[perm]  # client-order event, lane state
+            else:
+                W_t = jnp.asarray(spec.W, jnp.float32)
+                active, key_q = ones, key_mix
+        with jax.named_scope("round/keys"):
+            batch_last = jax.tree.map(lambda b: b[:, K - 1], batches)
+            keys_last = jax.vmap(
+                lambda ck: jax.random.split(ck, K)[K - 1])(client_keys)
 
-        x_next, y_pub, loss_last = tail(
-            state.params, y, v, g, batch_last, keys_last, key_q, active,
-            W_t)
-        losses = jnp.mean(
-            jnp.concatenate([losses_head, loss_last[:, None]], axis=1),
-            axis=1)                                      # [m], mean over K
-
+        with jax.named_scope("round/mix"):
+            x_next, y_pub, loss_last = tail(
+                state.params, y, v, g, batch_last, keys_last, key_q, active,
+                W_t)
         metrics = {}
-        if scheduled and spec.gates_participation:
-            metrics["loss"] = (jnp.sum(losses * active)
-                               / jnp.maximum(active.sum(), 1.0))
-        else:
-            metrics["loss"] = jnp.mean(losses)
-        if with_metrics and scheduled:
-            metrics["active_frac"] = jnp.mean(active)
-        if with_metrics or with_telemetry:
-            cdist = consensus_distance(x_next)
-            drift = consensus_distance(y_pub)
+        with jax.named_scope("round/metrics"):
+            losses = jnp.mean(
+                jnp.concatenate([losses_head, loss_last[:, None]], axis=1),
+                axis=1)                                  # [m], mean over K
+            if scheduled and spec.gates_participation:
+                metrics["loss"] = (jnp.sum(losses * active)
+                                   / jnp.maximum(active.sum(), 1.0))
+            else:
+                metrics["loss"] = jnp.mean(losses)
+            if with_metrics and scheduled:
+                metrics["active_frac"] = jnp.mean(active)
+            if with_metrics or with_telemetry:
+                cdist = consensus_distance(x_next)
+                drift = consensus_distance(y_pub)
         if with_metrics:
             metrics["consensus_dist"] = cdist
             metrics["local_drift"] = drift
@@ -526,8 +542,10 @@ def _make_fused_round_step(loss_fn: LossFn, cfg: DFedAvgMConfig,
                     placement_boundary_lanes=(
                         None if placement_lanes is None
                         else jnp.float32(placement_lanes)))
+        with jax.named_scope("round/keys"):
+            round_next = state.round + 1
         new_state = RoundState(params=x_next, rng=key_next,
-                               round=state.round + 1, token=state.token)
+                               round=round_next, token=state.token)
         return new_state, metrics
 
     return round_step

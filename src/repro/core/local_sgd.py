@@ -66,8 +66,11 @@ def local_train(loss_fn: LossFn, params: Pytree, batches: Pytree,
     def body(carry, inp):
         y, v = carry
         batch, k = inp
-        loss, g = grad_fn(y, batch, k)
-        y, v = heavy_ball_update(y, v, g, eta, theta, fused_fn=fused_update)
+        with jax.named_scope("sgd/grad"):
+            loss, g = grad_fn(y, batch, k)
+        with jax.named_scope("sgd/update"):
+            y, v = heavy_ball_update(y, v, g, eta, theta,
+                                     fused_fn=fused_update)
         return (y, v), loss
 
     keys = jax.random.split(key, K)
@@ -105,14 +108,18 @@ def local_train_deferred(loss_fn: LossFn, params: Pytree, batches: Pytree,
     def body(carry, inp):
         y, v = carry
         batch, k = inp
-        loss, g = grad_fn(y, batch, k)
-        y, v = heavy_ball_update(y, v, g, eta, theta, fused_fn=fused_update)
+        with jax.named_scope("sgd/grad"):
+            loss, g = grad_fn(y, batch, k)
+        with jax.named_scope("sgd/update"):
+            y, v = heavy_ball_update(y, v, g, eta, theta,
+                                     fused_fn=fused_update)
         return (y, v), loss
 
     keys = jax.random.split(key, K)
     head = jax.tree.map(lambda b: b[:K - 2], batches)
     (y, v), losses = jax.lax.scan(body, (params, v0), (head, keys[:K - 2]))
     batch_pen = jax.tree.map(lambda b: b[K - 2], batches)
-    loss_pen, g = grad_fn(y, batch_pen, keys[K - 2])
+    with jax.named_scope("sgd/grad"):
+        loss_pen, g = grad_fn(y, batch_pen, keys[K - 2])
     losses = jnp.concatenate([losses, loss_pen[None]])
     return y, v, g, losses

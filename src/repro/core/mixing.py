@@ -551,16 +551,26 @@ def _make_sparse_exec(plan: GossipPlan, mesh, client_axes: Sequence[str],
     if quant is None or not quant.enabled:
 
         def body(z_blocks, wself, wsteps):
-            s = sid()
+            with jax.named_scope("wire/exchange"):
+                s = sid()
             layout = WireLayout.for_tree(
                 jax.tree.map(lambda a: a[0], z_blocks))
-            rows = jax.vmap(layout.flatten_f32)(z_blocks)  # [m_local, n]
-            got = issue_recvs(rows, s)
-            acc = wself[:, None] * rows
+            with jax.named_scope("wire/pack"):
+                rows = jax.vmap(layout.flatten_f32)(z_blocks)  # [m_local, n]
+            with jax.named_scope("wire/exchange"):
+                got = issue_recvs(rows, s)
+            # The f32 wire has no codec: its decode is the weighted sum.
+            with jax.named_scope("wire/decode"):
+                acc = wself[:, None] * rows
             for k in live:
-                acc = acc + wsteps[k][:, None] * combine_recv(rows, got[k],
-                                                              k, s)
-            return jax.vmap(layout.unflatten)(acc)
+                with jax.named_scope("wire/decode"):
+                    w_k = wsteps[k][:, None]
+                with jax.named_scope("wire/exchange"):
+                    recv = combine_recv(rows, got[k], k, s)
+                with jax.named_scope("wire/decode"):
+                    acc = acc + w_k * recv
+            with jax.named_scope("wire/unpack"):
+                return jax.vmap(layout.unflatten)(acc)
 
         def ex(x, z, wself, wsteps, key=None):
             del x, key
@@ -572,8 +582,10 @@ def _make_sparse_exec(plan: GossipPlan, mesh, client_axes: Sequence[str],
             fn = jax.shard_map(body, mesh=mesh,
                                in_specs=(specs,) + w_specs, out_specs=specs,
                                check_vma=not sharded2d)
-            return fn(z, jnp.asarray(wself, jnp.float32),
-                      jnp.asarray(wsteps, jnp.float32))
+            with jax.named_scope("wire/exchange"):
+                wself = jnp.asarray(wself, jnp.float32)
+                wsteps = jnp.asarray(wsteps, jnp.float32)
+            return fn(z, wself, wsteps)
 
         return ex
 
@@ -582,68 +594,80 @@ def _make_sparse_exec(plan: GossipPlan, mesh, client_axes: Sequence[str],
     use_noise_input = sharded2d and quant.stochastic
 
     def q_body(x_blocks, z_blocks, keys_blk, wself, wsteps, *noise_in):
-        s = sid()
+        with jax.named_scope("wire/exchange"):
+            s = sid()
         layout = WireLayout.for_tree(jax.tree.map(lambda a: a[0], x_blocks),
                                      bits=quant.bits)
         nl, W = layout.n_leaves, layout.total_words
-        x2d = layout.to_planar_stacked(x_blocks)      # [m_local, per, W]
-        # Leaf-dtype subtraction before the f32 cast — the dense
-        # reference's (z - x).astype(f32) semantics.
-        delta = layout.to_planar_stacked(jax.tree.map(
-            lambda zl, xl: zl - xl, z_blocks, x_blocks))
-        if sharded2d and quant.scale_mode != "fixed":
-            # Model-sharded leaves: the local amax covers only this
-            # device's slice — all-reduce it over the model axes so every
-            # shard derives the IDENTICAL per-leaf scale (max is
-            # order-exact: bitwise equal to the 1D layout's scale).
-            amax = jax.lax.pmax(layout.leaf_amax(delta), maxes)
-            scales = layout.scales_from_amax(amax, quant)
-        else:
-            scales = layout.leaf_scales(delta, quant)  # [m_local, n_leaves]
-        leaf_keys = (jnp.transpose(keys_blk, (1, 0, 2))
-                     if quant.stochastic else None)   # [nl, m_local, 2]
-        noise2d = (layout.to_planar_stacked(noise_in[0])
-                   if noise_in else None)
+        with jax.named_scope("wire/pack"):
+            x2d = layout.to_planar_stacked(x_blocks)  # [m_local, per, W]
+            # Leaf-dtype subtraction before the f32 cast — the dense
+            # reference's (z - x).astype(f32) semantics.
+            delta = layout.to_planar_stacked(jax.tree.map(
+                lambda zl, xl: zl - xl, z_blocks, x_blocks))
+        with jax.named_scope("wire/scales"):
+            if sharded2d and quant.scale_mode != "fixed":
+                # Model-sharded leaves: the local amax covers only this
+                # device's slice — all-reduce it over the model axes so
+                # every shard derives the IDENTICAL per-leaf scale (max
+                # is order-exact: bitwise equal to the 1D layout's scale).
+                amax = jax.lax.pmax(layout.leaf_amax(delta), maxes)
+                scales = layout.scales_from_amax(amax, quant)
+            else:
+                scales = layout.leaf_scales(delta, quant)  # [m_local, nl]
+        with jax.named_scope("wire/encode/noise"):
+            leaf_keys = (jnp.transpose(keys_blk, (1, 0, 2))
+                         if quant.stochastic else None)  # [nl, m_local, 2]
+            noise2d = (layout.to_planar_stacked(noise_in[0])
+                       if noise_in else None)
         words = layout.encode(delta, scales, quant, leaf_keys=leaf_keys,
                               pallas=pallas, noise=noise2d)  # [m_local, W]
-        tail = [jax.lax.bitcast_convert_type(scales, jnp.uint32)]
-        if lemma5:
-            tail.append(jax.lax.bitcast_convert_type(
-                x2d.reshape(m_local, -1), jnp.uint32))
-        stream = jnp.concatenate([words] + tail, axis=1)  # [m_local, L]
-        got = issue_recvs(stream, s)
-        streams = [stream] + [combine_recv(stream, got[k], k, s)
-                              for k in live]
-        wlist = [wself] + [wsteps[k] for k in live]
-        weights = jnp.stack(wlist, axis=1)            # [m_local, K]
-        words_all, scales_all, xs = _split_streams(streams, W, nl,
-                                                   layout.per, lemma5)
-        base = _weighted_replica_base(xs, weights) if lemma5 else x2d
+        with jax.named_scope("wire/stream"):
+            tail = [jax.lax.bitcast_convert_type(scales, jnp.uint32)]
+            if lemma5:
+                tail.append(jax.lax.bitcast_convert_type(
+                    x2d.reshape(m_local, -1), jnp.uint32))
+            stream = jnp.concatenate([words] + tail, axis=1)  # [m_local, L]
+        with jax.named_scope("wire/exchange"):
+            got = issue_recvs(stream, s)
+            streams = [stream] + [combine_recv(stream, got[k], k, s)
+                                  for k in live]
+        with jax.named_scope("wire/stream"):
+            wlist = [wself] + [wsteps[k] for k in live]
+            weights = jnp.stack(wlist, axis=1)        # [m_local, K]
+            words_all, scales_all, xs = _split_streams(streams, W, nl,
+                                                       layout.per, lemma5)
+            base = _weighted_replica_base(xs, weights) if lemma5 else x2d
         out = layout.decode_apply(base, words_all, scales_all, weights,
                                   quant, pallas=pallas)
-        return layout.from_planar_stacked(out)
+        with jax.named_scope("wire/unpack"):
+            return layout.from_planar_stacked(out)
 
     def ex(x, z, wself, wsteps, key):
         specs = _full_specs(x, ca, param_specs)
         n_leaves = len(jax.tree.leaves(x))
-        if quant.stochastic:
-            keys = jnp.transpose(_quant_leaf_keys(key, n_leaves, plan.m),
-                                 (1, 0, 2))           # [m(client), nl, 2]
-            if plan.lane_to_client is not None:
-                # Lane p replays client lane_to_client[p]'s exact draws —
-                # key derivation stays in CLIENT space (single source of
-                # truth), so placed == unplaced bitwise.
-                keys = keys[jnp.asarray(plan.lane_to_client)]
-        else:
-            keys = jnp.zeros((plan.m, 1, 2), jnp.uint32)
-        if use_noise_input:
-            # 2D mesh: draw the FULL leaves' rounding noise here (where
-            # the unsharded geometry is known) and let shard_map slice
-            # each device's model block via the param specs.
-            extra = (_model_shard_noise(x, keys, plan.m),)
-            extra_specs = (specs,)
-        else:
-            extra, extra_specs = (), ()
+        with jax.named_scope("wire/encode/noise"):
+            if quant.stochastic:
+                keys = jnp.transpose(
+                    _quant_leaf_keys(key, n_leaves, plan.m),
+                    (1, 0, 2))                        # [m(client), nl, 2]
+                if plan.lane_to_client is not None:
+                    # Lane p replays client lane_to_client[p]'s exact
+                    # draws — key derivation stays in CLIENT space
+                    # (single source of truth), so placed == unplaced
+                    # bitwise.
+                    keys = keys[jnp.asarray(plan.lane_to_client)]
+            else:
+                keys = jnp.zeros((plan.m, 1, 2), jnp.uint32)
+            if use_noise_input:
+                # 2D mesh: draw the FULL leaves' rounding noise here
+                # (where the unsharded geometry is known) and let
+                # shard_map slice each device's model block via the
+                # param specs.
+                extra = (_model_shard_noise(x, keys, plan.m),)
+                extra_specs = (specs,)
+            else:
+                extra, extra_specs = (), ()
         # pallas_call has no replication rule, so the planar-wire body runs
         # with the checker off.
         fn = jax.shard_map(q_body, mesh=mesh,
@@ -651,8 +675,10 @@ def _make_sparse_exec(plan: GossipPlan, mesh, client_axes: Sequence[str],
                            + w_specs + extra_specs,
                            out_specs=specs,
                            check_vma=not (pallas or sharded2d))
-        return fn(x, z, keys, jnp.asarray(wself, jnp.float32),
-                  jnp.asarray(wsteps, jnp.float32), *extra)
+        with jax.named_scope("wire/exchange"):
+            wself = jnp.asarray(wself, jnp.float32)
+            wsteps = jnp.asarray(wsteps, jnp.float32)
+        return fn(x, z, keys, wself, wsteps, *extra)
 
     return ex
 
@@ -722,8 +748,11 @@ def make_event_mixer(m: int, quant: QuantConfig | None = None, mesh=None,
                                wire=wire)
 
         def mix_event(x, z, W, active, key=None):
-            w_self, w_steps = plan.gather_weights(W)
-            return ex(x, z_gate(active, z, x), w_self, w_steps, key)
+            with jax.named_scope("wire/exchange"):
+                w_self, w_steps = plan.gather_weights(W)
+            with jax.named_scope("wire/pack"):
+                z_eff = z_gate(active, z, x)
+            return ex(x, z_eff, w_self, w_steps, key)
 
         return mix_event
 
@@ -886,44 +915,62 @@ def make_fused_tail(loss_fn, m: int, *, eta: float, theta: float,
         # structure is identical to the quantized body.
         def body(x_bl, y_bl, v_bl, g_bl, batch_bl, klast_bl, wself, wsteps,
                  act):
-            s = sid()
+            with jax.named_scope("wire/exchange"):
+                s = sid()
             layout = WireLayout.for_tree(jax.tree.map(lambda a: a[0], x_bl))
             # Tree-level penultimate step: only the published z ever gets
             # flattened to a wire row (same layout traffic as the unfused
             # round); XLA fuses the elementwise chains.
             if gate:
-                y_bl = jax.tree.map(
-                    lambda yl, xl: jnp.where(
-                        act.reshape((-1,) + (1,) * (yl.ndim - 1)) > 0,
-                        yl, xl), y_bl, x_bl)
-                v_bl, g_bl = _gate0(v_bl, act), _gate0(g_bl, act)
-            v1 = jax.tree.map(
-                lambda vl, gl: theta_f * vl.astype(jnp.float32)
-                - eta_f * gl.astype(jnp.float32), v_bl, g_bl)
-            y1 = jax.tree.map(
-                lambda yl, vl: (yl.astype(jnp.float32) + vl)
-                .astype(yl.dtype), y_bl, v1)
-            z = jax.vmap(layout.flatten_f32)(y1)        # published y_{K-1}
-            got = issue_steps(z, s)
+                with jax.named_scope("wire/pack"):
+                    y_bl = jax.tree.map(
+                        lambda yl, xl: jnp.where(
+                            act.reshape((-1,) + (1,) * (yl.ndim - 1)) > 0,
+                            yl, xl), y_bl, x_bl)
+                    v_bl, g_bl = _gate0(v_bl, act), _gate0(g_bl, act)
+            with jax.named_scope("sgd/update"):
+                v1 = jax.tree.map(
+                    lambda vl, gl: theta_f * vl.astype(jnp.float32)
+                    - eta_f * gl.astype(jnp.float32), v_bl, g_bl)
+                y1 = jax.tree.map(
+                    lambda yl, vl: (yl.astype(jnp.float32) + vl)
+                    .astype(yl.dtype), y_bl, v1)
+            with jax.named_scope("wire/pack"):
+                z = jax.vmap(layout.flatten_f32)(y1)    # published y_{K-1}
+            with jax.named_scope("wire/exchange"):
+                got = issue_steps(z, s)
             # ---- overlap window: the last gradient computes while the
             # wire flies — nothing below reads a received buffer until
             # the weighted combine.
-            loss_last, gK = jax.vmap(grad_fn)(y1, batch_bl, klast_bl)
+            with jax.named_scope("sgd/grad"):
+                loss_last, gK = jax.vmap(grad_fn)(y1, batch_bl, klast_bl)
             if gate:
-                gK = _gate0(gK, act)
-            acc = wself[:, None] * z
+                with jax.named_scope("sgd/update"):
+                    gK = _gate0(gK, act)
+            # The f32 wire has no codec: its decode is the weighted sum.
+            with jax.named_scope("wire/decode"):
+                acc = wself[:, None] * z
             for k in live:
-                acc = acc + wsteps[k][:, None] * combine_step(z, got[k],
-                                                              k, s)
-            x_next = jax.tree.map(
-                lambda ml, vl, gl: (ml.astype(jnp.float32) + theta_f * vl
-                                    - eta_f * gl.astype(jnp.float32))
-                .astype(ml.dtype), jax.vmap(layout.unflatten)(acc), v1, gK)
+                with jax.named_scope("wire/decode"):
+                    w_k = wsteps[k][:, None]
+                with jax.named_scope("wire/exchange"):
+                    recv = combine_step(z, got[k], k, s)
+                with jax.named_scope("wire/decode"):
+                    acc = acc + w_k * recv
+            with jax.named_scope("wire/unpack"):
+                mixed = jax.vmap(layout.unflatten)(acc)
+            with jax.named_scope("sgd/update"):
+                x_next = jax.tree.map(
+                    lambda ml, vl, gl: (ml.astype(jnp.float32)
+                                        + theta_f * vl
+                                        - eta_f * gl.astype(jnp.float32))
+                    .astype(ml.dtype), mixed, v1, gK)
             return x_next, y1, loss_last
 
         def tail(x, y, v, g, batch_last, keys_last, key_q, active, W):
             del key_q
-            w_self, w_steps = plan.gather_weights(W)
+            with jax.named_scope("wire/exchange"):
+                w_self, w_steps = plan.gather_weights(W)
             specs = _full_specs(x, ca, param_specs)
             bspecs = _full_specs(batch_last, ca, None)
             fn = jax.shard_map(body, mesh=mesh,
@@ -931,41 +978,50 @@ def make_fused_tail(loss_fn, m: int, *, eta: float, theta: float,
                                          P(ca, None), P(ca), P(None, ca),
                                          P(ca)),
                                out_specs=(specs, specs, P(ca)))
-            return fn(x, y, v, g, batch_last, keys_last,
-                      jnp.asarray(w_self, jnp.float32),
-                      jnp.asarray(w_steps, jnp.float32),
-                      jnp.asarray(active, jnp.float32))
+            with jax.named_scope("wire/exchange"):
+                w_self = jnp.asarray(w_self, jnp.float32)
+                w_steps = jnp.asarray(w_steps, jnp.float32)
+                active = jnp.asarray(active, jnp.float32)
+            return fn(x, y, v, g, batch_last, keys_last, w_self, w_steps,
+                      active)
 
         return tail
 
     def q_body(x_bl, y_bl, v_bl, g_bl, batch_bl, klast_bl, keys_blk,
                wself, wsteps, act):
-        s = sid()
+        with jax.named_scope("wire/exchange"):
+            s = sid()
         layout = WireLayout.for_tree(jax.tree.map(lambda a: a[0], x_bl),
                                      bits=quant.bits)
         nl, Wd = layout.n_leaves, layout.total_words
-        x2d = layout.to_planar_stacked(x_bl)        # [m_local, per, W]
+        with jax.named_scope("wire/pack"):
+            x2d = layout.to_planar_stacked(x_bl)    # [m_local, per, W]
         m_loc = x2d.shape[0]
-        leaf_keys = (jnp.transpose(keys_blk, (1, 0, 2))
-                     if quant.stochastic else None)
+        with jax.named_scope("wire/encode/noise"):
+            leaf_keys = (jnp.transpose(keys_blk, (1, 0, 2))
+                         if quant.stochastic else None)
         if pallas:
             # Kernel path: y/v/g are staged planar so the fused kernels
             # stream them — the penultimate update + pack is ONE pass,
             # mix + deferred update is ONE pass.
-            y2d = layout.to_planar_stacked(y_bl)
-            v2d = layout.to_planar_stacked(v_bl)
-            g2d = layout.to_planar_stacked(g_bl)
-            if gate:
-                am = act[:, None, None]
-                y2d = jnp.where(am > 0, y2d, x2d)
-                v2d = v2d * am
-                g2d = g2d * am
-            et = jnp.tile(jnp.stack([eta_f, theta_f])[None], (m_loc, 1))
+            with jax.named_scope("wire/pack"):
+                y2d = layout.to_planar_stacked(y_bl)
+                v2d = layout.to_planar_stacked(v_bl)
+                g2d = layout.to_planar_stacked(g_bl)
+                if gate:
+                    am = act[:, None, None]
+                    y2d = jnp.where(am > 0, y2d, x2d)
+                    v2d = v2d * am
+                    g2d = g2d * am
+            with jax.named_scope("wire/encode"):
+                et = jnp.tile(jnp.stack([eta_f, theta_f])[None],
+                              (m_loc, 1))
             # Scales of the RESULTING delta, same expression order as the
             # fused kernel — a reduction, not another full-size buffer
             # pass.
-            delta = (y2d + (theta_f * v2d - eta_f * g2d)) - x2d
-            scales = layout.leaf_scales(delta, quant)  # [m_local, nl]
+            with jax.named_scope("wire/scales"):
+                delta = (y2d + (theta_f * v2d - eta_f * g2d)) - x2d
+                scales = layout.leaf_scales(delta, quant)  # [m_local, nl]
             # SEND: apply the penultimate step and emit the wire words as
             # a side output of the same pass.
             y_out, v_out, words = layout.encode_momentum(
@@ -977,86 +1033,107 @@ def make_fused_tail(loss_fn, m: int, *, eta: float, theta: float,
             # hand, and only z and x ever get planar-staged, matching the
             # unfused round's layout traffic.
             if gate:
-                y_bl = jax.tree.map(
-                    lambda yl, xl: jnp.where(
-                        act.reshape((-1,) + (1,) * (yl.ndim - 1)) > 0,
-                        yl, xl), y_bl, x_bl)
-                v_bl, g_bl = _gate0(v_bl, act), _gate0(g_bl, act)
-            v1 = jax.tree.map(
-                lambda vl, gl: theta_f * vl.astype(jnp.float32)
-                - eta_f * gl.astype(jnp.float32), v_bl, g_bl)
-            y1 = jax.tree.map(
-                lambda yl, vl: (yl.astype(jnp.float32) + vl)
-                .astype(yl.dtype), y_bl, v1)
-            z2d = layout.to_planar_stacked(y1)
-            delta = z2d - x2d
-            scales = layout.leaf_scales(delta, quant)  # [m_local, nl]
+                with jax.named_scope("wire/pack"):
+                    y_bl = jax.tree.map(
+                        lambda yl, xl: jnp.where(
+                            act.reshape((-1,) + (1,) * (yl.ndim - 1)) > 0,
+                            yl, xl), y_bl, x_bl)
+                    v_bl, g_bl = _gate0(v_bl, act), _gate0(g_bl, act)
+            with jax.named_scope("sgd/update"):
+                v1 = jax.tree.map(
+                    lambda vl, gl: theta_f * vl.astype(jnp.float32)
+                    - eta_f * gl.astype(jnp.float32), v_bl, g_bl)
+                y1 = jax.tree.map(
+                    lambda yl, vl: (yl.astype(jnp.float32) + vl)
+                    .astype(yl.dtype), y_bl, v1)
+            with jax.named_scope("wire/pack"):
+                z2d = layout.to_planar_stacked(y1)
+                delta = z2d - x2d
+            with jax.named_scope("wire/scales"):
+                scales = layout.leaf_scales(delta, quant)  # [m_local, nl]
             words = layout.encode(delta, scales, quant,
                                   leaf_keys=leaf_keys, pallas=False)
-        tail_ = [jax.lax.bitcast_convert_type(scales, jnp.uint32)]
-        if lemma5:
-            tail_.append(jax.lax.bitcast_convert_type(
-                x2d.reshape(m_loc, -1), jnp.uint32))
-        stream = jnp.concatenate([words] + tail_, axis=1)  # [m_local, L]
-        got = issue_steps(stream, s)
+        with jax.named_scope("wire/stream"):
+            tail_ = [jax.lax.bitcast_convert_type(scales, jnp.uint32)]
+            if lemma5:
+                tail_.append(jax.lax.bitcast_convert_type(
+                    x2d.reshape(m_loc, -1), jnp.uint32))
+            stream = jnp.concatenate([words] + tail_, axis=1)  # [m_loc, L]
+        with jax.named_scope("wire/exchange"):
+            got = issue_steps(stream, s)
         # ---- overlap window: the round's LAST gradient computes while
         # the wire flies — nothing below touches a received stream until
         # the fused decode.
-        y_pub = layout.from_planar_stacked(y_out) if pallas else y1
-        loss_last, gK = jax.vmap(grad_fn)(y_pub, batch_bl, klast_bl)
+        with jax.named_scope("wire/unpack"):
+            y_pub = layout.from_planar_stacked(y_out) if pallas else y1
+        with jax.named_scope("sgd/grad"):
+            loss_last, gK = jax.vmap(grad_fn)(y_pub, batch_bl, klast_bl)
         if pallas:
-            gK2d = layout.to_planar_stacked(gK)
-            if gate:
-                gK2d = gK2d * act[:, None, None]
+            with jax.named_scope("wire/pack"):
+                gK2d = layout.to_planar_stacked(gK)
+                if gate:
+                    gK2d = gK2d * act[:, None, None]
         elif gate:
-            gK = _gate0(gK, act)
-        streams = [stream] + [combine_step(stream, got[k], k, s)
-                              for k in live]
-        wlist = [wself] + [wsteps[k] for k in live]
-        weights = jnp.stack(wlist, axis=1)          # [m_local, K]
-        words_all, scales_all, xs = _split_streams(streams, Wd, nl,
-                                                   layout.per, lemma5)
-        base = _weighted_replica_base(xs, weights) if lemma5 else x2d
+            with jax.named_scope("sgd/update"):
+                gK = _gate0(gK, act)
+        with jax.named_scope("wire/exchange"):
+            streams = [stream] + [combine_step(stream, got[k], k, s)
+                                  for k in live]
+        with jax.named_scope("wire/stream"):
+            wlist = [wself] + [wsteps[k] for k in live]
+            weights = jnp.stack(wlist, axis=1)      # [m_local, K]
+            words_all, scales_all, xs = _split_streams(streams, Wd, nl,
+                                                       layout.per, lemma5)
+            base = _weighted_replica_base(xs, weights) if lemma5 else x2d
         # RECEIVE: mix + deferred last update in one fused pass (kernel
         # path) / one XLA-fused chain (oracle path).
         if pallas:
             out2d = layout.decode_apply_momentum(
                 base, words_all, scales_all, weights, v_out, gK2d, et,
                 quant, pallas=True)
-            return layout.from_planar_stacked(out2d), y_pub, loss_last
+            with jax.named_scope("wire/unpack"):
+                return layout.from_planar_stacked(out2d), y_pub, loss_last
         out2d = layout.decode_apply(base, words_all, scales_all, weights,
                                     quant, pallas=False)
-        x_next = jax.tree.map(
-            lambda ml, vl, gl: (ml.astype(jnp.float32) + theta_f * vl
-                                - eta_f * gl.astype(jnp.float32))
-            .astype(ml.dtype), layout.from_planar_stacked(out2d), v1, gK)
+        with jax.named_scope("wire/unpack"):
+            mixed = layout.from_planar_stacked(out2d)
+        with jax.named_scope("sgd/update"):
+            x_next = jax.tree.map(
+                lambda ml, vl, gl: (ml.astype(jnp.float32) + theta_f * vl
+                                    - eta_f * gl.astype(jnp.float32))
+                .astype(ml.dtype), mixed, v1, gK)
         return x_next, y_pub, loss_last
 
     def tail(x, y, v, g, batch_last, keys_last, key_q, active, W):
-        w_self, w_steps = plan.gather_weights(W)
+        with jax.named_scope("wire/exchange"):
+            w_self, w_steps = plan.gather_weights(W)
         specs = _full_specs(x, ca, param_specs)
         bspecs = _full_specs(batch_last, ca, None)
         n_leaves = len(jax.tree.leaves(x))
-        if quant.stochastic:
-            keys = jnp.transpose(_quant_leaf_keys(key_q, n_leaves, m),
-                                 (1, 0, 2))         # [m(client), nl, 2]
-            if plan.lane_to_client is not None:
-                # Placed plan: lane p replays client lane_to_client[p]'s
-                # draws (client-space key derivation, like the unfused
-                # executor) — placed == unplaced bitwise.
-                keys = keys[jnp.asarray(plan.lane_to_client)]
-        else:
-            keys = jnp.zeros((m, 1, 2), jnp.uint32)
+        with jax.named_scope("wire/encode/noise"):
+            if quant.stochastic:
+                keys = jnp.transpose(_quant_leaf_keys(key_q, n_leaves, m),
+                                     (1, 0, 2))     # [m(client), nl, 2]
+                if plan.lane_to_client is not None:
+                    # Placed plan: lane p replays client
+                    # lane_to_client[p]'s draws (client-space key
+                    # derivation, like the unfused executor) — placed ==
+                    # unplaced bitwise.
+                    keys = keys[jnp.asarray(plan.lane_to_client)]
+            else:
+                keys = jnp.zeros((m, 1, 2), jnp.uint32)
         fn = jax.shard_map(q_body, mesh=mesh,
                            in_specs=(specs, specs, specs, specs, bspecs,
                                      P(ca, None), P(ca, None, None), P(ca),
                                      P(None, ca), P(ca)),
                            out_specs=(specs, specs, P(ca)),
                            check_vma=not pallas)
-        return fn(x, y, v, g, batch_last, keys_last, keys,
-                  jnp.asarray(w_self, jnp.float32),
-                  jnp.asarray(w_steps, jnp.float32),
-                  jnp.asarray(active, jnp.float32))
+        with jax.named_scope("wire/exchange"):
+            w_self = jnp.asarray(w_self, jnp.float32)
+            w_steps = jnp.asarray(w_steps, jnp.float32)
+            active = jnp.asarray(active, jnp.float32)
+        return fn(x, y, v, g, batch_last, keys_last, keys, w_self, w_steps,
+                  active)
 
     return tail
 
@@ -1126,9 +1203,10 @@ def make_scheduled_mixer(schedule: TopologySchedule, cfg: MixerConfig,
 
     def mixer(x: Pytree, z: Pytree, key: jax.Array, t
               ) -> tuple[Pytree, jnp.ndarray]:
-        W_t, active, key_q = schedule.round_event(key, t)
-        if perm is not None:
-            active = active[perm]
+        with jax.named_scope("wire/exchange"):
+            W_t, active, key_q = schedule.round_event(key, t)
+            if perm is not None:
+                active = active[perm]
         return ev(x, z, W_t, active, key_q), active
 
     return mixer

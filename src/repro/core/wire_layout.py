@@ -310,8 +310,9 @@ class WireLayout:
         if stochastic and noise is None:
             if leaf_keys is None:
                 raise ValueError("stochastic encode needs per-leaf keys")
-            noise = (self.noise(leaf_keys) if delta.ndim == 2
-                     else self.noise_stacked(leaf_keys))
+            with jax.named_scope("noise"):
+                noise = (self.noise(leaf_keys) if delta.ndim == 2
+                         else self.noise_stacked(leaf_keys))
         elif not stochastic:
             noise = None
         if pallas:
@@ -359,8 +360,9 @@ class WireLayout:
         if stochastic and noise is None:
             if leaf_keys is None:
                 raise ValueError("stochastic encode needs per-leaf keys")
-            noise = (self.noise(leaf_keys) if y2d.ndim == 2
-                     else self.noise_stacked(leaf_keys))
+            with jax.named_scope("noise"):
+                noise = (self.noise(leaf_keys) if y2d.ndim == 2
+                         else self.noise_stacked(leaf_keys))
         elif not stochastic:
             noise = None
         if pallas:

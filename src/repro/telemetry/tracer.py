@@ -11,10 +11,12 @@ thread's ``pool/step`` next to the worker thread's ``pool/prepare``).
 
 Each span also enters a ``jax.profiler.TraceAnnotation`` with the same
 name: when a device profile is being captured (``jax.profiler.trace``),
-the host spans land on the profiler timeline under identical labels, and
-the compiled step's internal stages carry matching ``jax.named_scope``
-names (``round/local_sgd``, ``round/mix``, ``wire/encode``, ...) — so
-host trace and device profile align without a correlation table.
+the span lands on the profiler's host timeline, on the device trace's
+clock, beside the device ops of the compiled step, whose stages carry
+``jax.named_scope`` paths (``round/local_sgd``, ``round/mix/wire/encode``,
+...; see ``docs/OBSERVABILITY.md``). The saved Chrome JSON is not on
+that clock: its timestamps count from the tracer's own
+``perf_counter`` origin.
 
 A disabled tracer (``Tracer(enabled=False)``, the default for every
 runner argument) costs one attribute check per span — the hot loops stay
@@ -83,17 +85,6 @@ class Tracer:
                 with self._lock:
                     self._events.append(ev)
 
-    def instant(self, name: str, **args) -> None:
-        """Zero-duration marker (Chrome 'i' event)."""
-        if not self.enabled:
-            return
-        ev = {"ph": "i", "name": name, "pid": _PID, "tid": self._tid(),
-              "ts": (self._clock() - self._t0) * 1e6, "s": "t"}
-        if args:
-            ev["args"] = args
-        with self._lock:
-            self._events.append(ev)
-
     # -- export ------------------------------------------------------------
 
     @property
@@ -108,16 +99,6 @@ class Tracer:
     def save(self, path) -> None:
         with open(path, "w") as f:
             json.dump(self.to_chrome_trace(), f)
-
-    def durations(self) -> dict[str, float]:
-        """Total seconds per span name — the stage-time breakdown the
-        report's telemetry mode renders."""
-        out: dict[str, float] = {}
-        for ev in self.events:
-            if ev.get("ph") == "X":
-                out[ev["name"]] = out.get(ev["name"], 0.0) \
-                    + ev["dur"] / 1e6
-        return out
 
 
 NULL_TRACER = Tracer(enabled=False)

@@ -168,6 +168,46 @@ print("CACHE_OK")
         assert any(tmp_path.iterdir()), "no compile landed in the cache"
 
 
+def test_compile_cache_key_holds_scopes(tmp_path):
+    """Two programs that differ only in a ``jax.named_scope`` get cache
+    entries of their own (a loaded executable names its ops by the scopes
+    of the program that compiled it), the same program hits its entry
+    from a fresh process, and source paths enter the key relative to
+    the repository root."""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               PYTHONPATH=os.pathsep.join(sys.path))
+    code = """
+import os, re, sys
+import jax, jax.numpy as jnp
+from repro.launch.compile_cache import REPO_ROOT, use_compile_cache
+use_compile_cache()
+pat = jax.config.jax_hlo_source_file_canonicalization_regex
+assert re.sub(pat, "", os.path.join(REPO_ROOT, "src", "m.py")) == \\
+    os.path.join("src", "m.py")
+def f(x):
+    with jax.named_scope(sys.argv[1]):
+        return x * 3 + 1
+jax.jit(f)(jnp.arange(8.0)).block_until_ready()
+print("ENTRIES", len(os.listdir(jax.config.jax_compilation_cache_dir)))
+"""
+
+    def entries(scope):
+        r = subprocess.run([sys.executable, "-c", code, scope],
+                           capture_output=True, text=True, timeout=300,
+                           env=env)
+        out = [l for l in r.stdout.splitlines() if l.startswith("ENTRIES")]
+        assert out, r.stdout + r.stderr
+        return int(out[0].split()[1])
+
+    first = entries("round/mix")
+    assert first > 0
+    assert entries("round/mix") == first
+    assert entries("round/metrics") > first
+
+
 def test_chip_path_never_imports_dryrun():
     """``launch/dryrun.py`` rewrites XLA_FLAGS when imported; the chip
     path (the training entry point and ``chip_smoke.py``) must not load
