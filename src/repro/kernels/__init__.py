@@ -10,6 +10,8 @@ dequant_mix   — fused unpack + dequantize + gossip apply (eq. 7): ring /
                 ``dequant_mix_buffer_pallas`` consuming every received
                 stream + runtime scales/weights in one pass
 momentum_sgd  — fused heavy-ball parameter update (eq. 4)
+tiling        — the codec kernels' grid: tiles of many lane blocks a step,
+                each block with its own scale
 
 Each kernel has a pure-jnp oracle in ``ref.py`` (the buffer oracles double
 as the CPU execution path of the flat wire codec) and a padded/jit'd

@@ -10,8 +10,11 @@ weighted sum is applied directly to x, instead of materializing three
 dequantized f32 tensors in HBM (saves 3 full-size HBM writes + reads per
 round; the op is strictly bandwidth-bound).
 
-Layout matches quantize_pack: planar [per, W] view, lane axis blocked by
-LANE_BLOCK.
+Layout matches quantize_pack: planar [per, W] view, lane axis tiled by
+G lane blocks a grid step (``kernels.tiling``), each LANE_BLOCK-word
+block dequantized with its own scale. VMEM per step: (per base in + k
+u32 streams + per out) x G * LANE_BLOCK words, double-buffered — b=4,
+k=5, f32, G=64: 84 B * 32K * 2 ≈ 5.3 MiB.
 """
 from __future__ import annotations
 
@@ -21,12 +24,28 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .quantize_pack import block_scale, scale_groups, scale_spec
 from .ref import LANE_BLOCK
+from .tiling import for_each_block, lane_tiles, scale_groups
+
+
+def _mix_block(x_ref, q_ref, w_ref, lanes, scale, *, bits: int,
+               n_streams: int):
+    """One lane block of ``x + sum_k w[k] * deq(stream[k], scale(k))``,
+    f32, streams in order."""
+    mask = jnp.uint32((1 << bits) - 1)
+    offset = jnp.int32(1 << (bits - 1))
+    shifts = jax.lax.broadcasted_iota(jnp.uint32, (32 // bits, 1), 0) * bits
+    acc = x_ref[:, lanes].astype(jnp.float32)
+    for k in range(n_streams):
+        fields = (q_ref[pl.ds(k, 1), lanes] >> shifts) & mask
+        deq = (fields.astype(jnp.int32) - offset).astype(jnp.float32) \
+            * scale(k)
+        acc += w_ref[0, k] * deq
+    return acc
 
 
 def _dequant_mix_buffer_kernel(x_ref, q_ref, s_ref, w_ref, out_ref, *,
-                               bits: int, n_streams: int):
+                               bits: int, n_streams: int, g: int):
     """Flat-wire-buffer fused apply: the whole model's planar buffer in
     one kernel, with PER-LANE-BLOCK scales (each block carries its owning
     leaf's scale — see ``core.wire_layout``):
@@ -41,18 +60,12 @@ def _dequant_mix_buffer_kernel(x_ref, q_ref, s_ref, w_ref, out_ref, *,
     with the oracle is a few ulp, not bitwise (FMA contraction is a
     per-compilation choice — see the oracle's docstring).
     """
-    per = 32 // bits
-    mask = jnp.uint32((1 << bits) - 1)
-    offset = jnp.int32(1 << (bits - 1))
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (per, 1), 0) * bits
+    def block(lanes, scale):
+        acc = _mix_block(x_ref, q_ref, w_ref, lanes, scale, bits=bits,
+                         n_streams=n_streams)
+        out_ref[:, lanes] = acc.astype(out_ref.dtype)
 
-    acc = x_ref[...].astype(jnp.float32)
-    for k in range(n_streams):
-        fields = (q_ref[k][None, :] >> shifts) & mask
-        deq = (fields.astype(jnp.int32) - offset).astype(jnp.float32) \
-            * block_scale(s_ref, k)
-        acc += w_ref[0, k] * deq
-    out_ref[...] = acc.astype(out_ref.dtype)
+    for_each_block(g, s_ref, block)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
@@ -62,26 +75,27 @@ def dequant_mix_buffer_pallas(x2d: jnp.ndarray, streams: jnp.ndarray,
                               interpret: bool = False) -> jnp.ndarray:
     """x2d: [per, W] (f32/bf16) planar buffer; streams: uint32 [k, W];
     block_scales: f32 [k, W // LANE_BLOCK]; weights: f32 [k] (traced OK).
-    Returns [per, W]. VMEM per step: (per + k) * LANE_BLOCK words — e.g.
-    b=8, k=5: 9 * 512 * 4 B ≈ 18 KiB, far under budget."""
+    Returns [per, W]."""
     per, w = x2d.shape
     k = streams.shape[0]
     n_blocks = w // LANE_BLOCK
     assert per == 32 // bits and w % LANE_BLOCK == 0, (per, w)
     assert block_scales.shape == (k, n_blocks), (block_scales.shape, k)
+    out = jax.ShapeDtypeStruct(x2d.shape, x2d.dtype)
+    tiles = lane_tiles(n_blocks, x2d, streams, out)
     kernel = functools.partial(_dequant_mix_buffer_kernel, bits=bits,
-                               n_streams=k)
+                               n_streams=k, g=tiles.g)
     return pl.pallas_call(
         kernel,
-        grid=(n_blocks,),
+        grid=tiles.grid,
         in_specs=[
-            pl.BlockSpec((per, LANE_BLOCK), lambda i: (0, i)),
-            pl.BlockSpec((k, LANE_BLOCK), lambda i: (0, i)),
-            scale_spec(k),
+            tiles.spec(per),
+            tiles.spec(k),
+            tiles.scale_spec(k),
             pl.BlockSpec((1, k), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((per, LANE_BLOCK), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
+        out_specs=tiles.spec(per),
+        out_shape=out,
         interpret=interpret,
         name="dequant_mix_buffer",
     )(x2d, streams, scale_groups(block_scales),
@@ -90,7 +104,7 @@ def dequant_mix_buffer_pallas(x2d: jnp.ndarray, streams: jnp.ndarray,
 
 def _dequant_mix_momentum_buffer_kernel(x_ref, q_ref, s_ref, w_ref, v_ref,
                                         g_ref, et_ref, out_ref, *, bits: int,
-                                        n_streams: int):
+                                        n_streams: int, g: int):
     """Fused mix + deferred momentum: the round's combined decode-apply AND
     final heavy-ball update in one memory pass —
 
@@ -103,20 +117,14 @@ def _dequant_mix_momentum_buffer_kernel(x_ref, q_ref, s_ref, w_ref, v_ref,
     momentum restarts at 0 every round (Algorithm 1), so v' dies here.
     eta/theta are runtime scalars in et_ref = [[eta, theta]].
     """
-    per = 32 // bits
-    mask = jnp.uint32((1 << bits) - 1)
-    offset = jnp.int32(1 << (bits - 1))
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (per, 1), 0) * bits
+    def block(lanes, scale):
+        acc = _mix_block(x_ref, q_ref, w_ref, lanes, scale, bits=bits,
+                         n_streams=n_streams)
+        v_next = (et_ref[0, 1] * v_ref[:, lanes].astype(jnp.float32)
+                  - et_ref[0, 0] * g_ref[:, lanes].astype(jnp.float32))
+        out_ref[:, lanes] = (acc + v_next).astype(out_ref.dtype)
 
-    acc = x_ref[...].astype(jnp.float32)
-    for k in range(n_streams):
-        fields = (q_ref[k][None, :] >> shifts) & mask
-        deq = (fields.astype(jnp.int32) - offset).astype(jnp.float32) \
-            * block_scale(s_ref, k)
-        acc += w_ref[0, k] * deq
-    v_next = (et_ref[0, 1] * v_ref[...].astype(jnp.float32)
-              - et_ref[0, 0] * g_ref[...].astype(jnp.float32))
-    out_ref[...] = (acc + v_next).astype(out_ref.dtype)
+    for_each_block(g, s_ref, block)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
@@ -137,22 +145,24 @@ def dequant_mix_momentum_buffer_pallas(x2d: jnp.ndarray, streams: jnp.ndarray,
     n_blocks = w // LANE_BLOCK
     assert per == 32 // bits and w % LANE_BLOCK == 0, (per, w)
     assert block_scales.shape == (k, n_blocks), (block_scales.shape, k)
+    out = jax.ShapeDtypeStruct(x2d.shape, x2d.dtype)
+    tiles = lane_tiles(n_blocks, x2d, streams, v2d, g2d, out)
     kernel = functools.partial(_dequant_mix_momentum_buffer_kernel,
-                               bits=bits, n_streams=k)
-    buf = pl.BlockSpec((per, LANE_BLOCK), lambda i: (0, i))
+                               bits=bits, n_streams=k, g=tiles.g)
+    buf = tiles.spec(per)
     return pl.pallas_call(
         kernel,
-        grid=(n_blocks,),
+        grid=tiles.grid,
         in_specs=[
             buf,
-            pl.BlockSpec((k, LANE_BLOCK), lambda i: (0, i)),
-            scale_spec(k),
+            tiles.spec(k),
+            tiles.scale_spec(k),
             pl.BlockSpec((1, k), lambda i: (0, 0)),
             buf, buf,
             pl.BlockSpec((1, 2), lambda i: (0, 0)),
         ],
         out_specs=buf,
-        out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
+        out_shape=out,
         interpret=interpret,
         name="dequant_mix_momentum_buffer",
     )(x2d, streams, scale_groups(block_scales),

@@ -9,11 +9,10 @@ Layout (see kernels.ref): input is viewed as [per, W] with the lane axis W
 a multiple of 128; word w ORs together the offset-encoded fields of
 column w across the ``per`` sublanes — all shifts are lane-parallel.
 
-Grid: 1-D over lane blocks of LANE_BLOCK words.
-VMEM per step: per*LANE_BLOCK f32 in + (optional) noise + LANE_BLOCK u32
-out — e.g. b=8: 4*512*4 B + 512*4 B ≈ 10 KiB, far under the ~16 MiB VMEM
-budget; LANE_BLOCK could be raised 256x before VMEM pressure, but the
-kernel is bandwidth-bound either way.
+Grid: 1-D over tiles of G lane blocks (``kernels.tiling``); each lane
+block of LANE_BLOCK words is quantized with its own scale. VMEM per
+step: (per f32 in + per f32 noise + one u32 out) x G * LANE_BLOCK,
+double-buffered — b=8, G=64: 36 B * 32K * 2 ≈ 2.3 MiB.
 """
 from __future__ import annotations
 
@@ -22,40 +21,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from .ref import LANE_BLOCK
-
-# Per-lane-block scales reach the kernels through SMEM, SCALE_GROUP blocks
-# at a time: a (rows, 1) block of a [rows, n_blocks] array is no legal TPU
-# block (its last dim is neither a multiple of 128 nor the whole array's),
-# so grid step i reads column i % SCALE_GROUP of scale block
-# i // SCALE_GROUP.
-SCALE_GROUP = 128
-
-
-def scale_groups(block_scales: jnp.ndarray) -> jnp.ndarray:
-    """f32 [rows, n_blocks] -> [rows, n_blocks rounded up to SCALE_GROUP]
-    (zero-padded; the padded columns belong to no grid step)."""
-    pad = -block_scales.shape[-1] % SCALE_GROUP
-    return jnp.pad(block_scales.astype(jnp.float32), ((0, 0), (0, pad)))
-
-
-def scale_spec(rows: int) -> pl.BlockSpec:
-    """SMEM block of ``rows`` x SCALE_GROUP per-block scales (pair it with
-    :func:`scale_groups` and :func:`block_scale`)."""
-    return pl.BlockSpec((rows, SCALE_GROUP), lambda i: (0, i // SCALE_GROUP),
-                        memory_space=pltpu.SMEM)
-
-
-# Packed words leave the kernels as a [1, W] row: the TPU tiles a 1-D
-# uint32 array by 1024, which a 1-D LANE_BLOCK block does not match.
-_WORD_SPEC = pl.BlockSpec((1, LANE_BLOCK), lambda i: (0, i))
-
-
-def block_scale(s_ref, row: int):
-    """This grid step's scale in row ``row`` of a :func:`scale_spec` block."""
-    return s_ref[row, pl.program_id(0) % SCALE_GROUP]
+from .tiling import for_each_block, lane_tiles, scale_groups
 
 
 def _pack_rows(fields: jnp.ndarray, bits: int) -> jnp.ndarray:
@@ -69,19 +37,29 @@ def _pack_rows(fields: jnp.ndarray, bits: int) -> jnp.ndarray:
     return words
 
 
-def _quantize_pack_kernel(x_ref, noise_ref, s_ref, out_ref, *, bits: int,
-                          stochastic: bool):
-    per = 32 // bits
+def _encode_block(x, noise_ref, lanes, s, *, bits: int, stochastic: bool):
+    """One lane block's words: quantize ``x`` [per, LANE_BLOCK] f32 with
+    step ``s`` (stochastic rounding against ``noise_ref[:, lanes]``),
+    offset-encode and pack — the oracle's expression, in its order."""
     qmin = -(2 ** (bits - 1))
     qmax = 2 ** (bits - 1) - 1
-    s = block_scale(s_ref, 0)
-    a = x_ref[...] / s                       # [per, LANE_BLOCK] f32
+    a = x / s
     k = jnp.floor(a)
     if stochastic:
-        k = k + (noise_ref[...] < (a - k)).astype(jnp.float32)
+        k = k + (noise_ref[:, lanes] < (a - k)).astype(jnp.float32)
     k = jnp.clip(k, qmin, qmax).astype(jnp.int32)
     fields = (k + (1 << (bits - 1))).astype(jnp.uint32)
-    out_ref[...] = _pack_rows(fields, bits)                # [1, LANE_BLOCK]
+    return _pack_rows(fields, bits)                        # [1, LANE_BLOCK]
+
+
+def _quantize_pack_kernel(x_ref, noise_ref, s_ref, out_ref, *, bits: int,
+                          stochastic: bool, g: int):
+    def block(lanes, scale):
+        out_ref[:, lanes] = _encode_block(
+            x_ref[:, lanes], noise_ref, lanes, scale(0), bits=bits,
+            stochastic=stochastic)
+
+    for_each_block(g, s_ref, block)
 
 
 @functools.partial(jax.jit,
@@ -103,18 +81,18 @@ def quantize_pack_buffer_pallas(x2d: jnp.ndarray, s_blocks: jnp.ndarray,
     assert per == 32 // bits and w % LANE_BLOCK == 0, (per, w)
     n_blocks = w // LANE_BLOCK
     assert s_blocks.shape == (1, n_blocks), (s_blocks.shape, n_blocks)
+    out = jax.ShapeDtypeStruct((1, w), jnp.uint32)
+    tiles = lane_tiles(n_blocks, x2d, noise, out)
     kernel = functools.partial(_quantize_pack_kernel, bits=bits,
-                               stochastic=stochastic)
+                               stochastic=stochastic, g=tiles.g)
     return pl.pallas_call(
         kernel,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((per, LANE_BLOCK), lambda i: (0, i)),
-            pl.BlockSpec((per, LANE_BLOCK), lambda i: (0, i)),
-            scale_spec(1),
-        ],
-        out_specs=_WORD_SPEC,
-        out_shape=jax.ShapeDtypeStruct((1, w), jnp.uint32),
+        grid=tiles.grid,
+        in_specs=[tiles.spec(per), tiles.spec(per), tiles.scale_spec(1)],
+        # Words leave as a [1, W] row: the TPU tiles a 1-D uint32 array
+        # by 1024, which a 1-D tile does not always match.
+        out_specs=tiles.spec(1),
+        out_shape=out,
         interpret=interpret,
         name="quantize_pack_buffer",
     )(x2d, noise, scale_groups(s_blocks))[0]
@@ -122,7 +100,7 @@ def quantize_pack_buffer_pallas(x2d: jnp.ndarray, s_blocks: jnp.ndarray,
 
 def _momentum_quantize_pack_kernel(y_ref, v_ref, g_ref, x_ref, noise_ref,
                                    s_ref, et_ref, y_out, v_out, w_out, *,
-                                   bits: int, stochastic: bool):
+                                   bits: int, stochastic: bool, g: int):
     """Fused final-local-step + encode: apply the round's last heavy-ball
     update and emit the wire words as a SIDE OUTPUT of the same pass —
 
@@ -135,25 +113,20 @@ def _momentum_quantize_pack_kernel(y_ref, v_ref, g_ref, x_ref, noise_ref,
     costs its own trip over the model. eta/theta ride a runtime [1, 2]
     scalar block like ``momentum_sgd``'s.
     """
-    per = 32 // bits
-    qmin = -(2 ** (bits - 1))
-    qmax = 2 ** (bits - 1) - 1
     eta = et_ref[0, 0]
     theta = et_ref[0, 1]
-    v_next = (theta * v_ref[...].astype(jnp.float32)
-              - eta * g_ref[...].astype(jnp.float32))
-    y_next = y_ref[...].astype(jnp.float32) + v_next
-    delta = y_next - x_ref[...].astype(jnp.float32)
-    s = block_scale(s_ref, 0)
-    a = delta / s                            # [per, LANE_BLOCK] f32
-    k = jnp.floor(a)
-    if stochastic:
-        k = k + (noise_ref[...] < (a - k)).astype(jnp.float32)
-    k = jnp.clip(k, qmin, qmax).astype(jnp.int32)
-    fields = (k + (1 << (bits - 1))).astype(jnp.uint32)
-    y_out[...] = y_next.astype(y_out.dtype)
-    v_out[...] = v_next.astype(v_out.dtype)
-    w_out[...] = _pack_rows(fields, bits)
+
+    def block(lanes, scale):
+        v_next = (theta * v_ref[:, lanes].astype(jnp.float32)
+                  - eta * g_ref[:, lanes].astype(jnp.float32))
+        y_next = y_ref[:, lanes].astype(jnp.float32) + v_next
+        delta = y_next - x_ref[:, lanes].astype(jnp.float32)
+        y_out[:, lanes] = y_next.astype(y_out.dtype)
+        v_out[:, lanes] = v_next.astype(v_out.dtype)
+        w_out[:, lanes] = _encode_block(delta, noise_ref, lanes, scale(0),
+                                        bits=bits, stochastic=stochastic)
+
+    for_each_block(g, s_ref, block)
 
 
 @functools.partial(jax.jit,
@@ -181,21 +154,23 @@ def momentum_quantize_pack_buffer_pallas(
     assert per == 32 // bits and w % LANE_BLOCK == 0, (per, w)
     n_blocks = w // LANE_BLOCK
     assert s_blocks.shape == (1, n_blocks), (s_blocks.shape, n_blocks)
+    outs = (jax.ShapeDtypeStruct(y2d.shape, y2d.dtype),
+            jax.ShapeDtypeStruct(v2d.shape, v2d.dtype),
+            jax.ShapeDtypeStruct((1, w), jnp.uint32))
+    tiles = lane_tiles(n_blocks, y2d, v2d, g2d, x2d, noise, *outs)
     kernel = functools.partial(_momentum_quantize_pack_kernel, bits=bits,
-                               stochastic=stochastic)
-    buf = pl.BlockSpec((per, LANE_BLOCK), lambda i: (0, i))
+                               stochastic=stochastic, g=tiles.g)
+    buf = tiles.spec(per)
     y_o, v_o, words = pl.pallas_call(
         kernel,
-        grid=(n_blocks,),
+        grid=tiles.grid,
         in_specs=[
             buf, buf, buf, buf, buf,
-            scale_spec(1),
+            tiles.scale_spec(1),
             pl.BlockSpec((1, 2), lambda i: (0, 0)),
         ],
-        out_specs=(buf, buf, _WORD_SPEC),
-        out_shape=(jax.ShapeDtypeStruct(y2d.shape, y2d.dtype),
-                   jax.ShapeDtypeStruct(v2d.shape, v2d.dtype),
-                   jax.ShapeDtypeStruct((1, w), jnp.uint32)),
+        out_specs=(buf, buf, tiles.spec(1)),
+        out_shape=outs,
         interpret=interpret,
         name="momentum_quantize_pack_buffer",
     )(y2d, v2d, g2d, x2d, noise, scale_groups(s_blocks),

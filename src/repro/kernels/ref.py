@@ -19,7 +19,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-LANE_BLOCK = 512  # lane-dim block for all kernels (multiple of 128)
+# Words a lane block holds (a multiple of 128): the granularity of the
+# per-block wire scales and of leaf alignment in ``core.wire_layout``. The
+# codec kernels' grid steps cover tiles of many such blocks
+# (``kernels.tiling``).
+LANE_BLOCK = 512
 
 
 def planar_pad_len(n: int, bits: int) -> tuple[int, int]:

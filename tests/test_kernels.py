@@ -1,5 +1,8 @@
 """Pallas kernels vs pure-jnp ref oracles: shape/dtype sweeps in interpret
 mode (per-kernel allclose, as required by the brief)."""
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,12 +14,14 @@ from repro.core import local_train
 from repro.kernels import (decode_apply_plan, decode_apply_ring,
                            encode_delta, make_fused_momentum_update,
                            momentum_update_flat)
-from repro.kernels import ref
-from repro.kernels.dequant_mix import (dequant_mix_momentum_buffer_pallas,
+from repro.kernels import ref, tiling
+from repro.kernels.dequant_mix import (dequant_mix_buffer_pallas,
+                                       dequant_mix_momentum_buffer_pallas,
                                        dequant_mix_pallas)
 from repro.kernels.momentum_sgd import momentum_sgd_pallas
 from repro.kernels.quantize_pack import (
-    momentum_quantize_pack_buffer_pallas, quantize_pack_pallas)
+    momentum_quantize_pack_buffer_pallas, quantize_pack_buffer_pallas,
+    quantize_pack_pallas)
 
 BITS = (2, 4, 8, 16)
 SIZES = (1, 100, 512, 2048, 5000, 65536)
@@ -198,22 +203,116 @@ def test_momentum_pallas_traced_eta_batches_under_vmap():
                                    atol=1e-6)
 
 
+# ---------------------------------------------------------------------------
+# Buffer kernels over lane tiles: G lane blocks a grid step, each with its
+# own scale; RAGGED_BLOCKS leaves the last step of every tile size ragged.
+# ---------------------------------------------------------------------------
+
+RAGGED_BLOCKS = 130          # 2 x 65: no multiple of any G of 4 or more
+N_BLOCKS = (2, RAGGED_BLOCKS)
+
+
+def _blockwise(bits, n_blocks, key, n=1):
+    """``n`` [per, W] f32 buffers whose lane blocks each have a magnitude
+    of their own, and [1, n_blocks] scales that fit the first one block
+    by block (absmax / qmax, as ``core.wire_layout`` sets them), so a
+    lane block quantized or dequantized with a neighbour's scale differs.
+    """
+    per, w = 32 // bits, n_blocks * ref.LANE_BLOCK
+    keys = jax.random.split(key, n + 1)
+    mag = jax.random.uniform(keys[0], (n_blocks,), minval=0.05, maxval=2.0)
+    mag = jnp.repeat(mag, ref.LANE_BLOCK)[None, :]
+    bufs = [jax.random.normal(k, (per, w)) * mag for k in keys[1:]]
+    absmax = jnp.abs(bufs[0]).reshape(per, n_blocks, -1).max(axis=(0, 2))
+    return bufs, (absmax / (2 ** (bits - 1) - 1))[None, :]
+
+
+def _grid_and_vmem(fn, *args) -> tuple[int, int, int]:
+    """(grid steps, lanes a step, bytes of a step's VMEM blocks
+    double-buffered) of the one ``pallas_call`` in ``fn`` traced at
+    ``args``."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for v in eqn.params.values():
+                sub = getattr(v, "jaxpr", None)
+                if sub is not None:
+                    yield from walk(getattr(sub, "jaxpr", sub))
+    (eqn,) = walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    gm = eqn.params["grid_mapping"]
+    vmem = [bm.block_aval for bm in gm.block_mappings
+            if bm.block_aval.memory_space is None]
+    nbytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in vmem)
+    (steps,) = gm.grid
+    return steps, vmem[0].shape[-1], 2 * nbytes
+
+
+def _assert_ragged(fn, *args, n_blocks):
+    steps, lanes, _ = _grid_and_vmem(fn, *args)
+    g = lanes // ref.LANE_BLOCK
+    assert steps == -(-n_blocks // g)
+    if n_blocks == RAGGED_BLOCKS:
+        assert g > 1 and n_blocks % g, (g, n_blocks)
+
+
+@pytest.mark.parametrize("bits", (4, 8))
+@pytest.mark.parametrize("stochastic", (False, True))
+@pytest.mark.parametrize("n_blocks", N_BLOCKS)
+def test_quantize_pack_buffer_tiles_match_ref(bits, stochastic, n_blocks):
+    """Encode over lane tiles: words bitwise the oracle's, with a scale of
+    its own on every lane block and a ragged last grid step."""
+    (x,), sblk = _blockwise(bits, n_blocks, jax.random.PRNGKey(bits))
+    noise = jax.random.uniform(jax.random.PRNGKey(7), x.shape)
+    fn = functools.partial(quantize_pack_buffer_pallas, bits=bits,
+                           stochastic=stochastic, interpret=True)
+    _assert_ragged(fn, x, sblk, noise, n_blocks=n_blocks)
+    words = fn(x, sblk, noise)
+    expected = ref.quantize_pack_buffer_ref(
+        x, sblk[0], bits, noise=noise if stochastic else None)
+    assert words.shape == (n_blocks * ref.LANE_BLOCK,)
+    assert jnp.array_equal(words, expected)
+
+
+@pytest.mark.parametrize("bits", (4, 8))
+@pytest.mark.parametrize("k", (2, 3, 5))
+@pytest.mark.parametrize("n_blocks", N_BLOCKS)
+def test_dequant_mix_buffer_tiles_match_ref(bits, k, n_blocks):
+    """Decode-apply over lane tiles: k streams, a scale of their own on
+    every lane block of every stream, a ragged last grid step."""
+    per, w = 32 // bits, n_blocks * ref.LANE_BLOCK
+    rng = np.random.default_rng(bits * 10 + k)
+    x = jnp.asarray(rng.normal(size=(per, w)), jnp.float32)
+    streams = jnp.asarray(
+        rng.integers(0, 2 ** 32, size=(k, w), dtype=np.uint32))
+    sblk = jnp.asarray(rng.uniform(0.01, 0.1, size=(k, n_blocks)),
+                       jnp.float32)
+    weights = jnp.asarray(rng.uniform(0.0, 0.5, size=(k,)), jnp.float32)
+    fn = functools.partial(dequant_mix_buffer_pallas, bits=bits,
+                           interpret=True)
+    _assert_ragged(fn, x, streams, sblk, weights, n_blocks=n_blocks)
+    out = fn(x, streams, sblk, weights)
+    o = np.asarray(ref.dequant_mix_buffer_ref(x, streams, sblk, weights,
+                                              bits))
+    tol = 8 * np.finfo(np.float32).eps * (np.abs(o).max() + 1.0)
+    np.testing.assert_allclose(np.asarray(out), o, rtol=0, atol=tol)
+
+
 @pytest.mark.parametrize("bits", (4, 8))
 @pytest.mark.parametrize("stochastic", (False, True))
 def test_fused_encode_kernel_matches_ref(bits, stochastic):
     """momentum_quantize_pack fusion: the applied last local step AND the
     packed wire in one pass — integer wire BITWISE vs the oracle, float
-    outputs to ~ulp (FMA contraction)."""
-    per, w = 32 // bits, 2 * ref.LANE_BLOCK
-    nb = w // ref.LANE_BLOCK
-    keys = jax.random.split(jax.random.PRNGKey(bits), 6)
-    y, v, g, x = (jax.random.normal(k, (per, w)) * 0.3 for k in keys[:4])
-    sblk = jax.random.uniform(keys[4], (1, nb), minval=0.01, maxval=0.1)
-    noise = jax.random.uniform(keys[5], (per, w))
+    outputs to ~ulp (FMA contraction). Ragged last grid step."""
+    n_blocks = RAGGED_BLOCKS
+    (y, v, g, x), sblk = _blockwise(bits, n_blocks,
+                                    jax.random.PRNGKey(bits), n=4)
+    noise = jax.random.uniform(jax.random.PRNGKey(7), y.shape)
     et = jnp.asarray([0.05, 0.9], jnp.float32)
-    yo, vo, words = momentum_quantize_pack_buffer_pallas(
-        y, v, g, x, sblk, noise, et, bits=bits, stochastic=stochastic,
-        interpret=True)
+    fn = functools.partial(momentum_quantize_pack_buffer_pallas, bits=bits,
+                           stochastic=stochastic, interpret=True)
+    _assert_ragged(fn, y, v, g, x, sblk, noise, et, n_blocks=n_blocks)
+    yo, vo, words = fn(y, v, g, x, sblk, noise, et)
     yr, vr, wr = ref.momentum_quantize_pack_buffer_ref(
         y, v, g, x, sblk[0], bits, 0.05, 0.9,
         noise=noise if stochastic else None)
@@ -223,24 +322,71 @@ def test_fused_encode_kernel_matches_ref(bits, stochastic):
 
 
 @pytest.mark.parametrize("bits", (4, 8))
-@pytest.mark.parametrize("k", (1, 3))
+@pytest.mark.parametrize("k", (1, 2, 3, 5))
 def test_fused_decode_kernel_matches_ref(bits, k):
     """dequant_mix_momentum fusion: mix + the deferred last heavy-ball
-    step in one pass, vs the tree-level oracle."""
-    per, w = 32 // bits, 2 * ref.LANE_BLOCK
-    nb = w // ref.LANE_BLOCK
+    step in one pass, vs the tree-level oracle. Ragged last grid step."""
+    n_blocks = RAGGED_BLOCKS
+    per, w = 32 // bits, n_blocks * ref.LANE_BLOCK
     rng = np.random.default_rng(bits * 10 + k)
     x = jnp.asarray(rng.normal(size=(per, w)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(per, w)), jnp.float32)
     g = jnp.asarray(rng.normal(size=(per, w)), jnp.float32)
     streams = jnp.asarray(
         rng.integers(0, 2 ** 32, size=(k, w), dtype=np.uint32))
-    sblk = jnp.asarray(rng.uniform(0.01, 0.1, size=(k, nb)), jnp.float32)
+    sblk = jnp.asarray(rng.uniform(0.01, 0.1, size=(k, n_blocks)),
+                       jnp.float32)
     weights = jnp.asarray(rng.uniform(0.0, 0.5, size=(k,)), jnp.float32)
     et = jnp.asarray([0.05, 0.9], jnp.float32)
-    out = dequant_mix_momentum_buffer_pallas(
-        x, streams, sblk, weights, v, g, et, bits=bits, interpret=True)
+    fn = functools.partial(dequant_mix_momentum_buffer_pallas, bits=bits,
+                           interpret=True)
+    _assert_ragged(fn, x, streams, sblk, weights, v, g, et,
+                   n_blocks=n_blocks)
+    out = fn(x, streams, sblk, weights, v, g, et)
     expected = ref.dequant_mix_momentum_buffer_ref(
         x, streams, sblk, weights, v, g, et, bits)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                atol=1e-5)
+
+
+def _olmo_two_layer_layout(bits: int):
+    from repro.configs import get_config
+    from repro.core.wire_layout import WireLayout
+    from repro.models import model as M
+    cfg = dataclasses.replace(get_config("olmo-1b"), n_layers=2)
+    params = jax.eval_shape(lambda k: M.init_model(k, cfg)[0],
+                            jax.random.PRNGKey(0))
+    return WireLayout.for_tree(params, bits=bits)
+
+
+@pytest.mark.parametrize("kernel,k", (("encode", 1), ("decode", 2),
+                                      ("decode", 3), ("fused_encode", 1),
+                                      ("fused_decode", 3)))
+def test_codec_tiles_at_cell_width(kernel, k):
+    """The benchmark cells' wire, the two-layer OLMo-1B q8 buffer
+    (per 4, W 59,310,080): each codec ``pallas_call`` streams it in at
+    most 2,048 grid steps, not one per lane block (115,840), and a step's
+    double-buffered VMEM blocks stay under the budget. Traced only."""
+    lay = _olmo_two_layer_layout(8)
+    per, w, nb = lay.per, lay.total_words, lay.n_blocks
+    assert (per, w, nb) == (4, 59_310_080, 115_840)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)   # noqa: E731
+    buf, q = f32(per, w), jax.ShapeDtypeStruct((k, w), jnp.uint32)
+    et = f32(2)
+    fn, args = {
+        "encode": (functools.partial(quantize_pack_buffer_pallas, bits=8,
+                                     stochastic=True),
+                   (buf, f32(1, nb), buf)),
+        "decode": (functools.partial(dequant_mix_buffer_pallas, bits=8),
+                   (buf, q, f32(k, nb), f32(k))),
+        "fused_encode": (functools.partial(
+            momentum_quantize_pack_buffer_pallas, bits=8, stochastic=True),
+            (buf, buf, buf, buf, f32(1, nb), buf, et)),
+        "fused_decode": (functools.partial(
+            dequant_mix_momentum_buffer_pallas, bits=8),
+            (buf, q, f32(k, nb), f32(k), buf, buf, et)),
+    }[kernel]
+    steps, lanes, vmem = _grid_and_vmem(fn, *args)
+    assert steps <= 2048, (steps, lanes)
+    assert steps == -(-nb // (lanes // ref.LANE_BLOCK))
+    assert vmem <= tiling.VMEM_BUDGET, vmem

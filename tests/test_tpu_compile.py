@@ -22,7 +22,7 @@ from repro.configs import get_config
 from repro.core import (MixerConfig, MixingSpec, QuantConfig,
                         TopologySchedule, make_mixer)
 from repro.core.mixing import _mix_dense_quantized, mix_dense
-from repro.core.wire_layout import WireLayout
+from repro.core.wire_layout import LANE_BLOCK, WireLayout
 from repro.kernels.dequant_mix import (dequant_mix_buffer_pallas,
                                        dequant_mix_momentum_buffer_pallas)
 from repro.kernels.ops import momentum_update_flat
@@ -115,6 +115,38 @@ def test_fused_round_decoder_compiles_at_olmo_width(one_chip, bits):
             x, q, s, wt, v, g, et, bits=bits, interpret=False),
         one_chip, buf, ((k, w), jnp.uint32), ((k, lay.n_blocks), jnp.float32),
         ((k,), jnp.float32), buf, buf, ((2,), jnp.float32))
+    assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("kernel", ("encode", "decode", "fused_encode",
+                                    "fused_decode"))
+def test_codec_kernels_compile_at_ragged_width(one_chip, kernel):
+    """OLMo width plus 37 lane blocks: the last grid step covers only
+    part of a lane tile, so its out-of-range blocks are masked on write."""
+    lay = _olmo_layout(8)
+    nb = lay.n_blocks + 37
+    per, w, k = lay.per, nb * LANE_BLOCK, 3
+    buf, et = ((per, w), jnp.float32), ((2,), jnp.float32)
+    q = ((k, w), jnp.uint32)
+    fn, shapes = {
+        "encode": (lambda x, s, n: quantize_pack_buffer_pallas(
+            x, s, n, bits=8, stochastic=True, interpret=False),
+            (buf, ((1, nb), jnp.float32), buf)),
+        "decode": (lambda x, q, s, wt: dequant_mix_buffer_pallas(
+            x, q, s, wt, bits=8, interpret=False),
+            (buf, q, ((k, nb), jnp.float32), ((k,), jnp.float32))),
+        "fused_encode": (
+            lambda y, v, g, x, s, n, et: momentum_quantize_pack_buffer_pallas(
+                y, v, g, x, s, n, et, bits=8, stochastic=True,
+                interpret=False),
+            (buf, buf, buf, buf, ((1, nb), jnp.float32), buf, et)),
+        "fused_decode": (
+            lambda x, q, s, wt, v, g, et: dequant_mix_momentum_buffer_pallas(
+                x, q, s, wt, v, g, et, bits=8, interpret=False),
+            (buf, q, ((k, nb), jnp.float32), ((k,), jnp.float32), buf, buf,
+             et)),
+    }[kernel]
+    txt = _compiled_text(fn, one_chip, *shapes)
     assert "tpu_custom_call" in txt
 
 
