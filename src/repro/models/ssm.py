@@ -14,6 +14,11 @@ sharding ("ssm_inner" / "ssm_state"); expressiveness is unchanged.
 
 Shapes: d_inner = expand * d_model; nheads = d_inner / head_dim;
 x: [b, l, h, p]; B, C: [b, l, n] (ngroups = 1); dt: [b, l, h].
+
+Scopes (``jax.named_scope``, op metadata only): ``mamba/proj`` (input
+projections, dt, A), ``mamba/conv`` (the three causal convolutions),
+``mamba/ssd`` (the state-space scan and its D skip), ``mamba/gate``
+(gated RMSNorm and output projection); see docs/OBSERVABILITY.md.
 """
 from __future__ import annotations
 
@@ -83,7 +88,10 @@ def _segsum_decay(da_cs: jnp.ndarray) -> jnp.ndarray:
     diff = da_cs[..., :, None] - da_cs[..., None, :]   # [..., Q, Q]
     q = da_cs.shape[-1]
     tri = jnp.tril(jnp.ones((q, q), bool))
-    return jnp.where(tri, jnp.exp(diff), 0.0)
+    # Mask before exp: above the diagonal diff is positive and can
+    # overflow, and the inf there would turn the backward pass's zero
+    # cotangent into NaN.
+    return jnp.where(tri, jnp.exp(jnp.where(tri, diff, 0.0)), 0.0)
 
 
 def ssd_chunked(x: jnp.ndarray, dA: jnp.ndarray, B: jnp.ndarray,
@@ -153,60 +161,70 @@ def apply_mamba2(params: Pytree, x: jnp.ndarray, *, head_dim: int = 64,
     h = d_inner // head_dim
     n = params["wB"].shape[1]
 
-    z = x @ params["wz"]                               # [b,l,di]
-    xin = x @ params["wx"]
-    Braw = x @ params["wB"]
-    Craw = x @ params["wC"]
-    dt = jax.nn.softplus(x.astype(jnp.float32) @
-                         params["wdt"].astype(jnp.float32)
-                         + params["dt_bias"])          # [b,l,h]
-    A = -jnp.exp(params["A_log"])                      # [h]
+    with jax.named_scope("mamba/proj"):
+        z = x @ params["wz"]                           # [b,l,di]
+        xin = x @ params["wx"]
+        Braw = x @ params["wB"]
+        Craw = x @ params["wC"]
+        dt = jax.nn.softplus(x.astype(jnp.float32) @
+                             params["wdt"].astype(jnp.float32)
+                             + params["dt_bias"])      # [b,l,h]
+        A = -jnp.exp(params["A_log"])                  # [h]
 
     decode = cache is not None and l == 1
     cstate = cache if cache is not None else {}
-    xc = _causal_conv(xin, params["conv_x"], cstate.get("conv_x"))
-    Bc = _causal_conv(Braw, params["conv_B"], cstate.get("conv_B"))
-    Cc = _causal_conv(Craw, params["conv_C"], cstate.get("conv_C"))
+    with jax.named_scope("mamba/conv"):
+        xc = _causal_conv(xin, params["conv_x"], cstate.get("conv_x"))
+        Bc = _causal_conv(Braw, params["conv_B"], cstate.get("conv_B"))
+        Cc = _causal_conv(Craw, params["conv_C"], cstate.get("conv_C"))
 
-    xh = xc.reshape(b, l, h, head_dim)
-    x_dt = xh.astype(jnp.float32) * dt[..., None]
-    dA = dt * A[None, None, :]
+    with jax.named_scope("mamba/ssd"):
+        xh = xc.reshape(b, l, h, head_dim)
+        x_dt = xh.astype(jnp.float32) * dt[..., None]
+        dA = dt * A[None, None, :]
 
-    if decode:
-        s = cstate["ssm"].astype(jnp.float32)          # [b,h,n,p]
-        da1 = jnp.exp(dA[:, 0])                        # [b,h]
-        s_new = s * da1[..., None, None] + jnp.einsum(
-            "bn,bhp->bhnp", Bc[:, 0].astype(jnp.float32), x_dt[:, 0])
-        y = jnp.einsum("bn,bhnp->bhp", Cc[:, 0].astype(jnp.float32), s_new)
-        y = y[:, None]                                 # [b,1,h,p]
-        new_cache = {
-            "conv_x": jnp.concatenate([cstate["conv_x"][:, 1:], xin], axis=1),
-            "conv_B": jnp.concatenate([cstate["conv_B"][:, 1:], Braw], axis=1),
-            "conv_C": jnp.concatenate([cstate["conv_C"][:, 1:], Craw], axis=1),
-            "ssm": s_new.astype(cstate["ssm"].dtype),
-        }
-    else:
-        y, s_final = ssd_chunked(x_dt, dA, Bc, Cc, chunk=chunk,
-                                 init_state=cstate.get("ssm"))
-        new_cache = None
-        if cache is not None:   # chunked prefill into state
+        if decode:
+            s = cstate["ssm"].astype(jnp.float32)      # [b,h,n,p]
+            da1 = jnp.exp(dA[:, 0])                    # [b,h]
+            s_new = s * da1[..., None, None] + jnp.einsum(
+                "bn,bhp->bhnp", Bc[:, 0].astype(jnp.float32), x_dt[:, 0])
+            y = jnp.einsum("bn,bhnp->bhp", Cc[:, 0].astype(jnp.float32),
+                           s_new)
+            y = y[:, None]                             # [b,1,h,p]
             new_cache = {
-                "conv_x": jnp.concatenate([cstate["conv_x"], xin],
-                                          axis=1)[:, -(D_CONV - 1):],
-                "conv_B": jnp.concatenate([cstate["conv_B"], Braw],
-                                          axis=1)[:, -(D_CONV - 1):],
-                "conv_C": jnp.concatenate([cstate["conv_C"], Craw],
-                                          axis=1)[:, -(D_CONV - 1):],
-                "ssm": s_final.astype(cstate["ssm"].dtype),
+                "conv_x": jnp.concatenate([cstate["conv_x"][:, 1:], xin],
+                                          axis=1),
+                "conv_B": jnp.concatenate([cstate["conv_B"][:, 1:], Braw],
+                                          axis=1),
+                "conv_C": jnp.concatenate([cstate["conv_C"][:, 1:], Craw],
+                                          axis=1),
+                "ssm": s_new.astype(cstate["ssm"].dtype),
             }
+        else:
+            y, s_final = ssd_chunked(x_dt, dA, Bc, Cc, chunk=chunk,
+                                     init_state=cstate.get("ssm"))
+            new_cache = None
+            if cache is not None:   # chunked prefill into state
+                new_cache = {
+                    "conv_x": jnp.concatenate([cstate["conv_x"], xin],
+                                              axis=1)[:, -(D_CONV - 1):],
+                    "conv_B": jnp.concatenate([cstate["conv_B"], Braw],
+                                              axis=1)[:, -(D_CONV - 1):],
+                    "conv_C": jnp.concatenate([cstate["conv_C"], Craw],
+                                              axis=1)[:, -(D_CONV - 1):],
+                    "ssm": s_final.astype(cstate["ssm"].dtype),
+                }
 
-    y = y + params["D"][None, None, :, None] * xh.astype(jnp.float32)
-    y = y.reshape(b, l, d_inner)
-    # gated RMSNorm (Mamba2): norm(y * silu(z))
-    g = y * jax.nn.silu(z.astype(jnp.float32))
-    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + 1e-6)
-    g = g * params["norm_scale"].astype(jnp.float32)
-    out = g.astype(x.dtype) @ params["wo"]
+        y = y + params["D"][None, None, :, None] * xh.astype(jnp.float32)
+        y = y.reshape(b, l, d_inner)
+
+    with jax.named_scope("mamba/gate"):
+        # gated RMSNorm (Mamba2): norm(y * silu(z))
+        g = y * jax.nn.silu(z.astype(jnp.float32))
+        g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
+                              + 1e-6)
+        g = g * params["norm_scale"].astype(jnp.float32)
+        out = g.astype(x.dtype) @ params["wo"]
     return out, new_cache
 
 
