@@ -269,6 +269,11 @@ def make_async_round_step(loss_fn: LossFn, cfg: DFedAvgMConfig,
     :class:`TopologySchedule` (the event index drives the schedule, and
     the schedule's active mask composes with the clock's ready mask).
 
+    ``with_metrics``: also emit ``consensus_dist``, a cross-client sum
+    over the whole model (see ``dfedavgm.make_round_step``); the event's
+    ``loss``, ``clock``, ``ready_frac``, ``live_edges`` and staleness
+    (``mean_staleness``, ``max_staleness``) are emitted either way.
+
     ``with_telemetry``: additionally emit ``metrics["telemetry"]`` (a
     :class:`repro.telemetry.Telemetry` pytree): the event's staleness
     HISTOGRAM (per-client version lag, overflow bucket past the hard
@@ -395,19 +400,19 @@ def make_async_round_step(loss_fn: LossFn, cfg: DFedAvgMConfig,
         # Loss over the clients whose clocks fired (>= 1 by construction);
         # NOT ready_eff, which can be all-zero when the only finisher is
         # schedule-inactive — 0/1 would print as a spurious perfect loss.
+        lag = version_next.max() - version_next
         metrics = {
             "loss": jnp.sum(losses * ready) / ready.sum(),
             "clock": t_now,
             "ready_frac": jnp.mean(ready_eff),
             "live_edges": jnp.sum(
                 (W_eff * (1.0 - jnp.eye(m, dtype=jnp.float32))) != 0.0),
+            "mean_staleness": jnp.mean(lag.astype(jnp.float32)),
+            "max_staleness": lag.max(),
         }
         if with_metrics or with_telemetry:
             cdist = consensus_distance(x_next)
         if with_metrics:
-            lag = version_next.max() - version_next
-            metrics["mean_staleness"] = jnp.mean(lag.astype(jnp.float32))
-            metrics["max_staleness"] = lag.max()
             metrics["consensus_dist"] = cdist
         if with_telemetry:
             with jax.named_scope("round/telemetry"):

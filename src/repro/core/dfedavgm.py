@@ -149,6 +149,15 @@ def make_round_step(loss_fn: LossFn, cfg: DFedAvgMConfig,
     hold x), so with skip off it instead includes the discarded updates
     of inactive lanes.
 
+    ``with_metrics``: also emit the two full-model diagnostics,
+    ``consensus_dist`` (over x') and ``local_drift`` (over z). Each is a
+    cross-client mean of every leaf, which on a client mesh is an
+    all-reduce of the whole model, so a caller that reads them on few
+    rounds builds the step without them and applies
+    :func:`consensus_distance` to the returned params when it reads
+    (``launch/train.py`` does). ``loss`` and ``active_frac`` are emitted
+    either way.
+
     ``with_telemetry``: additionally emit ``metrics["telemetry"]`` — a
     :class:`repro.telemetry.Telemetry` pytree of in-graph observability
     counters (consensus distance, local drift, realized live edges and
@@ -336,7 +345,7 @@ def make_round_step(loss_fn: LossFn, cfg: DFedAvgMConfig,
             else:
                 x_next = mixer(state.params, z, key_mix, state.round)
         with jax.named_scope("round/metrics"):
-            if with_metrics and scheduled:
+            if scheduled:
                 metrics["active_frac"] = jnp.mean(active)
             # "loss" is the mean over clients that PARTICIPATED this
             # round — inactive clients' lanes are either skipped
@@ -519,7 +528,7 @@ def _make_fused_round_step(loss_fn: LossFn, cfg: DFedAvgMConfig,
                                    / jnp.maximum(active.sum(), 1.0))
             else:
                 metrics["loss"] = jnp.mean(losses)
-            if with_metrics and scheduled:
+            if scheduled:
                 metrics["active_frac"] = jnp.mean(active)
             if with_metrics or with_telemetry:
                 cdist = consensus_distance(x_next)

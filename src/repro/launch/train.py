@@ -24,8 +24,9 @@ import numpy as np
 from ..configs import get_config, reduced as make_reduced
 from ..core import (AsyncConfig, CommLedger, DFedAvgMConfig, MixingSpec,
                     QuantConfig, SpeedModel, TopologySchedule,
-                    async_event_bits, average_params, init_async_state,
-                    init_round_state, make_round_step, round_comm_bits)
+                    async_event_bits, average_params, consensus_distance,
+                    init_async_state, init_round_state, make_round_step,
+                    round_comm_bits)
 from ..core.topology import erdos_renyi_graph, ring_graph, torus_graph
 from ..data.synthetic import lm_client_batches, lm_round_batches
 from ..models import model as M
@@ -555,12 +556,19 @@ def _run_resident(args, cfg, log, tracer):
     # warning on CPU, a real saving on device).
     warnings.filterwarnings("ignore",
                             message="Some donated buffers were not usable")
+    # consensus_dist is a cross-client sum over the whole model (on a
+    # client mesh, an all-reduce of every leaf). Only the rounds whose
+    # record is written read it, so the step leaves it out and the driver
+    # evaluates it on the returned params there. --telemetry keeps it in
+    # the step: the telemetry pytree needs local_drift over z.
     round_step = make_round_step(loss, dfed, spec, mesh=mesh,
                                  client_axes=client_axes or (),
                                  param_specs=param_specs,
                                  async_cfg=acfg,
+                                 with_metrics=args.telemetry,
                                  with_telemetry=args.telemetry,
                                  placement=placement)
+    consensus = jax.jit(consensus_distance)
     if mesh is None and place_mesh is not None:
         # GSPMD would hand the dense mix back replicated on every device;
         # the clients stay on their shards for the next round.
@@ -604,6 +612,7 @@ def _run_resident(args, cfg, log, tracer):
     ledger = CommLedger(0.0 if acfg is not None
                         else round_comm_bits(spec, d, quant))
     records = []
+    n_consensus = 0
     for t in range(args.rounds):
         t_round = time.perf_counter()
         with tracer.span("round/data", t=t):
@@ -639,6 +648,10 @@ def _run_resident(args, cfg, log, tracer):
                 save_checkpoint(args.ckpt_dir, t + 1, state)
         cadence = t % max(1, args.rounds // 10) == 0 or t == args.rounds - 1
         if log.jsonl is not None or cadence:
+            n_consensus += 1
+            if "consensus_dist" not in metrics:
+                with tracer.span("round/consensus", t=t):
+                    metrics["consensus_dist"] = consensus(state.params)
             with tracer.span("round/d2h", t=t):
                 fields = _round_fields(metrics,
                                        comm_bits=ledger.total_bits)
@@ -650,6 +663,10 @@ def _run_resident(args, cfg, log, tracer):
                     round_s=time.perf_counter() - t_round, **fields))
     avg = average_params(state.params)
     log.info(f"done; consensus model leaves: {len(jax.tree.leaves(avg))}")
+    # Under --telemetry the step computes it every round.
+    log.info(f"consensus diagnostic on "
+             f"{args.rounds if args.telemetry else n_consensus} of "
+             f"{args.rounds} rounds")
     log.end(args.rounds, comm_bits=float(ledger.total_bits),
             final_loss=float(metrics["loss"]),
             final_consensus_dist=(float(metrics["consensus_dist"])
