@@ -1,46 +1,16 @@
 """Roofline table: reads experiments/dryrun/*.json produced by
-repro.launch.dryrun and emits one row per (arch x shape x mesh x tag),
-plus the fused-round bytes-moved/bytes-minimum rows from BENCH_gossip.json
-(written by bench_timevarying's gossip compare)."""
+repro.launch.dryrun and emits one row per (arch x shape x mesh x tag)."""
 import json
 from pathlib import Path
 
 OUT = Path(__file__).resolve().parents[1] / "experiments" / "dryrun"
-GOSSIP = Path(__file__).resolve().parents[1] / "BENCH_gossip.json"
-
-
-def _fused_rows():
-    """Round-level memory roofline: structural bytes moved per round over
-    the paper-minimum bill (K x (3 reads + 2 writes) of N + realized
-    wire), for the fused and unfused round builds."""
-    if not GOSSIP.exists():
-        return []
-    fz = json.loads(GOSSIP.read_text()).get("fused")
-    if not fz:
-        return []
-    rows = []
-    for arm in ("unfused", "fused"):
-        a = fz[arm]
-        rows.append((
-            f"roofline/round_{arm}_b{fz['bits']}",
-            a["roofline_ratio"],
-            f"bytes_moved={a['bytes_moved_per_round']:.3e};"
-            f"bytes_min={fz['bytes_min_per_round']:.3e};"
-            f"us={a['us_per_round']:.1f}"))
-    tk = fz["tail_kernel_bytes"]
-    rows.append((
-        "roofline/round_tail_kernels_fused_vs_unfused",
-        tk["fused"],
-        f"unfused_bytes={tk['unfused']:.3e};"
-        f"saved_frac={fz['tail_kernel_bytes_saved_frac']:.3f}"))
-    return rows
 
 
 def run():
-    rows = _fused_rows()
+    rows = []
     if not OUT.exists():
-        return rows + [("roofline/no-dryrun-data", 0.0,
-                        "run: python -m repro.launch.dryrun")]
+        return [("roofline/no-dryrun-data", 0.0,
+                 "run: python -m repro.launch.dryrun")]
     for f in sorted(OUT.glob("*.json")):
         rec = json.loads(f.read_text())
         name = f"roofline/{rec['arch']}/{rec['shape']}/{rec['mesh']}/" \

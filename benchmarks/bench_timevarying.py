@@ -274,139 +274,6 @@ _MESH2D_SRC = """
 """
 
 
-_FUSED_SRC = """
-    import json, warnings
-    import numpy as np, jax, jax.numpy as jnp
-    from jax.sharding import Mesh
-    from benchmarks.common import timeit_best
-    from repro.core import MixingSpec, QuantConfig
-    from repro.core.comm_cost import plan_round_bits
-    from repro.core.dfedavgm import (DFedAvgMConfig, init_round_state,
-                                     make_round_step)
-    from repro.launch.cost_model import structural_costs
-
-    warnings.filterwarnings("ignore",
-                            message="Some donated buffers were not usable")
-    m, d, K, iters = {m}, {d}, {K}, {iters}
-    mesh = Mesh(np.array(jax.devices()[:m]), ("clients",))
-    spec = MixingSpec.ring(m, self_weight=0.5)
-    plan = spec.gossip_plan()
-    q = QuantConfig(bits=8, stochastic=False, delta_mode="eq7")
-
-    def loss_fn(p, b, r):
-        return 0.5 * jnp.sum((p["w"] - b["c"]) ** 2)
-
-    params = {{"w": jax.random.normal(jax.random.PRNGKey(0), (m, d))}}
-    batches = {{"c": jax.random.normal(jax.random.PRNGKey(1), (m, K, d))}}
-    key = jax.random.PRNGKey(7)
-
-    # Paper-minimum HBM bill per round (the roofline denominator): each
-    # of the K local heavy-ball steps reads y, v, g and writes y', v' —
-    # 3 reads + 2 writes of the N=m*d f32 model elements (see the
-    # ``kernels/momentum_sgd.py`` docstring) — plus the realized wire
-    # bytes the gossip plan actually ships.
-    wire_bytes = plan_round_bits(plan, d, q) / 8.0
-    bytes_min = K * 5 * 4 * (m * d) + wire_bytes
-    out = {{"m": m, "d": d, "K": K, "bits": 8,
-            "bytes_min_per_round": bytes_min,
-            "realized_wire_bytes": wire_bytes}}
-
-    # ---- tail-stage kernel bytes (deterministic, trace-only) ----
-    # The stage the fusion rewrote, as its Pallas kernel sequence: the
-    # round's last two local updates + wire encode + decode-apply. The
-    # unfused tail runs two standalone momentum passes and a separate
-    # pack/mix; the fused tail is one encode kernel (update + pack) and
-    # one decode kernel (mix + deferred update). structural_costs counts
-    # a pallas_call's operand/output buffers exactly once — its true HBM
-    # traffic — so the comparison is exact and machine-independent.
-    from repro.core.wire_layout import WireLayout
-    from repro.kernels.momentum_sgd import momentum_sgd_pallas
-    from repro.kernels.quantize_pack import (
-        quantize_pack_buffer_pallas, momentum_quantize_pack_buffer_pallas)
-    from repro.kernels.dequant_mix import (
-        dequant_mix_buffer_pallas, dequant_mix_momentum_buffer_pallas)
-
-    lay = WireLayout.for_tree({{"w": jnp.zeros((d,), jnp.float32)}}, bits=8)
-    per, Wd = 32 // 8, lay.total_words
-    ks = 3                       # self + 2 ring neighbors
-    sds = jax.ShapeDtypeStruct
-    buf = sds((per, Wd), jnp.float32)
-    u32s = sds((ks, Wd), jnp.uint32)
-    sb = sds((ks, Wd // 512), jnp.float32)
-    wts = sds((ks,), jnp.float32)
-    et2 = sds((1, 2), jnp.float32)
-
-    def tail_unfused(y, v, g, x, streams, sblk, w):
-        y, v = momentum_sgd_pallas(y, v, g, eta=0.05, theta=0.9)
-        y, v = momentum_sgd_pallas(y, v, g, eta=0.05, theta=0.9)
-        words = quantize_pack_buffer_pallas(
-            y - x, sblk[:1], jnp.zeros_like(y), bits=8, stochastic=False)
-        return dequant_mix_buffer_pallas(x, streams, sblk, w, bits=8), words
-
-    def tail_fused(y, v, g, x, streams, sblk, w, et):
-        y1, v1, words = momentum_quantize_pack_buffer_pallas(
-            y, v, g, x, sblk[:1], jnp.zeros_like(y), et, bits=8,
-            stochastic=False)
-        return dequant_mix_momentum_buffer_pallas(
-            x, streams, sblk, w, v1, g, et, bits=8), words
-
-    tb_u = structural_costs(tail_unfused, buf, buf, buf, buf, u32s, sb,
-                            wts).bytes
-    tb_f = structural_costs(tail_fused, buf, buf, buf, buf, u32s, sb,
-                            wts, et2).bytes
-    out["tail_kernel_bytes"] = {{"unfused": tb_u, "fused": tb_f}}
-    out["tail_kernel_bytes_saved_frac"] = 1.0 - tb_f / tb_u
-    arms = {{}}
-    for arm, fuse in (("unfused", False), ("fused", True)):
-        cfg = DFedAvgMConfig(eta=0.05, theta=0.9, local_steps=K, quant=q,
-                             fuse_round=fuse)
-        raw = make_round_step(loss_fn, cfg, spec, mesh=mesh,
-                              client_axes=("clients",))
-        # Bytes come from the PLANAR-WIRE build — the Pallas-kernel
-        # program a TPU deployment runs, where the fused round's merged
-        # encode/decode passes are single pallas_call eqns. Tracing it is
-        # free on any backend (make_jaxpr never executes the kernels);
-        # the TIMED program below stays wire="auto" (the XLA oracle of
-        # the same math — interpret-mode Pallas wall clock on a CPU host
-        # would measure the interpreter, not the round).
-        planar = make_round_step(
-            loss_fn, DFedAvgMConfig(eta=0.05, theta=0.9, local_steps=K,
-                                    quant=q, fuse_round=fuse,
-                                    wire="planar"),
-            spec, mesh=mesh, client_axes=("clients",))
-        costs = structural_costs(planar, init_round_state(params, key),
-                                 batches)
-        step = jax.jit(raw, donate_argnums=(0,))
-        # Fresh buffer copies per arm: the donated state aliases params
-        # and key, and donation deletes them for the next arm otherwise.
-        st, _ = step(init_round_state(jax.tree.map(jnp.copy, params),
-                                      jnp.copy(key)), batches)
-        jax.block_until_ready(st.params)
-        arms[arm] = {{"step": step, "st": st, "us": float("inf")}}
-        out[arm] = {{"bytes_moved_per_round": costs.bytes,
-                     "roofline_ratio": costs.bytes / bytes_min}}
-    # INTERLEAVED best-of-5: alternating the arms inside every rep puts
-    # both on the same scheduler weather, so host noise cancels out of
-    # the fused-vs-unfused CI comparison instead of flipping it
-    # (timeit_best at reps=1 per arm per alternation, min() across).
-    for _ in range(5):
-        for arm in ("unfused", "fused"):
-            a = arms[arm]
-            us, a["st"] = timeit_best(
-                lambda i, st, step=a["step"]: step(st, batches)[0],
-                a["st"], iters=iters, reps=1)
-            a["us"] = min(a["us"], us)
-    for arm in ("unfused", "fused"):
-        out[arm]["us_per_round"] = arms[arm]["us"]
-    out["fused_speedup"] = (out["unfused"]["us_per_round"]
-                            / out["fused"]["us_per_round"])
-    out["fused_bytes_saved_frac"] = (
-        1.0 - out["fused"]["bytes_moved_per_round"]
-        / out["unfused"]["bytes_moved_per_round"])
-    print("JSON::" + json.dumps(out))
-"""
-
-
 def mesh2d_compare(smoke: bool = False) -> dict:
     """2D (clients x model) mesh vs the 1D client mesh: the same ring
     plan mixed with params model-sharded over 4 device columns. Each
@@ -423,23 +290,6 @@ def mesh2d_compare(smoke: bool = False) -> dict:
     assert res["wire_ratio_1d_over_2d_b32"] == float(mp), res
     assert res["wire_ratio_1d_over_2d_b8"] >= 3.0, res
     return res
-
-
-def fused_round_compare(smoke: bool = False) -> dict:
-    """Whole-round fused vs unfused: the overlapped variant
-    (``DFedAvgMConfig.fuse_round``) folds the last local step into the
-    wire encode, computes the final gradient inside the gossip window,
-    and applies mix + momentum in one decode pass. Reports best-of-3
-    wall clock plus the ROOFLINE columns the CI perf gate checks:
-    structural bytes moved per round vs the paper-minimum bill
-    (K x (3 reads + 2 writes) of N, plus realized wire). Lands under the
-    ``fused`` key of BENCH_gossip.json."""
-    m = 8
-    d = 16384 if smoke else 65536
-    K = 4
-    iters = 5 if smoke else 20
-    return _run_json_subprocess(
-        _FUSED_SRC.format(m=m, d=d, K=K, iters=iters), m)
 
 
 def telemetry_overhead_compare(smoke: bool = False) -> dict:
@@ -610,9 +460,6 @@ def gossip_backend_compare(smoke: bool = False) -> list[tuple]:
     # 2D mesh arm: model-parallel columns vs the 1D client mesh — the
     # per-device wire must shrink ~linearly with the MP degree.
     res["mesh2d"] = mesh2d_compare(smoke=smoke)
-    # Fused-round arm: the overlapped variant against the default round
-    # on the same mesh, with the roofline columns CI gates on.
-    res["fused"] = fused_round_compare(smoke=smoke)
     # Telemetry-overhead arm: with_telemetry on vs off, gated <= 1.10x.
     res["telemetry"] = telemetry_overhead_compare(smoke=smoke)
     # Placement arm: contiguous vs partitioned lane layout on irregular
@@ -652,15 +499,6 @@ def gossip_backend_compare(smoke: bool = False) -> list[tuple]:
         f"wire1dB={m1a['payload_permute_bytes_per_device']:.0f}|"
         f"ratio={m2['wire_ratio_1d_over_2d_b8']:.2f}|"
         f"fp32_ratio={m2['wire_ratio_1d_over_2d_b32']:.2f}"))
-    fz = res["fused"]
-    rows.append((
-        "round_fused_vs_unfused_b8",
-        fz["fused"]["us_per_round"],
-        f"unfused_us={fz['unfused']['us_per_round']:.1f}|"
-        f"speedup={fz['fused_speedup']:.2f}|"
-        f"fused_roofline={fz['fused']['roofline_ratio']:.2f}|"
-        f"unfused_roofline={fz['unfused']['roofline_ratio']:.2f}|"
-        f"bytes_saved_frac={fz['fused_bytes_saved_frac']:.3f}"))
     tl = res["telemetry"]
     rows.append((
         "round_telemetry_on_vs_off",

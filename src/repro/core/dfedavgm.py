@@ -20,10 +20,9 @@ from typing import Any, Callable, NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 
-from .local_sgd import local_train, local_train_deferred
+from .local_sgd import local_train
 from .mixing import (MixerConfig, _clients_per_shard, _quant_leaf_keys,
-                     consensus_distance, make_event_mixer, make_fused_tail,
-                     make_mixer)
+                     consensus_distance, make_event_mixer, make_mixer)
 from .quantize import QuantConfig, message_bits
 from .topology import MixingSpec, TopologySchedule
 
@@ -48,14 +47,6 @@ class DFedAvgMConfig:
     wire:  flat wire-buffer codec backend for the sparse mixer — "auto"
            (Pallas buffer kernels on TPU, XLA lowering elsewhere),
            "planar" (force the kernels), "seq" (force the XLA lowering)
-    fuse_round: opt into the FUSED ROUND (``core.mixing.make_fused_tail``):
-           the last two local steps fold into the wire encode/decode
-           kernels and every plan step's transfer overlaps the final
-           gradient. An algorithm VARIANT — it defers one local step past
-           the mix (neighbors see y_{K-1}, not y_K), so it is NOT
-           bit-compatible with the default round except at ``eta == 0``.
-           Needs ``local_steps >= 2``; incompatible with stateful
-           schedules, compute-skip gathers, and the async engine.
     """
 
     eta: float = 0.01
@@ -64,7 +55,6 @@ class DFedAvgMConfig:
     quant: QuantConfig | None = None
     mixer_impl: str = "auto"
     wire: str = "auto"
-    fuse_round: bool = False
 
     def mixer_config(self) -> MixerConfig:
         return MixerConfig(impl=self.mixer_impl, quant=self.quant,
@@ -196,14 +186,6 @@ def make_round_step(loss_fn: LossFn, cfg: DFedAvgMConfig,
             client_axes=client_axes, param_specs=param_specs,
             fused_update=fused_update, with_metrics=with_metrics,
             with_telemetry=with_telemetry)
-
-    if cfg.fuse_round:
-        return _make_fused_round_step(
-            loss_fn, cfg, spec, mesh=mesh, client_axes=client_axes,
-            param_specs=param_specs, fused_update=fused_update,
-            with_metrics=with_metrics, with_telemetry=with_telemetry,
-            skip_inactive_compute=skip_inactive_compute,
-            placement=placement)
 
     scheduled = isinstance(spec, TopologySchedule)
     stateful = scheduled and spec.is_stateful
@@ -418,143 +400,6 @@ def make_round_step(loss_fn: LossFn, cfg: DFedAvgMConfig,
             round_next = state.round + 1
         new_state = RoundState(params=x_next, rng=key_next,
                                round=round_next, token=token_next)
-        return new_state, metrics
-
-    return round_step
-
-
-def _make_fused_round_step(loss_fn: LossFn, cfg: DFedAvgMConfig,
-                           spec: MixingSpec | TopologySchedule,
-                           mesh=None, client_axes: Sequence[str] = (),
-                           param_specs: Pytree | None = None,
-                           fused_update=None, with_metrics: bool = True,
-                           with_telemetry: bool = False,
-                           skip_inactive_compute: bool | str = "auto",
-                           placement=None) -> Callable:
-    """The ``cfg.fuse_round`` realization of :func:`make_round_step`: K-2
-    local steps run in the usual scan (``local_train_deferred``), then the
-    whole tail — penultimate update + wire encode (one fused pass), every
-    plan step's ppermute, the LAST gradient inside the overlap window, and
-    mix + deferred last update (one fused pass) — executes through
-    ``core.mixing.make_fused_tail``. Same ``round_step(state, batches) ->
-    (state', metrics)`` contract and PRNG discipline (per-step keys are
-    ``jax.random.split(client_key, K)`` either way); the ``loss`` metric
-    averages the identical K per-step losses. NOT bit-compatible with the
-    unfused round except at ``eta == 0`` (the variant defers one step past
-    the mix — see ``make_fused_tail``)."""
-    scheduled = isinstance(spec, TopologySchedule)
-    if scheduled and spec.is_stateful:
-        raise ValueError("fuse_round does not support stateful schedules "
-                         "(the walk token gates compute mid-round)")
-    if skip_inactive_compute is True:
-        raise ValueError("fuse_round runs the full-width client vmap; "
-                         "skip_inactive_compute=True is incompatible")
-    if cfg.local_steps < 2:
-        raise ValueError(
-            f"fuse_round needs local_steps >= 2 (one step is deferred "
-            f"past the mix), got {cfg.local_steps}")
-    m = spec.m
-    mcfg = cfg.mixer_config()
-    impl = mcfg.resolved_impl(spec, mesh, client_axes)
-    # Cycle schedules switch between per-member plans in the unfused
-    # sparse path; the fused tail keeps one backend per step, so they
-    # take the dense reference.
-    sparse = impl in ("ring", "torus", "sparse") and not (
-        scheduled and spec.kind == "cycle")
-    plan = spec.gossip_plan() if sparse else None
-    if placement is not None:
-        if plan is None:
-            raise ValueError("placement requires the sparse backend, "
-                             f"got impl={impl!r}")
-        plan = plan.placed(placement)
-    perm = None if placement is None else jnp.asarray(placement.perm)
-    gate = bool(scheduled and spec.gates_participation)
-    tail = make_fused_tail(
-        loss_fn, m, eta=cfg.eta, theta=cfg.theta, quant=cfg.quant,
-        mesh=mesh, client_axes=client_axes, param_specs=param_specs,
-        plan=plan, wire=cfg.wire, gate=gate)
-    ones = jnp.ones((m,), jnp.float32)
-    if with_telemetry:
-        from ..telemetry.metrics import (Telemetry, client_dim,
-                                         live_edge_count, wire_bits_for)
-        static_edges = (None if scheduled
-                        else float(spec.graph.num_directed_edges()))
-        placement_lanes = (None if plan is None else
-                           _placed_boundary_lane_slots(plan, mesh,
-                                                       client_axes))
-
-    def round_step(state: RoundState, batches: Pytree):
-        with jax.named_scope("round/keys"):
-            key_round, key_mix, key_next = jax.random.split(state.rng, 3)
-            client_keys = jax.random.split(key_round, m)
-            if perm is not None:
-                # Lane order: lane p trains client perm[p] (keys derive
-                # in client order first — see make_round_step).
-                batches = jax.tree.map(lambda b: b[perm], batches)
-                client_keys = client_keys[perm]
-        K = jax.tree.leaves(batches)[0].shape[1]
-
-        train_head = lambda p, b, k: local_train_deferred(
-            loss_fn, p, b, k, eta=cfg.eta, theta=cfg.theta,
-            fused_update=fused_update)
-        with jax.named_scope("round/local_sgd"):
-            y, v, g, losses_head = jax.vmap(train_head)(
-                state.params, batches, client_keys)      # losses [m, K-1]
-
-        with jax.named_scope("round/mix/wire/exchange"):
-            if scheduled:
-                W_t, active, key_q = spec.round_event(key_mix, state.round)
-                if perm is not None:
-                    active = active[perm]  # client-order event, lane state
-            else:
-                W_t = jnp.asarray(spec.W, jnp.float32)
-                active, key_q = ones, key_mix
-        with jax.named_scope("round/keys"):
-            batch_last = jax.tree.map(lambda b: b[:, K - 1], batches)
-            keys_last = jax.vmap(
-                lambda ck: jax.random.split(ck, K)[K - 1])(client_keys)
-
-        with jax.named_scope("round/mix"):
-            x_next, y_pub, loss_last = tail(
-                state.params, y, v, g, batch_last, keys_last, key_q, active,
-                W_t)
-        metrics = {}
-        with jax.named_scope("round/metrics"):
-            losses = jnp.mean(
-                jnp.concatenate([losses_head, loss_last[:, None]], axis=1),
-                axis=1)                                  # [m], mean over K
-            if scheduled and spec.gates_participation:
-                metrics["loss"] = (jnp.sum(losses * active)
-                                   / jnp.maximum(active.sum(), 1.0))
-            else:
-                metrics["loss"] = jnp.mean(losses)
-            if scheduled:
-                metrics["active_frac"] = jnp.mean(active)
-            if with_metrics or with_telemetry:
-                cdist = consensus_distance(x_next)
-                drift = consensus_distance(y_pub)
-        if with_metrics:
-            metrics["consensus_dist"] = cdist
-            metrics["local_drift"] = drift
-        if with_telemetry:
-            with jax.named_scope("round/telemetry"):
-                live = (live_edge_count(W_t) if scheduled
-                        else jnp.float32(static_edges))
-                d = client_dim(state.params)
-                # Quantizer fields stay None here: the fused tail's wire
-                # delta (y1 - x, formed INSIDE the encode kernels) never
-                # exists as a separate tensor to replay against.
-                metrics["telemetry"] = Telemetry(
-                    consensus_dist=cdist, local_drift=drift,
-                    live_edges=live,
-                    wire_bits=wire_bits_for(d, cfg.quant, live),
-                    placement_boundary_lanes=(
-                        None if placement_lanes is None
-                        else jnp.float32(placement_lanes)))
-        with jax.named_scope("round/keys"):
-            round_next = state.round + 1
-        new_state = RoundState(params=x_next, rng=key_next,
-                               round=round_next, token=state.token)
         return new_state, metrics
 
     return round_step

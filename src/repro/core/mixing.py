@@ -75,8 +75,8 @@ from .wire_layout import WireLayout, rows_to_planar
 Pytree = Any
 
 __all__ = ["MixerConfig", "make_mixer", "make_scheduled_mixer", "mix_dense",
-           "make_plan_mixer", "make_event_mixer", "make_fused_tail",
-           "execute_plan_reference", "consensus_distance"]
+           "make_plan_mixer", "make_event_mixer", "execute_plan_reference",
+           "consensus_distance"]
 
 _IMPLS = ("auto", "dense", "ring", "torus", "sparse")
 _WIRES = ("auto", "seq", "planar")
@@ -763,379 +763,6 @@ def make_event_mixer(m: int, quant: QuantConfig | None = None, mesh=None,
         return _mix_dense_quantized(W, x, z_eff, quant, key)
 
     return mix_event
-
-
-# ---------------------------------------------------------------------------
-# Fused-round tail: deferred last two local steps + wire + mix, overlapped
-# ---------------------------------------------------------------------------
-
-def make_fused_tail(loss_fn, m: int, *, eta: float, theta: float,
-                    quant: QuantConfig | None = None, mesh=None,
-                    client_axes: Sequence[str] = ("clients",),
-                    param_specs: Pytree | None = None,
-                    plan: GossipPlan | None = None, wire: str = "auto",
-                    gate: bool = True) -> Callable:
-    """Fused-round tail: the round's last two local steps, the wire
-    encode, every plan step's ppermute, and the combined decode-apply in
-    ONE overlapped stage (see ``DFedAvgMConfig.fuse_round``).
-
-    The returned
-    ``tail(x, y, v, g, batch_last, keys_last, key_q, active, W)``
-    consumes :func:`~repro.core.local_sgd.local_train_deferred`'s output
-    (``y``/``v``/``g`` the round's un-applied penultimate step, stacked
-    over clients) and runs, per client:
-
-      1. SEND — one fused pass applies ``v' = theta*v - eta*g;
-         y' = y + v'`` and emits ``pack(Q(y' - x))`` as a SIDE OUTPUT
-         (``WireLayout.encode_momentum``): the wire buffer never costs
-         its own trip over the model. The published ``z`` is ``y'``.
-      2. Every plan step's masked ppermute issues immediately — the
-         sends all read the same stream, a dataflow antichain.
-      3. OVERLAP WINDOW — the round's LAST gradient ``g_K = grad(y')``
-         computes between issue and decode, so on hardware with async
-         collectives the wire flies behind it.
-      4. RECEIVE — one fused pass mixes the received streams AND applies
-         the deferred last update (``WireLayout.decode_apply_momentum``):
-         ``x' = [base + sum_k w_k*deq(stream_k)] + (theta*v' - eta*g_K)``
-         — mix -> v' -> y' in a single read/write of the model.
-
-    Relative to the unfused round this defers ONE local step past the
-    mix — neighbors see ``y_{K-1}``, not ``y_K`` — trading one step of
-    wire freshness for full wire/compute overlap. It is an algorithm
-    VARIANT, not a bit-compatible rewrite; at ``eta == 0`` the deferred
-    updates vanish and the two rounds coincide bitwise (pinned in
-    ``tests/test_fused_round.py``). Inactive clients (``gate=True``)
-    gate to ``y = x, v = g = 0`` before the encode, so they publish
-    ``Q(0)``, apply a zero deferred update, and are held exactly.
-
-    Backend mirrors :func:`make_event_mixer`: ``plan=None`` is the dense
-    reference (einsum mix, any ``W``); a :class:`GossipPlan` runs the
-    sparse masked-ppermute realization (one-client-per-shard or
-    block-sharded). Returns ``(x_next, y_pub, loss_last)``: ``y_pub``
-    the published z (consensus-drift metric), ``loss_last`` [m] the last
-    step's per-client losses.
-    """
-    grad_fn = jax.value_and_grad(loss_fn)
-    eta_f = jnp.float32(eta)
-    theta_f = jnp.float32(theta)
-    quant_on = quant is not None and quant.enabled
-
-    def _gate0(tree, active):
-        return jax.tree.map(
-            lambda l: (l * active.reshape((-1,) + (1,) * (l.ndim - 1)))
-            .astype(l.dtype), tree)
-
-    if plan is None:
-        # ---- dense reference: tree-level, any (traced) W ----
-        def tail(x, y, v, g, batch_last, keys_last, key_q, active, W):
-            if gate:
-                y = jax.tree.map(
-                    lambda yl, xl: jnp.where(
-                        active.reshape((-1,) + (1,) * (yl.ndim - 1)) > 0,
-                        yl, xl), y, x)
-                v, g = _gate0(v, active), _gate0(g, active)
-            v1 = jax.tree.map(
-                lambda vl, gl: theta_f * vl.astype(jnp.float32)
-                - eta_f * gl.astype(jnp.float32), v, g)
-            y1 = jax.tree.map(
-                lambda yl, vl: (yl.astype(jnp.float32) + vl)
-                .astype(yl.dtype), y, v1)
-            loss_last, gK = jax.vmap(grad_fn)(y1, batch_last, keys_last)
-            if gate:
-                gK = _gate0(gK, active)
-            mixed = (_mix_dense_quantized(W, x, y1, quant, key_q)
-                     if quant_on else mix_dense(W, y1))
-            x_next = jax.tree.map(
-                lambda ml, vl, gl: (ml.astype(jnp.float32) + theta_f * vl
-                                    - eta_f * gl.astype(jnp.float32))
-                .astype(ml.dtype), mixed, v1, gK)
-            return x_next, y1, loss_last
-
-        return tail
-
-    # ---- sparse: shard_map + masked ppermutes, stacked [m_local] form ----
-    if plan.m != m:
-        raise ValueError(f"plan has m={plan.m}, expected {m}")
-    ca = tuple(client_axes)
-    m_local = _clients_per_shard(mesh, ca, m)
-    if m_local is None:
-        raise ValueError(
-            f"fused sparse tail needs a mesh carrying a client block per "
-            f"shard: m={m}, client_axes={ca!r}")
-    if _specs_model_sharded(param_specs, _model_axes(mesh, ca)):
-        raise ValueError(
-            "fuse_round is not supported with model-sharded params on a "
-            "2D (clients, model) mesh: the fused tail computes the "
-            "round's last gradient INSIDE the client shard_map body, "
-            "which would see only this device's model slice of the "
-            "params. Run the unfused round (fuse_round=False) — its "
-            "local SGD runs outside the mixer under GSPMD, which "
-            "partitions the loss over the model axis automatically.")
-    axis = ca[0] if len(ca) == 1 else ca
-    pairs = [plan.wire_pairs(k) for k in range(plan.n_steps)]
-    live = [k for k in range(plan.n_steps) if pairs[k]]
-    pallas = _pallas_wire(wire)
-    lemma5 = quant_on and quant.delta_mode == "lemma5"
-
-    # ONE realization for every shard width: the compiled BlockPlan's
-    # intra gathers + boundary sub-step ppermutes (at m_local == 1 each
-    # plan step is exactly one width-1 sub-step — the historical
-    # one-permute-per-step program, pinned by the mesh HLO tests).
-    bp = plan.block_plan(plan.m // m_local)
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    intra_t = {k: jnp.asarray(bp.intra_src[k]) for k in live}
-    sub_t = {k: [(sub, jnp.asarray(sub.send_lanes),
-                  jnp.asarray(sub.recv_lanes)) for sub in bp.substeps[k]]
-             for k in live}
-
-    def sid():
-        idx = jax.lax.axis_index(ca[0])
-        for a in ca[1:]:
-            idx = idx * sizes[a] + jax.lax.axis_index(a)
-        return idx
-
-    def issue_steps(stream, s):
-        # All sends read `stream` — a dataflow antichain; the
-        # boundary collectives overlap each other and the gradient
-        # computed between issue and combine.
-        return {k: [jax.lax.ppermute(_take_lanes(stream, send[s]), axis,
-                                     sub.pairs)
-                    for sub, send, _ in sub_t[k]] for k in live}
-
-    def combine_step(stream, got_k, k, s):
-        out = _take_lanes(stream, intra_t[k][s])
-        for (sub, send, recv), got in zip(sub_t[k], got_k):
-            out = _put_lanes(out, recv[s], got)
-        return out
-
-    if not quant_on:
-        # fp32 wire: the fused update+publish and mix+deferred-update are
-        # plain XLA elementwise chains (XLA fuses them natively — the
-        # Pallas kernels exist for the quantized wire); the overlap
-        # structure is identical to the quantized body.
-        def body(x_bl, y_bl, v_bl, g_bl, batch_bl, klast_bl, wself, wsteps,
-                 act):
-            with jax.named_scope("wire/exchange"):
-                s = sid()
-            layout = WireLayout.for_tree(jax.tree.map(lambda a: a[0], x_bl))
-            # Tree-level penultimate step: only the published z ever gets
-            # flattened to a wire row (same layout traffic as the unfused
-            # round); XLA fuses the elementwise chains.
-            if gate:
-                with jax.named_scope("wire/pack"):
-                    y_bl = jax.tree.map(
-                        lambda yl, xl: jnp.where(
-                            act.reshape((-1,) + (1,) * (yl.ndim - 1)) > 0,
-                            yl, xl), y_bl, x_bl)
-                    v_bl, g_bl = _gate0(v_bl, act), _gate0(g_bl, act)
-            with jax.named_scope("sgd/update"):
-                v1 = jax.tree.map(
-                    lambda vl, gl: theta_f * vl.astype(jnp.float32)
-                    - eta_f * gl.astype(jnp.float32), v_bl, g_bl)
-                y1 = jax.tree.map(
-                    lambda yl, vl: (yl.astype(jnp.float32) + vl)
-                    .astype(yl.dtype), y_bl, v1)
-            with jax.named_scope("wire/pack"):
-                z = jax.vmap(layout.flatten_f32)(y1)    # published y_{K-1}
-            with jax.named_scope("wire/exchange"):
-                got = issue_steps(z, s)
-            # ---- overlap window: the last gradient computes while the
-            # wire flies — nothing below reads a received buffer until
-            # the weighted combine.
-            with jax.named_scope("sgd/grad"):
-                loss_last, gK = jax.vmap(grad_fn)(y1, batch_bl, klast_bl)
-            if gate:
-                with jax.named_scope("sgd/update"):
-                    gK = _gate0(gK, act)
-            # The f32 wire has no codec: its decode is the weighted sum.
-            with jax.named_scope("wire/decode"):
-                acc = wself[:, None] * z
-            for k in live:
-                with jax.named_scope("wire/decode"):
-                    w_k = wsteps[k][:, None]
-                with jax.named_scope("wire/exchange"):
-                    recv = combine_step(z, got[k], k, s)
-                with jax.named_scope("wire/decode"):
-                    acc = acc + w_k * recv
-            with jax.named_scope("wire/unpack"):
-                mixed = jax.vmap(layout.unflatten)(acc)
-            with jax.named_scope("sgd/update"):
-                x_next = jax.tree.map(
-                    lambda ml, vl, gl: (ml.astype(jnp.float32)
-                                        + theta_f * vl
-                                        - eta_f * gl.astype(jnp.float32))
-                    .astype(ml.dtype), mixed, v1, gK)
-            return x_next, y1, loss_last
-
-        def tail(x, y, v, g, batch_last, keys_last, key_q, active, W):
-            del key_q
-            with jax.named_scope("wire/exchange"):
-                w_self, w_steps = plan.gather_weights(W)
-            specs = _full_specs(x, ca, param_specs)
-            bspecs = _full_specs(batch_last, ca, None)
-            fn = jax.shard_map(body, mesh=mesh,
-                               in_specs=(specs, specs, specs, specs, bspecs,
-                                         P(ca, None), P(ca), P(None, ca),
-                                         P(ca)),
-                               out_specs=(specs, specs, P(ca)))
-            with jax.named_scope("wire/exchange"):
-                w_self = jnp.asarray(w_self, jnp.float32)
-                w_steps = jnp.asarray(w_steps, jnp.float32)
-                active = jnp.asarray(active, jnp.float32)
-            return fn(x, y, v, g, batch_last, keys_last, w_self, w_steps,
-                      active)
-
-        return tail
-
-    def q_body(x_bl, y_bl, v_bl, g_bl, batch_bl, klast_bl, keys_blk,
-               wself, wsteps, act):
-        with jax.named_scope("wire/exchange"):
-            s = sid()
-        layout = WireLayout.for_tree(jax.tree.map(lambda a: a[0], x_bl),
-                                     bits=quant.bits)
-        nl, Wd = layout.n_leaves, layout.total_words
-        with jax.named_scope("wire/pack"):
-            x2d = layout.to_planar_stacked(x_bl)    # [m_local, per, W]
-        m_loc = x2d.shape[0]
-        with jax.named_scope("wire/encode/noise"):
-            leaf_keys = (jnp.transpose(keys_blk, (1, 0, 2))
-                         if quant.stochastic else None)
-        if pallas:
-            # Kernel path: y/v/g are staged planar so the fused kernels
-            # stream them — the penultimate update + pack is ONE pass,
-            # mix + deferred update is ONE pass.
-            with jax.named_scope("wire/pack"):
-                y2d = layout.to_planar_stacked(y_bl)
-                v2d = layout.to_planar_stacked(v_bl)
-                g2d = layout.to_planar_stacked(g_bl)
-                if gate:
-                    am = act[:, None, None]
-                    y2d = jnp.where(am > 0, y2d, x2d)
-                    v2d = v2d * am
-                    g2d = g2d * am
-            with jax.named_scope("wire/encode"):
-                et = jnp.tile(jnp.stack([eta_f, theta_f])[None],
-                              (m_loc, 1))
-            # Scales of the RESULTING delta, same expression order as the
-            # fused kernel — a reduction, not another full-size buffer
-            # pass.
-            with jax.named_scope("wire/scales"):
-                delta = (y2d + (theta_f * v2d - eta_f * g2d)) - x2d
-                scales = layout.leaf_scales(delta, quant)  # [m_local, nl]
-            # SEND: apply the penultimate step and emit the wire words as
-            # a side output of the same pass.
-            y_out, v_out, words = layout.encode_momentum(
-                y2d, v2d, g2d, x2d, scales, et, quant,
-                leaf_keys=leaf_keys, pallas=True)
-        else:
-            # Oracle path (CPU/seq wire): the same math at TREE level —
-            # XLA fuses the elementwise chains the Pallas kernels fuse by
-            # hand, and only z and x ever get planar-staged, matching the
-            # unfused round's layout traffic.
-            if gate:
-                with jax.named_scope("wire/pack"):
-                    y_bl = jax.tree.map(
-                        lambda yl, xl: jnp.where(
-                            act.reshape((-1,) + (1,) * (yl.ndim - 1)) > 0,
-                            yl, xl), y_bl, x_bl)
-                    v_bl, g_bl = _gate0(v_bl, act), _gate0(g_bl, act)
-            with jax.named_scope("sgd/update"):
-                v1 = jax.tree.map(
-                    lambda vl, gl: theta_f * vl.astype(jnp.float32)
-                    - eta_f * gl.astype(jnp.float32), v_bl, g_bl)
-                y1 = jax.tree.map(
-                    lambda yl, vl: (yl.astype(jnp.float32) + vl)
-                    .astype(yl.dtype), y_bl, v1)
-            with jax.named_scope("wire/pack"):
-                z2d = layout.to_planar_stacked(y1)
-                delta = z2d - x2d
-            with jax.named_scope("wire/scales"):
-                scales = layout.leaf_scales(delta, quant)  # [m_local, nl]
-            words = layout.encode(delta, scales, quant,
-                                  leaf_keys=leaf_keys, pallas=False)
-        with jax.named_scope("wire/stream"):
-            tail_ = [jax.lax.bitcast_convert_type(scales, jnp.uint32)]
-            if lemma5:
-                tail_.append(jax.lax.bitcast_convert_type(
-                    x2d.reshape(m_loc, -1), jnp.uint32))
-            stream = jnp.concatenate([words] + tail_, axis=1)  # [m_loc, L]
-        with jax.named_scope("wire/exchange"):
-            got = issue_steps(stream, s)
-        # ---- overlap window: the round's LAST gradient computes while
-        # the wire flies — nothing below touches a received stream until
-        # the fused decode.
-        with jax.named_scope("wire/unpack"):
-            y_pub = layout.from_planar_stacked(y_out) if pallas else y1
-        with jax.named_scope("sgd/grad"):
-            loss_last, gK = jax.vmap(grad_fn)(y_pub, batch_bl, klast_bl)
-        if pallas:
-            with jax.named_scope("wire/pack"):
-                gK2d = layout.to_planar_stacked(gK)
-                if gate:
-                    gK2d = gK2d * act[:, None, None]
-        elif gate:
-            with jax.named_scope("sgd/update"):
-                gK = _gate0(gK, act)
-        with jax.named_scope("wire/exchange"):
-            streams = [stream] + [combine_step(stream, got[k], k, s)
-                                  for k in live]
-        with jax.named_scope("wire/stream"):
-            wlist = [wself] + [wsteps[k] for k in live]
-            weights = jnp.stack(wlist, axis=1)      # [m_local, K]
-            words_all, scales_all, xs = _split_streams(streams, Wd, nl,
-                                                       layout.per, lemma5)
-            base = _weighted_replica_base(xs, weights) if lemma5 else x2d
-        # RECEIVE: mix + deferred last update in one fused pass (kernel
-        # path) / one XLA-fused chain (oracle path).
-        if pallas:
-            out2d = layout.decode_apply_momentum(
-                base, words_all, scales_all, weights, v_out, gK2d, et,
-                quant, pallas=True)
-            with jax.named_scope("wire/unpack"):
-                return layout.from_planar_stacked(out2d), y_pub, loss_last
-        out2d = layout.decode_apply(base, words_all, scales_all, weights,
-                                    quant, pallas=False)
-        with jax.named_scope("wire/unpack"):
-            mixed = layout.from_planar_stacked(out2d)
-        with jax.named_scope("sgd/update"):
-            x_next = jax.tree.map(
-                lambda ml, vl, gl: (ml.astype(jnp.float32) + theta_f * vl
-                                    - eta_f * gl.astype(jnp.float32))
-                .astype(ml.dtype), mixed, v1, gK)
-        return x_next, y_pub, loss_last
-
-    def tail(x, y, v, g, batch_last, keys_last, key_q, active, W):
-        with jax.named_scope("wire/exchange"):
-            w_self, w_steps = plan.gather_weights(W)
-        specs = _full_specs(x, ca, param_specs)
-        bspecs = _full_specs(batch_last, ca, None)
-        n_leaves = len(jax.tree.leaves(x))
-        with jax.named_scope("wire/encode/noise"):
-            if quant.stochastic:
-                keys = jnp.transpose(_quant_leaf_keys(key_q, n_leaves, m),
-                                     (1, 0, 2))     # [m(client), nl, 2]
-                if plan.lane_to_client is not None:
-                    # Placed plan: lane p replays client
-                    # lane_to_client[p]'s draws (client-space key
-                    # derivation, like the unfused executor) — placed ==
-                    # unplaced bitwise.
-                    keys = keys[jnp.asarray(plan.lane_to_client)]
-            else:
-                keys = jnp.zeros((m, 1, 2), jnp.uint32)
-        fn = jax.shard_map(q_body, mesh=mesh,
-                           in_specs=(specs, specs, specs, specs, bspecs,
-                                     P(ca, None), P(ca, None, None), P(ca),
-                                     P(None, ca), P(ca)),
-                           out_specs=(specs, specs, P(ca)),
-                           check_vma=not pallas)
-        with jax.named_scope("wire/exchange"):
-            w_self = jnp.asarray(w_self, jnp.float32)
-            w_steps = jnp.asarray(w_steps, jnp.float32)
-            active = jnp.asarray(active, jnp.float32)
-        return fn(x, y, v, g, batch_last, keys_last, keys, w_self, w_steps,
-                  active)
-
-    return tail
 
 
 # ---------------------------------------------------------------------------

@@ -336,94 +336,6 @@ class WireLayout:
         return kref.quantize_pack_buffer_ref(delta, sblk, quant.bits,
                                              noise=noise)
 
-    @jax.named_scope("wire/encode")
-    def encode_momentum(self, y2d: jnp.ndarray, v2d: jnp.ndarray,
-                        g2d: jnp.ndarray, x2d: jnp.ndarray,
-                        scales: jnp.ndarray, et: jnp.ndarray, quant,
-                        leaf_keys=None, pallas: bool = False,
-                        noise=None
-                        ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-        """Fused-round send side: apply the last local heavy-ball step and
-        emit the wire words as a side output of the same pass —
-
-            v' = theta*v - eta*g ;  y' = y + v' ;  words = pack(Q(y' - x))
-
-        y2d/v2d/g2d/x2d [per, W] f32 (pallas 2D) or [..., per, W] (xla /
-        block-sharded lax.map); scales [..., n_leaves] of the RESULTING
-        delta (caller computes them from the identical expression order —
-        a reduction, not a buffer write); et f32 [..., 2] = (eta, theta),
-        runtime (traced OK). Returns (y', v', words [..., W]).
-        """
-        from ..kernels import ref as kref
-        sblk = self.block_scales(scales)
-        stochastic = bool(quant.stochastic)
-        if stochastic and noise is None:
-            if leaf_keys is None:
-                raise ValueError("stochastic encode needs per-leaf keys")
-            with jax.named_scope("noise"):
-                noise = (self.noise(leaf_keys) if y2d.ndim == 2
-                         else self.noise_stacked(leaf_keys))
-        elif not stochastic:
-            noise = None
-        if pallas:
-            from ..kernels.ops import default_interpret
-            from ..kernels.quantize_pack import (
-                momentum_quantize_pack_buffer_pallas)
-            nz = noise if noise is not None else jnp.zeros_like(y2d)
-            if y2d.ndim == 3:
-                # Block-sharded lane axis: one traced per-lane kernel
-                # body via lax.map (see encode above).
-                return jax.lax.map(
-                    lambda a: momentum_quantize_pack_buffer_pallas(
-                        a[0], a[1], a[2], a[3], a[4].reshape(1, -1), a[5],
-                        a[6], bits=quant.bits, stochastic=stochastic,
-                        interpret=default_interpret()),
-                    (y2d, v2d, g2d, x2d, sblk, nz, et))
-            return momentum_quantize_pack_buffer_pallas(
-                y2d, v2d, g2d, x2d, sblk.reshape(1, -1), nz, et,
-                bits=quant.bits, stochastic=stochastic,
-                interpret=default_interpret())
-        eta = et[..., 0]
-        theta = et[..., 1]
-        return kref.momentum_quantize_pack_buffer_ref(
-            y2d, v2d, g2d, x2d, sblk, quant.bits,
-            eta[..., None, None] if eta.ndim else eta,
-            theta[..., None, None] if theta.ndim else theta, noise=noise)
-
-    @jax.named_scope("wire/decode")
-    def decode_apply_momentum(self, base: jnp.ndarray, streams: jnp.ndarray,
-                              scales: jnp.ndarray, weights: jnp.ndarray,
-                              v2d: jnp.ndarray, g2d: jnp.ndarray,
-                              et: jnp.ndarray, quant,
-                              pallas: bool = False) -> jnp.ndarray:
-        """Fused-round receive side: the combined decode-apply AND deferred
-        final momentum step in one memory pass —
-
-            out = [base + sum_k weights[k]*deq(streams[k])] + (theta*v - eta*g)
-
-        base/v2d/g2d [..., per, W]; streams uint32 [..., k, W]; scales
-        [..., k, n_leaves]; weights [..., k]; et f32 [..., 2]. No v
-        output — momentum restarts every round (Algorithm 1)."""
-        sblk = self.block_scales(scales)
-        if pallas:
-            from ..kernels.dequant_mix import (
-                dequant_mix_momentum_buffer_pallas)
-            from ..kernels.ops import default_interpret
-            if base.ndim == 3:
-                # Block-sharded lane axis: one traced per-lane kernel
-                # body via lax.map (see encode above).
-                return jax.lax.map(
-                    lambda a: dequant_mix_momentum_buffer_pallas(
-                        a[0], a[1], a[2], a[3], a[4], a[5], a[6],
-                        bits=quant.bits, interpret=default_interpret()),
-                    (base, streams, sblk, weights, v2d, g2d, et))
-            return dequant_mix_momentum_buffer_pallas(
-                base, streams, sblk, weights, v2d, g2d, et, bits=quant.bits,
-                interpret=default_interpret())
-        from ..kernels import ref as kref
-        return kref.dequant_mix_momentum_buffer_ref(
-            base, streams, sblk, weights, v2d, g2d, et, quant.bits)
-
     @jax.named_scope("wire/decode")
     def decode_apply(self, base: jnp.ndarray, streams: jnp.ndarray,
                      scales: jnp.ndarray, weights: jnp.ndarray, quant,

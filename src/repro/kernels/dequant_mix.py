@@ -102,74 +102,6 @@ def dequant_mix_buffer_pallas(x2d: jnp.ndarray, streams: jnp.ndarray,
       weights.reshape(1, k).astype(jnp.float32))
 
 
-def _dequant_mix_momentum_buffer_kernel(x_ref, q_ref, s_ref, w_ref, v_ref,
-                                        g_ref, et_ref, out_ref, *, bits: int,
-                                        n_streams: int, g: int):
-    """Fused mix + deferred momentum: the round's combined decode-apply AND
-    final heavy-ball update in one memory pass —
-
-        out = [x + sum_k w[k] * deq(stream[k], scale[k, block])]
-              + (theta * v - eta * g)
-
-    The (v, g) pair is the round's DEFERRED last local step (fused-round
-    mode holds it back past the wire): mix -> v' = theta*v - eta*g ->
-    y' = mixed + v' without a second trip over the model. No v output —
-    momentum restarts at 0 every round (Algorithm 1), so v' dies here.
-    eta/theta are runtime scalars in et_ref = [[eta, theta]].
-    """
-    def block(lanes, scale):
-        acc = _mix_block(x_ref, q_ref, w_ref, lanes, scale, bits=bits,
-                         n_streams=n_streams)
-        v_next = (et_ref[0, 1] * v_ref[:, lanes].astype(jnp.float32)
-                  - et_ref[0, 0] * g_ref[:, lanes].astype(jnp.float32))
-        out_ref[:, lanes] = (acc + v_next).astype(out_ref.dtype)
-
-    for_each_block(g, s_ref, block)
-
-
-@functools.partial(jax.jit, static_argnames=("bits", "interpret"))
-def dequant_mix_momentum_buffer_pallas(x2d: jnp.ndarray, streams: jnp.ndarray,
-                                       block_scales: jnp.ndarray,
-                                       weights: jnp.ndarray, v2d: jnp.ndarray,
-                                       g2d: jnp.ndarray, et: jnp.ndarray, *,
-                                       bits: int, interpret: bool = False
-                                       ) -> jnp.ndarray:
-    """Fused-round decoder: x2d: [per, W] planar base; streams: uint32
-    [k, W]; block_scales: f32 [k, W // LANE_BLOCK]; weights: f32 [k];
-    v2d/g2d: [per, W] planar velocity/gradient of the deferred step; et:
-    f32 [2] = (eta, theta) — all runtime (traced OK). Returns [per, W]:
-    the mixed params with the deferred momentum step applied. Oracle:
-    ``kernels.ref.dequant_mix_momentum_buffer_ref``."""
-    per, w = x2d.shape
-    k = streams.shape[0]
-    n_blocks = w // LANE_BLOCK
-    assert per == 32 // bits and w % LANE_BLOCK == 0, (per, w)
-    assert block_scales.shape == (k, n_blocks), (block_scales.shape, k)
-    out = jax.ShapeDtypeStruct(x2d.shape, x2d.dtype)
-    tiles = lane_tiles(n_blocks, x2d, streams, v2d, g2d, out)
-    kernel = functools.partial(_dequant_mix_momentum_buffer_kernel,
-                               bits=bits, n_streams=k, g=tiles.g)
-    buf = tiles.spec(per)
-    return pl.pallas_call(
-        kernel,
-        grid=tiles.grid,
-        in_specs=[
-            buf,
-            tiles.spec(k),
-            tiles.scale_spec(k),
-            pl.BlockSpec((1, k), lambda i: (0, 0)),
-            buf, buf,
-            pl.BlockSpec((1, 2), lambda i: (0, 0)),
-        ],
-        out_specs=buf,
-        out_shape=out,
-        interpret=interpret,
-        name="dequant_mix_momentum_buffer",
-    )(x2d, streams, scale_groups(block_scales),
-      weights.reshape(1, k).astype(jnp.float32), v2d, g2d,
-      et.reshape(1, 2).astype(jnp.float32))
-
-
 def dequant_mix_plan_pallas(x2d: jnp.ndarray, streams: jnp.ndarray,
                             scales: jnp.ndarray, weights: jnp.ndarray, *,
                             bits: int, interpret: bool = False

@@ -98,86 +98,6 @@ def quantize_pack_buffer_pallas(x2d: jnp.ndarray, s_blocks: jnp.ndarray,
     )(x2d, noise, scale_groups(s_blocks))[0]
 
 
-def _momentum_quantize_pack_kernel(y_ref, v_ref, g_ref, x_ref, noise_ref,
-                                   s_ref, et_ref, y_out, v_out, w_out, *,
-                                   bits: int, stochastic: bool, g: int):
-    """Fused final-local-step + encode: apply the round's last heavy-ball
-    update and emit the wire words as a SIDE OUTPUT of the same pass —
-
-        v' = theta * v - eta * g ;  y' = y + v' ;  delta = y' - x ;
-        words = pack(Q(delta / s))
-
-    instead of a momentum pass (3R+2W of N) followed by a separate
-    quantize+pack pass over the planar buffer (2R+W/4 more). One read of
-    (y, v, g, x), one write of (y', v', words): the wire buffer never
-    costs its own trip over the model. eta/theta ride a runtime [1, 2]
-    scalar block like ``momentum_sgd``'s.
-    """
-    eta = et_ref[0, 0]
-    theta = et_ref[0, 1]
-
-    def block(lanes, scale):
-        v_next = (theta * v_ref[:, lanes].astype(jnp.float32)
-                  - eta * g_ref[:, lanes].astype(jnp.float32))
-        y_next = y_ref[:, lanes].astype(jnp.float32) + v_next
-        delta = y_next - x_ref[:, lanes].astype(jnp.float32)
-        y_out[:, lanes] = y_next.astype(y_out.dtype)
-        v_out[:, lanes] = v_next.astype(v_out.dtype)
-        w_out[:, lanes] = _encode_block(delta, noise_ref, lanes, scale(0),
-                                        bits=bits, stochastic=stochastic)
-
-    for_each_block(g, s_ref, block)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("bits", "stochastic", "interpret"))
-def momentum_quantize_pack_buffer_pallas(
-        y2d: jnp.ndarray, v2d: jnp.ndarray, g2d: jnp.ndarray,
-        x2d: jnp.ndarray, s_blocks: jnp.ndarray, noise: jnp.ndarray,
-        et: jnp.ndarray, *, bits: int, stochastic: bool,
-        interpret: bool = False
-        ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Fused-round encoder: the final applied local step and the whole
-    planar wire buffer in ONE ``pallas_call``.
-
-    y2d/v2d/g2d/x2d: [per, W] f32 planar buffers (y, v of the last applied
-    step's inputs; g its gradient; x the round's held params); s_blocks:
-    f32 [1, W // LANE_BLOCK] per-lane-block scales of the RESULTING delta
-    (computed by the caller from the same expression — a reduction, not a
-    full-size write); noise: [per, W] (ignored unless stochastic); et: f32
-    [2] = (eta, theta), runtime (traced OK). Returns (y' [per, W],
-    v' [per, W], words uint32 [W]). Pack math and layout are identical to
-    :func:`quantize_pack_buffer_pallas`; the oracle is
-    ``kernels.ref.momentum_quantize_pack_buffer_ref``.
-    """
-    per, w = y2d.shape
-    assert per == 32 // bits and w % LANE_BLOCK == 0, (per, w)
-    n_blocks = w // LANE_BLOCK
-    assert s_blocks.shape == (1, n_blocks), (s_blocks.shape, n_blocks)
-    outs = (jax.ShapeDtypeStruct(y2d.shape, y2d.dtype),
-            jax.ShapeDtypeStruct(v2d.shape, v2d.dtype),
-            jax.ShapeDtypeStruct((1, w), jnp.uint32))
-    tiles = lane_tiles(n_blocks, y2d, v2d, g2d, x2d, noise, *outs)
-    kernel = functools.partial(_momentum_quantize_pack_kernel, bits=bits,
-                               stochastic=stochastic, g=tiles.g)
-    buf = tiles.spec(per)
-    y_o, v_o, words = pl.pallas_call(
-        kernel,
-        grid=tiles.grid,
-        in_specs=[
-            buf, buf, buf, buf, buf,
-            tiles.scale_spec(1),
-            pl.BlockSpec((1, 2), lambda i: (0, 0)),
-        ],
-        out_specs=(buf, buf, tiles.spec(1)),
-        out_shape=outs,
-        interpret=interpret,
-        name="momentum_quantize_pack_buffer",
-    )(y2d, v2d, g2d, x2d, noise, scale_groups(s_blocks),
-      et.reshape(1, 2).astype(jnp.float32))
-    return y_o, v_o, words[0]
-
-
 def quantize_pack_pallas(x2d: jnp.ndarray, s: jnp.ndarray,
                          noise: jnp.ndarray, *, bits: int,
                          stochastic: bool, interpret: bool = False

@@ -141,69 +141,6 @@ def dequant_mix_buffer_ref(base: jnp.ndarray, streams: jnp.ndarray,
     return acc.astype(base.dtype)
 
 
-def momentum_quantize_pack_buffer_ref(y: jnp.ndarray, v: jnp.ndarray,
-                                      g: jnp.ndarray, x: jnp.ndarray,
-                                      block_scales: jnp.ndarray, bits: int,
-                                      eta, theta,
-                                      noise: jnp.ndarray | None = None
-                                      ) -> tuple[jnp.ndarray, jnp.ndarray,
-                                                 jnp.ndarray]:
-    """Fused final-local-step + whole-buffer encode (oracle + CPU path of
-    ``momentum_quantize_pack_buffer_pallas``):
-
-        v' = theta*v - eta*g ;  y' = y + v' ;  words = pack(Q(y' - x))
-
-    y/v/g/x: [..., per, W] f32 planar buffers; block_scales:
-    [..., W // LANE_BLOCK] f32 — scales of the RESULTING delta, computed by
-    the caller from the same expression order; eta/theta: scalars (traced
-    OK). Returns (y', v', words [..., W]). The pack math is
-    ``quantize_pack_buffer_ref`` verbatim; the update expression order
-    matches the kernel so the integer wire stays the oracle's.
-    """
-    eta = jnp.asarray(eta, jnp.float32)
-    theta = jnp.asarray(theta, jnp.float32)
-    v_next = theta * v.astype(jnp.float32) - eta * g.astype(jnp.float32)
-    y_next = y.astype(jnp.float32) + v_next
-    delta = y_next - x.astype(jnp.float32)
-    words = quantize_pack_buffer_ref(delta, block_scales, bits, noise)
-    return y_next.astype(y.dtype), v_next.astype(v.dtype), words
-
-
-def dequant_mix_momentum_buffer_ref(base: jnp.ndarray, streams: jnp.ndarray,
-                                    block_scales: jnp.ndarray,
-                                    weights: jnp.ndarray, v: jnp.ndarray,
-                                    g: jnp.ndarray, et: jnp.ndarray,
-                                    bits: int) -> jnp.ndarray:
-    """Fused mix + deferred momentum (oracle + CPU path of
-    ``dequant_mix_momentum_buffer_pallas``):
-
-        out = [base + sum_k weights[..., k] * deq(streams[..., k, :])]
-              + (theta*v - eta*g)
-
-    Shapes as in ``dequant_mix_buffer_ref`` plus v/g: [..., per, W] and
-    et: f32 [..., 2] = (eta, theta). The momentum term is added to the f32
-    accumulator BEFORE the output-dtype cast — same op order as the
-    kernel; the FMA-contraction bitwise caveat of
-    ``dequant_mix_buffer_ref`` applies unchanged.
-    """
-    per = 32 // bits
-    n_streams = streams.shape[-2]
-    mask = jnp.uint32((1 << bits) - 1)
-    offset = 1 << (bits - 1)
-    shifts = (jnp.arange(per, dtype=jnp.uint32) * bits)[:, None]
-    bs = block_scales.astype(jnp.float32)
-    acc = base.astype(jnp.float32)
-    for k in range(n_streams):
-        fields = (streams[..., k, None, :] >> shifts) & mask
-        deq = _per_block((fields.astype(jnp.int32) - offset)
-                         .astype(jnp.float32), bs[..., k, :], jnp.multiply)
-        acc = acc + weights[..., k, None, None] * deq
-    et = jnp.asarray(et, jnp.float32)
-    v_next = (et[..., 1, None, None] * v.astype(jnp.float32)
-              - et[..., 0, None, None] * g.astype(jnp.float32))
-    return (acc + v_next).astype(base.dtype)
-
-
 def dequant_mix_ref(x: jnp.ndarray, q_own: jnp.ndarray, q_left: jnp.ndarray,
                     q_right: jnp.ndarray, scales: jnp.ndarray, bits: int,
                     w_self: float, w_nb: float) -> jnp.ndarray:

@@ -262,7 +262,7 @@ def main(argv=None):
                          "gossip lane, so per-device wire drops "
                          "~linearly with the degree; needs n_shards * "
                          "model_parallel devices and the sparse backend "
-                         "(incompatible with --fuse-round and --pool)")
+                         "(incompatible with --pool)")
     ap.add_argument("--placement", default="contiguous",
                     choices=["contiguous", "partition"],
                     help="client -> lane placement for the sparse backend: "
@@ -282,13 +282,6 @@ def main(argv=None):
                          "by backend")
     ap.add_argument("--self-weight", type=float, default=0.5,
                     help="ring self weight (0.5 => PSD W, safe for Alg. 2)")
-    ap.add_argument("--fuse-round", action="store_true",
-                    help="fused overlapped round variant: the last local "
-                         "step is folded into the wire encode, the final "
-                         "gradient computes inside the gossip window, and "
-                         "mix + momentum apply in one decode pass (needs "
-                         "--local-steps >= 2; a different algorithm "
-                         "variant, not bit-identical to the default)")
     ap.add_argument("--schedule", default="static",
                     choices=["static", "constant", "edge-sample", "partial",
                              "random-walk", "cycle"],
@@ -410,13 +403,6 @@ def _run_resident(args, cfg, log, tracer):
         raise SystemExit("--model-parallel > 1 needs the sparse backend "
                          "(the dense einsum reference mixes full "
                          "replicas); drop --mixer-impl dense")
-    if args.model_parallel > 1 and args.fuse_round:
-        raise SystemExit(
-            "--fuse-round is incompatible with --model-parallel > 1: the "
-            "fused tail computes the last gradient inside the client "
-            "shard_map body, which would only see a 1/model_parallel "
-            "slice of the params; run the unfused round (its local SGD "
-            "auto-partitions over the model axis under GSPMD)")
     from .mesh import make_client_mesh
     mesh = client_axes = None
     if args.mixer_impl in ("auto", "sparse"):
@@ -450,8 +436,7 @@ def _run_resident(args, cfg, log, tracer):
             m, clients_per_shard=args.clients_per_shard)
     dfed = DFedAvgMConfig(eta=args.eta, theta=args.theta,
                           local_steps=args.local_steps, quant=quant,
-                          mixer_impl=impl, wire=args.wire,
-                          fuse_round=args.fuse_round)
+                          mixer_impl=impl, wire=args.wire)
     scheduled = isinstance(spec, TopologySchedule)
     placement = None
     if args.placement == "partition":

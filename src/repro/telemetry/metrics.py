@@ -5,7 +5,7 @@ when it is built with ``with_telemetry=True``; the flag defaults to off
 and the off path emits the exact graph it did before (bit-identical — the
 parity tests pin it). Fields a path does not produce stay ``None``, which
 is an empty pytree subtree, so one NamedTuple serves the synchronous,
-fused, asynchronous, and pooled steps without shape games.
+asynchronous, and pooled steps without shape games.
 
 Metric definitions (see ``docs/OBSERVABILITY.md`` for the full math):
 

@@ -16,12 +16,10 @@ from repro.kernels import (decode_apply_plan, decode_apply_ring,
                            momentum_update_flat)
 from repro.kernels import ref, tiling
 from repro.kernels.dequant_mix import (dequant_mix_buffer_pallas,
-                                       dequant_mix_momentum_buffer_pallas,
                                        dequant_mix_pallas)
 from repro.kernels.momentum_sgd import momentum_sgd_pallas
-from repro.kernels.quantize_pack import (
-    momentum_quantize_pack_buffer_pallas, quantize_pack_buffer_pallas,
-    quantize_pack_pallas)
+from repro.kernels.quantize_pack import (quantize_pack_buffer_pallas,
+                                         quantize_pack_pallas)
 
 BITS = (2, 4, 8, 16)
 SIZES = (1, 100, 512, 2048, 5000, 65536)
@@ -157,7 +155,7 @@ def test_quantize_pack_error_bound():
 
 
 # ---------------------------------------------------------------------------
-# Fused-round kernels: runtime eta/theta, ragged shapes, encode/decode fusion
+# Momentum kernel: runtime eta/theta, ragged shapes
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("shape", ((3, 700), (1, 1), (9, 513), (8, 512)))
@@ -275,11 +273,12 @@ def test_quantize_pack_buffer_tiles_match_ref(bits, stochastic, n_blocks):
 
 
 @pytest.mark.parametrize("bits", (4, 8))
-@pytest.mark.parametrize("k", (2, 3, 5))
+@pytest.mark.parametrize("k", (1, 2, 3, 5))
 @pytest.mark.parametrize("n_blocks", N_BLOCKS)
 def test_dequant_mix_buffer_tiles_match_ref(bits, k, n_blocks):
-    """Decode-apply over lane tiles: k streams, a scale of their own on
-    every lane block of every stream, a ragged last grid step."""
+    """Decode-apply over lane tiles: k streams (k=1: a client with no
+    neighbour stream that round), a scale of their own on every lane
+    block of every stream, a ragged last grid step."""
     per, w = 32 // bits, n_blocks * ref.LANE_BLOCK
     rng = np.random.default_rng(bits * 10 + k)
     x = jnp.asarray(rng.normal(size=(per, w)), jnp.float32)
@@ -298,93 +297,52 @@ def test_dequant_mix_buffer_tiles_match_ref(bits, k, n_blocks):
     np.testing.assert_allclose(np.asarray(out), o, rtol=0, atol=tol)
 
 
-@pytest.mark.parametrize("bits", (4, 8))
-@pytest.mark.parametrize("stochastic", (False, True))
-def test_fused_encode_kernel_matches_ref(bits, stochastic):
-    """momentum_quantize_pack fusion: the applied last local step AND the
-    packed wire in one pass — integer wire BITWISE vs the oracle, float
-    outputs to ~ulp (FMA contraction). Ragged last grid step."""
-    n_blocks = RAGGED_BLOCKS
-    (y, v, g, x), sblk = _blockwise(bits, n_blocks,
-                                    jax.random.PRNGKey(bits), n=4)
-    noise = jax.random.uniform(jax.random.PRNGKey(7), y.shape)
-    et = jnp.asarray([0.05, 0.9], jnp.float32)
-    fn = functools.partial(momentum_quantize_pack_buffer_pallas, bits=bits,
-                           stochastic=stochastic, interpret=True)
-    _assert_ragged(fn, y, v, g, x, sblk, noise, et, n_blocks=n_blocks)
-    yo, vo, words = fn(y, v, g, x, sblk, noise, et)
-    yr, vr, wr = ref.momentum_quantize_pack_buffer_ref(
-        y, v, g, x, sblk[0], bits, 0.05, 0.9,
-        noise=noise if stochastic else None)
-    assert jnp.array_equal(words, wr), "fused encode wire is not bitwise"
-    np.testing.assert_allclose(np.asarray(yo), np.asarray(yr), atol=1e-6)
-    np.testing.assert_allclose(np.asarray(vo), np.asarray(vr), atol=1e-6)
+# The benchmark cells' wire layouts at q8: (model, layers) and the
+# (per, W, lane blocks) they give.
+CELL_WIRES = {"olmo-1b": (2, (4, 59_310_080, 115_840)),
+              "mamba2-780m": (4, (4, 33_950_208, 66_309))}
 
 
-@pytest.mark.parametrize("bits", (4, 8))
-@pytest.mark.parametrize("k", (1, 2, 3, 5))
-def test_fused_decode_kernel_matches_ref(bits, k):
-    """dequant_mix_momentum fusion: mix + the deferred last heavy-ball
-    step in one pass, vs the tree-level oracle. Ragged last grid step."""
-    n_blocks = RAGGED_BLOCKS
-    per, w = 32 // bits, n_blocks * ref.LANE_BLOCK
-    rng = np.random.default_rng(bits * 10 + k)
-    x = jnp.asarray(rng.normal(size=(per, w)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(per, w)), jnp.float32)
-    g = jnp.asarray(rng.normal(size=(per, w)), jnp.float32)
-    streams = jnp.asarray(
-        rng.integers(0, 2 ** 32, size=(k, w), dtype=np.uint32))
-    sblk = jnp.asarray(rng.uniform(0.01, 0.1, size=(k, n_blocks)),
-                       jnp.float32)
-    weights = jnp.asarray(rng.uniform(0.0, 0.5, size=(k,)), jnp.float32)
-    et = jnp.asarray([0.05, 0.9], jnp.float32)
-    fn = functools.partial(dequant_mix_momentum_buffer_pallas, bits=bits,
-                           interpret=True)
-    _assert_ragged(fn, x, streams, sblk, weights, v, g, et,
-                   n_blocks=n_blocks)
-    out = fn(x, streams, sblk, weights, v, g, et)
-    expected = ref.dequant_mix_momentum_buffer_ref(
-        x, streams, sblk, weights, v, g, et, bits)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
-                               atol=1e-5)
-
-
-def _olmo_two_layer_layout(bits: int):
+def _cell_layout(model: str, bits: int):
+    """The wire layout of a benchmark cell's model at published widths
+    and the cell's depth, in the config's dtypes (Mamba2 mixes bf16
+    weights with f32 ``A_log``/``dt_bias``)."""
     from repro.configs import get_config
     from repro.core.wire_layout import WireLayout
     from repro.models import model as M
-    cfg = dataclasses.replace(get_config("olmo-1b"), n_layers=2)
+    cfg = dataclasses.replace(get_config(model),
+                              n_layers=CELL_WIRES[model][0])
     params = jax.eval_shape(lambda k: M.init_model(k, cfg)[0],
                             jax.random.PRNGKey(0))
     return WireLayout.for_tree(params, bits=bits)
 
 
-@pytest.mark.parametrize("kernel,k", (("encode", 1), ("decode", 2),
-                                      ("decode", 3), ("fused_encode", 1),
-                                      ("fused_decode", 3)))
-def test_codec_tiles_at_cell_width(kernel, k):
-    """The benchmark cells' wire, the two-layer OLMo-1B q8 buffer
-    (per 4, W 59,310,080): each codec ``pallas_call`` streams it in at
-    most 2,048 grid steps, not one per lane block (115,840), and a step's
-    double-buffered VMEM blocks stay under the budget. Traced only."""
-    lay = _olmo_two_layer_layout(8)
+_CODEC_CASES = [("olmo-1b", "encode", 1), ("olmo-1b", "decode", 2),
+                ("olmo-1b", "decode", 3), ("mamba2-780m", "encode", 1),
+                ("mamba2-780m", "decode", 2), ("mamba2-780m", "decode", 3)]
+
+
+@pytest.mark.parametrize(
+    "model,kernel,k", _CODEC_CASES,
+    ids=[(f"{k}-{n}" if m == "olmo-1b" else f"mamba2-{k}-{n}")
+         for m, k, n in _CODEC_CASES])
+def test_codec_tiles_at_cell_width(model, kernel, k):
+    """The benchmark cells' q8 wire (two-layer OLMo-1B: per 4, W
+    59,310,080; four-layer Mamba2-780m: per 4, W 33,950,208): each codec
+    ``pallas_call`` streams it in at most 2,048 grid steps, not one per
+    lane block (115,840 and 66,309), and a step's double-buffered VMEM
+    blocks stay under the budget. Traced only."""
+    lay = _cell_layout(model, 8)
     per, w, nb = lay.per, lay.total_words, lay.n_blocks
-    assert (per, w, nb) == (4, 59_310_080, 115_840)
+    assert (per, w, nb) == CELL_WIRES[model][1]
     f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)   # noqa: E731
     buf, q = f32(per, w), jax.ShapeDtypeStruct((k, w), jnp.uint32)
-    et = f32(2)
     fn, args = {
         "encode": (functools.partial(quantize_pack_buffer_pallas, bits=8,
                                      stochastic=True),
                    (buf, f32(1, nb), buf)),
         "decode": (functools.partial(dequant_mix_buffer_pallas, bits=8),
                    (buf, q, f32(k, nb), f32(k))),
-        "fused_encode": (functools.partial(
-            momentum_quantize_pack_buffer_pallas, bits=8, stochastic=True),
-            (buf, buf, buf, buf, f32(1, nb), buf, et)),
-        "fused_decode": (functools.partial(
-            dequant_mix_momentum_buffer_pallas, bits=8),
-            (buf, q, f32(k, nb), f32(k), buf, buf, et)),
     }[kernel]
     steps, lanes, vmem = _grid_and_vmem(fn, *args)
     assert steps <= 2048, (steps, lanes)

@@ -300,24 +300,3 @@ def test_2d_train_driver_production_config():
     assert len(losses) == 3 and all(math.isfinite(v) for v in losses)
     assert losses[-1] < losses[0]
 
-
-def test_fused_tail_rejects_model_sharded_specs():
-    """fuse_round computes the last gradient inside the client shard_map
-    body, which would only see a 1/mp model slice — the 2D mesh must
-    refuse it loudly, not silently mis-train."""
-    out = run_sub(_PRELUDE + """
-    from repro.core import DFedAvgMConfig, TopologySchedule, make_round_step
-    from repro.core.topology import ring_graph
-    sched = TopologySchedule.constant(MixingSpec.ring(M, self_weight=0.5))
-    loss_fn = lambda p, b, r: 0.5 * jnp.sum((p["w"] - b["c"]) ** 2)
-    cfg = DFedAvgMConfig(eta=0.05, theta=0.5, local_steps=4,
-                         mixer_impl="sparse", fuse_round=True)
-    try:
-        make_round_step(loss_fn, cfg, sched, mesh=mesh2,
-                        client_axes=("clients",),
-                        param_specs={"w": P("clients", None, "model")})
-    except ValueError as e:
-        assert "model-sharded" in str(e), e
-        print("FUSE2D_REJECT_OK")
-    """)
-    assert "FUSE2D_REJECT_OK" in out

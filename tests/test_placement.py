@@ -261,3 +261,38 @@ def test_single_sparse_executor_lint_passes():
         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr}"
     assert "_make_sparse_exec" in r.stdout
+
+
+_LINT_OK = '''
+import jax
+
+def _make_sparse_exec(plan):
+    def ex(x):
+        s = jax.lax.pmax(x, "model")
+        return jax.lax.ppermute(s, "clients", [(0, 1)])
+    return ex
+'''
+
+
+@pytest.mark.parametrize("offence,extra", [
+    ("ppermute", "def make_tail(x):\n"
+                 "    return jax.lax.ppermute(x, 'clients', [(0, 1)])\n"),
+    ("pmax", "def make_tail(x):\n"
+             "    return jax.lax.pmax(x, 'model')\n"),
+    ("second sparse executor", "def _make_tail_exec(plan):\n"
+                               "    return plan\n"),
+])
+def test_single_sparse_executor_lint_rejects(tmp_path, offence, extra):
+    """``_make_sparse_exec`` is the one scope allowed a ppermute or a
+    pmax, and the one ``*_exec`` factory: a second round tail that
+    carries its own wire fails the lint."""
+    lint = os.path.join(REPO, "tools", "check_single_executor.py")
+    ok, bad = tmp_path / "ok.py", tmp_path / "bad.py"
+    ok.write_text(_LINT_OK)
+    bad.write_text(_LINT_OK + "\n\n" + extra)
+    r = subprocess.run([sys.executable, lint, str(ok)], capture_output=True,
+                       text=True, timeout=60)
+    assert r.returncode == 0, r.stdout + r.stderr
+    r = subprocess.run([sys.executable, lint, str(bad)], capture_output=True,
+                       text=True, timeout=60)
+    assert r.returncode == 1 and offence in r.stdout, r.stdout + r.stderr

@@ -4,13 +4,12 @@ The chip benchmark reads per-layer times from a device trace through the
 ``op_name`` metadata of the compiled round step (``jax.named_scope``
 paths). These tests compile the step of a tiny OLMo on a 2-shard CPU mesh
 (4 clients, 2 per shard: both intra-shard lane gathers and boundary
-ppermutes), sparse ring, q8 planar wire, and check its scopes:
+ppermutes), sparse ring, planar wire — K=2 at q8, K=1 at q8 and K=2 at
+q4 — and check its scopes:
 
 * every instruction whose ``op_name`` comes from the step's traced code
   holds one ``round/<stage>`` scope;
-* every instruction under ``round/mix`` holds exactly one wire stage (the
-  fused round's deferred last local step holds ``sgd/grad`` or
-  ``sgd/update`` there instead);
+* every instruction under ``round/mix`` holds exactly one wire stage;
 * ``round/metrics``, ``sgd/grad`` and ``sgd/update`` are there.
 
 The compile runs in a subprocess (two host devices; the main test
@@ -42,11 +41,9 @@ _COMPILE = """
     import json, re, sys
     from repro.launch import train
     args = ["--arch", "olmo-1b", "--layers", "1", "--clients", "4",
-            "--clients-per-shard", "2", "--local-steps", "2",
-            "--batch", "1", "--seq", "16", "--bits", "8",
+            "--clients-per-shard", "2", "--local-steps", str(K),
+            "--batch", "1", "--seq", "16", "--bits", str(BITS),
             "--mixer-impl", "sparse", "--wire", "planar", "--rounds", "1"]
-    if FUSED:
-        args.append("--fuse-round")
     res = train.main(args)
     txt = res.step.lower(res.state, res.batches).compile().as_text()
     # The stack-frame tables: frame id -> the file of its innermost frame.
@@ -75,14 +72,14 @@ _COMPILE = """
 """
 
 
-def _compiled_ops(fused: bool) -> list:
+def _compiled_ops(k: int, bits: int) -> list:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         " --xla_force_host_platform_device_count=2").strip()
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     env["JAX_PLATFORMS"] = "cpu"
     env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
-    code = f"FUSED = {fused}\n" + textwrap.dedent(_COMPILE)
+    code = f"K, BITS = {k}, {bits}\n" + textwrap.dedent(_COMPILE)
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=600, env=env, cwd=REPO)
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr}"
@@ -90,9 +87,10 @@ def _compiled_ops(fused: bool) -> list:
     return json.loads(line[4:])
 
 
-@pytest.mark.parametrize("fused", [False, True], ids=["unfused", "fused"])
-def test_every_round_step_op_holds_one_stage(fused):
-    ops = _compiled_ops(fused)
+@pytest.mark.parametrize("k,bits", [(2, 8), (1, 8), (2, 4)],
+                         ids=["unfused", "k1", "q4"])
+def test_every_round_step_op_holds_one_stage(k, bits):
+    ops = _compiled_ops(k, bits)
     # Traced code: an op_name with a source frame under the step's jit.
     # Parameters carry their argument path, reducer bodies a bare
     # primitive name; what XLA inserts carries no frame (its op_name, if
@@ -110,22 +108,9 @@ def test_every_round_step_op_holds_one_stage(fused):
             return False
         if stages != {"mix"}:
             return True
-        wire = {m.group(1) for m in _WIRE.finditer(name)}
-        sgd = {m.group(1) for m in _SGD.finditer(name)} if fused else set()
-        return len(wire) + len(sgd) == 1
+        return len({m.group(1) for m in _WIRE.finditer(name)}) == 1
 
     bad = sorted({name for name, src in traced if not staged(name)})
-    if fused:
-        # The fused round takes two gradients outside the K-step scan.
-        # Differentiating the attention's KV scan there, JAX hoists its
-        # loop-invariant part (the RoPE table and the causal mask, which
-        # read only constants) out of the scan with the name stack reset
-        # (``lax`` scan partial evaluation), so those few model ops land
-        # at the level the gradient was taken with no scope of the step.
-        hoisted = {name for name, src in traced
-                   if not staged(name) and "/repro/models/" in src}
-        assert hoisted and len(hoisted) < 20, sorted(hoisted)
-        bad = sorted(set(bad) - hoisted)
     assert bad == [], bad[:20]
 
     traced = [name for name, _ in traced]
@@ -135,8 +120,6 @@ def test_every_round_step_op_holds_one_stage(fused):
     assert {m.group(1) for n in mix for m in _WIRE.finditer(n)} \
         == set(WIRE_STAGES)
     assert any("/wire/encode/noise/" in n for n in mix)
-    # At K=2 the fused round's head scan is empty: both updates it owns
-    # run in the tail (the Pallas kernels fold them into the wire).
     sgd = [n for n in traced if "/round/local_sgd/" in n]
     assert {m.group(1) for n in sgd for m in _SGD.finditer(n)} \
-        == ({"grad"} if fused else set(SGD_STAGES))
+        == set(SGD_STAGES)

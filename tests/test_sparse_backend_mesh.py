@@ -21,10 +21,13 @@ Covers the acceptance matrix of the plan/compile/execute refactor:
     ships only boundary lanes — O(n_shards * boundary_degree) wire
     bytes, not O(m)
 """
+import json
 import os
 import subprocess
 import sys
 import textwrap
+
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -573,9 +576,70 @@ def test_block_ring_hlo_moves_boundary_lanes_only():
     assert "BLOCK_HLO_OK" in out
 
 
-def test_round_step_sparse_matches_dense_end_to_end():
-    """Full DFedAvgM rounds (local SGD + scheduled gossip) agree between
-    backends, and inactive clients still hold params exactly.
+# Full rounds of the one synchronous round step, dense vs sparse, at every
+# (spec, quant, K) below: the partial cohort gates inactive clients, the
+# static ring runs the sparse executor one client a shard, and the
+# block-sharded ring puts 2 clients on each of 4 shards (intra-shard lane
+# gathers plus boundary ppermutes). One subprocess computes every row.
+_E2E_CODE = _PRELUDE + """
+    import json
+    from repro.core import (DFedAvgMConfig, init_round_state,
+                            make_round_step)
+    loss_fn = lambda p, b, r: 0.5 * jnp.sum((p["w"] - b["c"]) ** 2)
+    mesh4 = Mesh(np.array(jax.devices()[:4]), ("clients",))
+    ring = MixingSpec.ring(M, self_weight=0.5)
+    specs = {"partial": (TopologySchedule.partial(ring_graph(M), 0.5), mesh),
+             "ring": (ring, mesh), "ring_block2": (ring, mesh4)}
+    quants = {"fp32": None,
+              "q8_lemma5": QuantConfig(bits=8, stochastic=False,
+                                       delta_mode="lemma5"),
+              "q8_eq7_stoch": QuantConfig(bits=8, stochastic=True,
+                                          delta_mode="eq7")}
+    def run(spec, q, K, impl, msh):
+        batches = {"c": jnp.broadcast_to(x[:, None], (M, K, D))}
+        cfg = DFedAvgMConfig(eta=0.05, theta=0.5, local_steps=K, quant=q,
+                             mixer_impl=impl)
+        step = jax.jit(make_round_step(loss_fn, cfg, spec, mesh=msh,
+                                       client_axes=("clients",) if msh
+                                       else ()))
+        st = init_round_state({"w": jnp.zeros((M, D))},
+                              jax.random.PRNGKey(7))
+        for _ in range(4):
+            st, mt = step(st, batches)
+        return np.asarray(st.params["w"]), float(mt.get("active_frac", 1))
+    rows, dense = {}, {}
+    for sname, (spec, msh) in specs.items():
+        for qname, q in quants.items():
+            for K in (1, 4):
+                # Both rings share one dense reference: the mesh is the
+                # sparse executor's alone.
+                dkey = (sname.split("_")[0], qname, K)
+                if dkey not in dense:
+                    dense[dkey] = run(spec, q, K, "dense", None)
+                w_d, af_d = dense[dkey]
+                w_s, af_s = run(spec, q, K, "sparse", msh)
+                diff = np.abs(w_d - w_s)
+                rows[f"{sname}-{qname}-K{K}"] = [
+                    float(diff.max()), float((diff > 1e-4).mean()), af_d,
+                    af_s]
+    print("ROWS " + json.dumps(rows))
+"""
+
+
+@pytest.fixture(scope="module")
+def e2e_rows():
+    out = run_sub(_E2E_CODE, timeout=1200)
+    line, = [l for l in out.splitlines() if l.startswith("ROWS ")]
+    return json.loads(line[len("ROWS "):])
+
+
+@pytest.mark.parametrize("K", (1, 4), ids=("K1", "K4"))
+@pytest.mark.parametrize("quant", ("fp32", "q8_lemma5", "q8_eq7_stoch"))
+@pytest.mark.parametrize("spec", ("partial", "ring", "ring_block2"))
+def test_round_step_sparse_matches_dense_end_to_end(e2e_rows, spec, quant,
+                                                     K):
+    """Full DFedAvgM rounds (local SGD + gossip) agree between backends,
+    and inactive clients still hold params exactly.
 
     Tolerances: the backends are independently compiled modules, so the
     local-SGD arithmetic picks up ~1-ulp FMA-contraction differences,
@@ -586,35 +650,10 @@ def test_round_step_sparse_matches_dense_end_to_end():
     Hence: a loose per-element cap of a few quantizer steps, plus a
     strict cap on HOW MANY elements may sit off the FMA-level floor —
     knife edges are rare, codec corruption is not."""
-    out = run_sub(_PRELUDE + """
-    from repro.core import (DFedAvgMConfig, init_round_state,
-                            make_round_step)
-    sched = TopologySchedule.partial(ring_graph(M), 0.5)
-    loss_fn = lambda p, b, r: 0.5 * jnp.sum((p["w"] - b["c"]) ** 2)
-    batches = {"c": jnp.broadcast_to(x[:, None], (M, 4, D))}
-    def run(impl, msh):
-        cfg = DFedAvgMConfig(eta=0.05, theta=0.5, local_steps=4,
-                             quant=QuantConfig(bits=8, stochastic=False),
-                             mixer_impl=impl)
-        step = jax.jit(make_round_step(loss_fn, cfg, sched, mesh=msh,
-                                       client_axes=("clients",) if msh
-                                       else ()))
-        st = init_round_state({"w": jnp.zeros((M, D))},
-                              jax.random.PRNGKey(7))
-        for _ in range(4):
-            st, mt = step(st, batches)
-        return np.asarray(st.params["w"]), float(mt["active_frac"])
-    w_d, af_d = run("dense", None)
-    w_s, af_s = run("sparse", mesh)
+    err, knife_frac, af_d, af_s = e2e_rows[f"{spec}-{quant}-K{K}"]
     assert af_d == af_s
-    diff = np.abs(w_d - w_s)
-    err = float(diff.max())
     assert err < 1e-2, err
-    knife_frac = float((diff > 1e-4).mean())
     assert knife_frac < 0.05, (knife_frac, err)
-    print("ROUNDS_OK", err, knife_frac)
-    """)
-    assert "ROUNDS_OK" in out
 
 
 def test_unified_executor_one_permute_per_step_m_local_1():

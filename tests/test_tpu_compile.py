@@ -1,7 +1,9 @@
 """The Pallas kernels of the round path, compiled for a TPU v5e that is
 described, not attached: interpret mode (every other kernel test) accepts
 block shapes and integer ops the chip's compiler refuses. Widths are
-OLMo-1B's published ones, cut to two layers as in ``chip_smoke.py``.
+OLMo-1B's published ones, cut to two layers as in ``chip_smoke.py``; the
+buffer codec kernels also compile at the four-layer Mamba2-780m wire of
+the Mamba2 benchmark cell.
 The dense reference mixer is compiled too: the chip's default f32
 matmul precision (one bf16 pass) is invisible on the CPU.
 
@@ -23,11 +25,9 @@ from repro.core import (MixerConfig, MixingSpec, QuantConfig,
                         TopologySchedule, make_mixer)
 from repro.core.mixing import _mix_dense_quantized, mix_dense
 from repro.core.wire_layout import LANE_BLOCK, WireLayout
-from repro.kernels.dequant_mix import (dequant_mix_buffer_pallas,
-                                       dequant_mix_momentum_buffer_pallas)
+from repro.kernels.dequant_mix import dequant_mix_buffer_pallas
 from repro.kernels.ops import momentum_update_flat
-from repro.kernels.quantize_pack import (momentum_quantize_pack_buffer_pallas,
-                                         quantize_pack_buffer_pallas)
+from repro.kernels.quantize_pack import quantize_pack_buffer_pallas
 from repro.models import model as M
 
 
@@ -56,11 +56,22 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _olmo_layout(bits: int) -> WireLayout:
-    cfg = dataclasses.replace(get_config("olmo-1b"), n_layers=2)
+# The benchmark cells' models and depths.
+CELL_LAYERS = {"olmo-1b": 2, "mamba2-780m": 4}
+
+
+def _cell_layout(model: str, bits: int) -> WireLayout:
+    cfg = dataclasses.replace(get_config(model),
+                              n_layers=CELL_LAYERS[model])
     params = jax.eval_shape(lambda k: M.init_model(k, cfg)[0],
                             jax.random.PRNGKey(0))
     return WireLayout.for_tree(params, bits=bits)
+
+
+# (model, bits); the OLMo cases keep their bare-bits ids.
+_WIDTHS = [("olmo-1b", 8), ("olmo-1b", 4), ("mamba2-780m", 8),
+           ("mamba2-780m", 4)]
+_WIDTH_IDS = [str(b) if m == "olmo-1b" else f"mamba2-{b}" for m, b in _WIDTHS]
 
 
 def _compiled_text(fn, sharding, *shapes) -> str:
@@ -68,9 +79,9 @@ def _compiled_text(fn, sharding, *shapes) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("bits", (8, 4))
-def test_quantize_pack_buffer_compiles_at_olmo_width(one_chip, bits):
-    lay = _olmo_layout(bits)
+@pytest.mark.parametrize("model,bits", _WIDTHS, ids=_WIDTH_IDS)
+def test_quantize_pack_buffer_compiles_at_olmo_width(one_chip, model, bits):
+    lay = _cell_layout(model, bits)
     per, w = lay.per, lay.total_words
     txt = _compiled_text(
         lambda x, s, n: quantize_pack_buffer_pallas(
@@ -80,9 +91,9 @@ def test_quantize_pack_buffer_compiles_at_olmo_width(one_chip, bits):
     assert "tpu_custom_call" in txt
 
 
-@pytest.mark.parametrize("bits", (8, 4))
-def test_dequant_mix_buffer_compiles_at_olmo_width(one_chip, bits):
-    lay = _olmo_layout(bits)
+@pytest.mark.parametrize("model,bits", _WIDTHS, ids=_WIDTH_IDS)
+def test_dequant_mix_buffer_compiles_at_olmo_width(one_chip, model, bits):
+    lay = _cell_layout(model, bits)
     per, w, k = lay.per, lay.total_words, 3
     txt = _compiled_text(
         lambda x, q, s, wt: dequant_mix_buffer_pallas(
@@ -92,42 +103,14 @@ def test_dequant_mix_buffer_compiles_at_olmo_width(one_chip, bits):
     assert "tpu_custom_call" in txt
 
 
-@pytest.mark.parametrize("bits", (8, 4))
-def test_fused_round_encoder_compiles_at_olmo_width(one_chip, bits):
-    lay = _olmo_layout(bits)
-    buf = ((lay.per, lay.total_words), jnp.float32)
-    txt = _compiled_text(
-        lambda y, v, g, x, s, n, et: momentum_quantize_pack_buffer_pallas(
-            y, v, g, x, s, n, et, bits=bits, stochastic=True,
-            interpret=False),
-        one_chip, buf, buf, buf, buf, ((1, lay.n_blocks), jnp.float32), buf,
-        ((2,), jnp.float32))
-    assert "tpu_custom_call" in txt
-
-
-@pytest.mark.parametrize("bits", (8, 4))
-def test_fused_round_decoder_compiles_at_olmo_width(one_chip, bits):
-    lay = _olmo_layout(bits)
-    w, k = lay.total_words, 3
-    buf = ((lay.per, w), jnp.float32)
-    txt = _compiled_text(
-        lambda x, q, s, wt, v, g, et: dequant_mix_momentum_buffer_pallas(
-            x, q, s, wt, v, g, et, bits=bits, interpret=False),
-        one_chip, buf, ((k, w), jnp.uint32), ((k, lay.n_blocks), jnp.float32),
-        ((k,), jnp.float32), buf, buf, ((2,), jnp.float32))
-    assert "tpu_custom_call" in txt
-
-
-@pytest.mark.parametrize("kernel", ("encode", "decode", "fused_encode",
-                                    "fused_decode"))
+@pytest.mark.parametrize("kernel", ("encode", "decode"))
 def test_codec_kernels_compile_at_ragged_width(one_chip, kernel):
     """OLMo width plus 37 lane blocks: the last grid step covers only
     part of a lane tile, so its out-of-range blocks are masked on write."""
-    lay = _olmo_layout(8)
+    lay = _cell_layout("olmo-1b", 8)
     nb = lay.n_blocks + 37
     per, w, k = lay.per, nb * LANE_BLOCK, 3
-    buf, et = ((per, w), jnp.float32), ((2,), jnp.float32)
-    q = ((k, w), jnp.uint32)
+    buf, q = ((per, w), jnp.float32), ((k, w), jnp.uint32)
     fn, shapes = {
         "encode": (lambda x, s, n: quantize_pack_buffer_pallas(
             x, s, n, bits=8, stochastic=True, interpret=False),
@@ -135,16 +118,6 @@ def test_codec_kernels_compile_at_ragged_width(one_chip, kernel):
         "decode": (lambda x, q, s, wt: dequant_mix_buffer_pallas(
             x, q, s, wt, bits=8, interpret=False),
             (buf, q, ((k, nb), jnp.float32), ((k,), jnp.float32))),
-        "fused_encode": (
-            lambda y, v, g, x, s, n, et: momentum_quantize_pack_buffer_pallas(
-                y, v, g, x, s, n, et, bits=8, stochastic=True,
-                interpret=False),
-            (buf, buf, buf, buf, ((1, nb), jnp.float32), buf, et)),
-        "fused_decode": (
-            lambda x, q, s, wt, v, g, et: dequant_mix_momentum_buffer_pallas(
-                x, q, s, wt, v, g, et, bits=8, interpret=False),
-            (buf, q, ((k, nb), jnp.float32), ((k,), jnp.float32), buf, buf,
-             et)),
     }[kernel]
     txt = _compiled_text(fn, one_chip, *shapes)
     assert "tpu_custom_call" in txt

@@ -6,8 +6,8 @@ body and a blocked ``m_local > 1`` body. PR 9 folded them into ONE block
 realization (``_make_sparse_exec``), which at ``m_local == 1``
 degenerates to the historical one-permute-per-step program — the mesh
 HLO pins hold either way. This lint keeps it that way: a second sparse
-executor (or a stray ``ppermute`` call site outside the two sanctioned
-bodies) is a CI failure, not a review nit, so the duplication cannot
+executor (or a stray ``ppermute`` call site outside it) is a CI
+failure, not a review nit, so the duplication cannot
 silently grow back.
 
 Checks, all by AST (no imports of jax needed):
@@ -15,8 +15,7 @@ Checks, all by AST (no imports of jax needed):
   1. exactly one top-level ``*_exec``-named function —
      ``_make_sparse_exec``;
   2. every ``jax.lax.ppermute`` / ``lax.ppermute`` / bare ``ppermute``
-     call site lives inside ``_make_sparse_exec`` or ``make_fused_tail``
-     (the fused tail shares the same block realization);
+     call site lives inside ``_make_sparse_exec``;
   3. every ``jax.lax.pmax`` call site is confined the same way — on the
      2D ``(clients, model)`` mesh the model-axis amax all-reduce is part
      of the per-model-shard wire realization (it makes the quantizer
@@ -34,7 +33,7 @@ import sys
 from pathlib import Path
 
 ALLOWED_EXEC_FACTORIES = ["_make_sparse_exec"]
-ALLOWED_PPERMUTE_SCOPES = {"_make_sparse_exec", "make_fused_tail"}
+ALLOWED_PPERMUTE_SCOPES = {"_make_sparse_exec"}
 ALLOWED_PMAX_SCOPES = ALLOWED_PPERMUTE_SCOPES
 
 
@@ -83,16 +82,14 @@ def check_file(path: Path) -> list[str]:
                 problems.append(
                     f"{path}:{node.lineno}: ppermute call site in "
                     f"{top.name!r} — wire traffic must go through "
-                    f"the block realization in _make_sparse_exec / "
-                    f"make_fused_tail")
+                    f"the block realization in _make_sparse_exec")
             if _is_call_to(node, "pmax") \
                     and top.name not in ALLOWED_PMAX_SCOPES:
                 problems.append(
                     f"{path}:{node.lineno}: pmax call site in "
                     f"{top.name!r} — the model-axis amax all-reduce "
                     f"(2D mesh scale consistency) belongs to the block "
-                    f"realization in _make_sparse_exec / "
-                    f"make_fused_tail")
+                    f"realization in _make_sparse_exec")
     return problems
 
 
